@@ -125,7 +125,7 @@ _IOTA_GROUPS_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]<=")
 _LIST_GROUPS_RE = re.compile(r"replica_groups=\{\{([0-9,]+)\}")
 _PAIRS_RE = re.compile(r"source_target_pairs=\{(\{[0-9,]+\}(?:,\{[0-9,]+\})*)\}")
 _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
-_SOURCE_RE = re.compile(r'source_file="([^"]*)"\s+source_line=(\d+)')
+_FRAME_ID_RE = re.compile(r"stack_frame_id=(\d+)")
 
 
 def _hlo_result_bytes(result: str):
@@ -196,6 +196,28 @@ def _short_where(source_file, source_line, op_name):
     return f"{path}:{source_line}{tail}" if path else op_name
 
 
+def _frame_sources(hlo_text) -> dict:
+    """{stack_frame_id: (source_file, source_line)} from the module's header
+    tables. XLA prints provenance as an index: ops carry `stack_frame_id=N`,
+    and the FileNames / FileLocations / StackFrames blocks at the top of the
+    module text resolve it (the frame's own location is the innermost user
+    frame — where the op was traced)."""
+    blocks = {}
+    for block in hlo_text.split("\n\n"):
+        name, _, body = block.strip().partition("\n")
+        if name in ("FileNames", "FileLocations", "StackFrames"):
+            blocks[name] = body
+    files = dict(re.findall(r'^(\d+) "(.*)"$', blocks.get("FileNames", ""),
+                            re.M))
+    locations = {
+        loc: (files[f], line) for loc, f, line in re.findall(
+            r"^(\d+) \{file_name_id=(\d+) .*?\bline=(\d+)",
+            blocks.get("FileLocations", ""), re.M)}
+    return {frame: locations[loc] for frame, loc in re.findall(
+        r"^(\d+) \{file_location_id=(\d+)", blocks.get("StackFrames", ""),
+        re.M)}
+
+
 def collective_inventory(hlo_text, *, loop_steps=1):
     """Parse every collective out of post-SPMD compiled HLO text.
 
@@ -204,6 +226,7 @@ def collective_inventory(hlo_text, *, loop_steps=1):
     but the op runs once per scanned step. Async ``-start``/``-done``
     pairs count once (the ``-start`` carries the transfer)."""
     ops = []
+    sources = _frame_sources(hlo_text)
     for line in hlo_text.splitlines():
         m = _COLLECTIVE_RE.search(line)
         if m is None:
@@ -223,9 +246,9 @@ def collective_inventory(hlo_text, *, loop_steps=1):
                 if pm:
                     group = pm.group(1).count("{")
         op_name = (_OP_NAME_RE.search(line) or [None, ""])[1]
-        sm = _SOURCE_RE.search(line)
-        where = _short_where(sm.group(1), sm.group(2), op_name) if sm \
-            else op_name
+        fm = _FRAME_ID_RE.search(line)
+        where = (_short_where(*sources[fm.group(1)], op_name)
+                 if fm and fm.group(1) in sources else op_name)
         count = int(loop_steps) if "/while/" in op_name else 1
         ops.append(CollectiveOp(
             kind=kind, result=result.split("{")[0], dtype=dtype,
@@ -634,17 +657,14 @@ def smoke_comms_budget(surfaces, *, decode_steps=None,
                        ici_bytes_per_s=None) -> CommsBudget:
     """The zoo CommsBudget: every step surface launches once per tick; the
     tick wall is decode_steps x the default TPOT objective; the ICI is the
-    running chip's (None off-accelerator, which un-gates the budget rule
-    rather than inventing a number)."""
+    running chip's (None on CPU, which un-gates the budget rule rather than
+    inventing a number)."""
     if ici_bytes_per_s is None:
         import jax
 
         from ..observability.xla import device_ici_bandwidth
 
-        try:
-            ici_bytes_per_s = device_ici_bandwidth(jax.devices()[0])
-        except Exception:
-            ici_bytes_per_s = None
+        ici_bytes_per_s = device_ici_bandwidth(jax.devices()[0])
     steps = decode_steps
     if steps is None:
         steps = max([s.get("loop_steps", 1) for s in surfaces] or [1])

@@ -22,8 +22,9 @@ Entry points (all return a ``Report``):
   without mutating optimizer bookkeeping.
 
 Nothing here executes the analyzed program and nothing raises out of the
-rule loop: a rule that crashes on an exotic jaxpr becomes an ``info``
-finding (rule-error), never an exception in the caller's training loop.
+rule loop: a rule that crashes on an exotic jaxpr becomes a ``high``
+finding (rule-error) — never an exception in the caller's training loop,
+and never a "clean" report from a lint whose rules did not run.
 """
 from __future__ import annotations
 
@@ -31,6 +32,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jex_core
 
 from .findings import BUILTIN_ALLOWLIST, HIGH, INFO, WARN, Finding
 
@@ -165,14 +167,14 @@ def _sub_jaxprs(params):
     for k, v in params.items():
         vs = v if isinstance(v, (tuple, list)) else (v,)
         for i, item in enumerate(vs):
-            if isinstance(item, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
+            if isinstance(item, (jex_core.Jaxpr, jex_core.ClosedJaxpr)):
                 tag = k if len(vs) == 1 else f"{k}[{i}]"
                 found.append((tag, item))
     return found
 
 
 def _as_open(j):
-    return j.jaxpr if isinstance(j, jax.core.ClosedJaxpr) else j
+    return j.jaxpr if isinstance(j, jex_core.ClosedJaxpr) else j
 
 
 def _eqn_scope(eqn, scope):
@@ -194,7 +196,7 @@ def _eqn_scope(eqn, scope):
 
 def iter_eqns(closed_jaxpr):
     """Yield (eqn, stack, axis_scope) over the whole program, recursing into
-    every sub-jaxpr. ``stack`` is a tuple like ('pjit:step_fn', 'scan');
+    every sub-jaxpr. ``stack`` is a tuple like ('jit:step_fn', 'scan');
     ``axis_scope`` the mesh/pmap axis names bound at that point."""
 
     def walk(jaxpr, stack, scope):
@@ -205,7 +207,7 @@ def iter_eqns(closed_jaxpr):
                 continue
             name = eqn.primitive.name
             label = name
-            if name in ("pjit", "closed_call", "core_call", "custom_vjp_call",
+            if name in ("jit", "closed_call", "core_call", "custom_vjp_call",
                         "custom_jvp_call", "remat", "checkpoint"):
                 label = f"{name}:{eqn.params.get('name', '')}".rstrip(":")
             inner_scope = _eqn_scope(eqn, scope)
@@ -222,7 +224,7 @@ def iter_consts(closed_jaxpr):
     those hoisted into nested ClosedJaxprs (jit closures land there)."""
 
     def walk(closed, stack):
-        if isinstance(closed, jax.core.ClosedJaxpr):
+        if isinstance(closed, jex_core.ClosedJaxpr):
             jaxpr = closed.jaxpr
             for var, val in zip(jaxpr.constvars, closed.consts):
                 yield var, val, stack
@@ -237,17 +239,13 @@ def iter_consts(closed_jaxpr):
 
 def source_of(eqn) -> str:
     """User-frame provenance of an equation, 'file:line (fn)' or ''."""
-    try:
-        from jax._src import source_info_util
+    from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is None:
-            return ""
-        fn = getattr(frame, "function_name", "") or ""
-        return (f"{frame.file_name}:{frame.start_line}"
-                + (f" ({fn})" if fn else ""))
-    except Exception:
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
         return ""
+    fn = frame.function_name
+    return f"{frame.file_name}:{frame.start_line}" + (f" ({fn})" if fn else "")
 
 
 # ------------------------------------------------------------ rule running
@@ -262,7 +260,7 @@ def _run_rules(prog, rules, allowlist):
         try:
             got = list(rule_fn(prog))
         except Exception as e:  # a broken rule must not break the caller
-            got = [Finding("rule-error", INFO,
+            got = [Finding("rule-error", HIGH,
                            f"rule {rule_id} crashed: {e!r}",
                            subject=prog.name)]
         cap = prog.thresholds.max_findings_per_rule
@@ -381,12 +379,11 @@ def analyze(fn, *args, _name=None, _mesh_axes=None, _hot=True,
     except Exception as e:
         # an unhashable static argument (itself a finding) aborts tracing;
         # report what can be judged without a jaxpr instead of raising
-        from .findings import INFO as _INFO
         from .rules import static_arg_findings
 
         findings = static_arg_findings(static_args)
         findings.append(Finding(
-            "rule-error", _INFO,
+            "rule-error", HIGH,
             f"program failed to trace, jaxpr rules skipped: {e!r}"[:300],
             subject=name))
         for f in findings:
@@ -396,13 +393,13 @@ def analyze(fn, *args, _name=None, _mesh_axes=None, _hot=True,
     donated = None
     n_in = len(closed.jaxpr.invars)
     eqns = closed.jaxpr.eqns
-    if (len(eqns) == 1 and eqns[0].primitive.name == "pjit"
+    if (len(eqns) == 1 and eqns[0].primitive.name == "jit"
             and "donated_invars" in eqns[0].params):
         # map per-eqn-operand flags back onto the outer invars (operand
         # order can differ from invar order when args are unused)
         flag_of = {v: d for v, d in zip(eqns[0].invars,
                                         eqns[0].params["donated_invars"])
-                   if not isinstance(v, jax.core.Literal)}
+                   if not isinstance(v, jex_core.Literal)}
         donated = tuple(flag_of.get(v, False) for v in closed.jaxpr.invars)
     elif _donate_argnums is not None:
         dn = set(_donate_argnums)

@@ -69,6 +69,8 @@ import json
 import math
 import os
 
+from jax.extend import core as jex_core
+
 from .core import Report, aval_bytes, fmt_bytes, source_of, _sub_jaxprs
 from .findings import HIGH, WARN, Allowlist, Finding
 
@@ -119,7 +121,7 @@ BUILTIN_HBM_ALLOWLIST = Allowlist([])
 def _is_var(v):
     import jax
 
-    return isinstance(v, jax.core.Var) and not isinstance(v, jax.core.DropVar)
+    return isinstance(v, jex_core.Var) and not isinstance(v, jax.core.DropVar)
 
 
 class _Buf:
@@ -202,16 +204,15 @@ def _unwrap_single_pjit(closed_jaxpr, donated):
     (an outer walk would hold every operand across the one eqn and
     donation could never release anything). Mirrors core.analyze's
     donation extraction off the pjit params."""
-    import jax
 
     jaxpr = closed_jaxpr.jaxpr
     eqns = jaxpr.eqns
     if (donated is None and len(eqns) == 1
-            and eqns[0].primitive.name == "pjit"
+            and eqns[0].primitive.name == "jit"
             and set(map(id, eqns[0].invars)) == set(map(id, jaxpr.invars))):
         inner = eqns[0].params.get("jaxpr")
         flags = eqns[0].params.get("donated_invars")
-        if isinstance(inner, jax.core.ClosedJaxpr) and flags is not None:
+        if isinstance(inner, jex_core.ClosedJaxpr) and flags is not None:
             return inner, tuple(flags)
     return closed_jaxpr, donated
 
@@ -304,7 +305,6 @@ def _inner_extra(eqn, depth):
     carries are pinned inside their body — the body's new-carry outputs
     then coexist with the pinned old carry, which is exactly the
     double-buffering XLA's loop lowering pays."""
-    import jax
 
     if depth > 24:
         return 0
@@ -314,7 +314,7 @@ def _inner_extra(eqn, depth):
     name = eqn.primitive.name
     extras = [0]
     for _tag, sub in subs:
-        if isinstance(sub, jax.core.ClosedJaxpr):
+        if isinstance(sub, jex_core.ClosedJaxpr):
             open_j = sub.jaxpr
             const_bytes = [getattr(c, "nbytes", aval_bytes(v.aval))
                            for v, c in zip(open_j.constvars, sub.consts)]
@@ -322,7 +322,7 @@ def _inner_extra(eqn, depth):
             open_j = sub
             const_bytes = []
         donated = ()
-        if name == "pjit":
+        if name == "jit":
             flags = eqn.params.get("donated_invars")
             if flags is not None:
                 donated = tuple(flags)
@@ -347,10 +347,9 @@ def estimate_peak(closed_jaxpr, *, donated=None, arg_names=None,
     ``donated``: per-invar flags; when omitted and the program is a single
     jitted call, the flags are read off its pjit equation (same extraction
     as core.analyze). ``top_k`` bounds the at-peak breakdown."""
-    import jax
 
     inner, donated = _unwrap_single_pjit(closed_jaxpr, donated)
-    if isinstance(inner, jax.core.ClosedJaxpr):
+    if isinstance(inner, jex_core.ClosedJaxpr):
         open_j = inner.jaxpr
         const_bytes = [getattr(c, "nbytes", aval_bytes(v.aval))
                        for v, c in zip(open_j.constvars, inner.consts)]

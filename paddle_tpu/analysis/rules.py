@@ -36,11 +36,13 @@ import re
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend import core as jex_core
 
 from .core import (
     Thresholds,
     _as_open,
     _sub_jaxprs,
+    aval_bytes,
     fmt_bytes,
     iter_consts,
     iter_eqns,
@@ -57,7 +59,8 @@ MXU_PRIMS = {"dot_general", "conv_general_dilated"}
 LAYOUT_PRIMS = {"transpose", "reshape", "broadcast_in_dim", "squeeze",
                 "slice", "dynamic_slice", "rev", "copy", "gather",
                 "concatenate"}
-CALLBACK_PRIMS = {"pure_callback", "io_callback", "debug_callback"}
+CALLBACK_PRIMS = {"pure_callback", "io_callback", "debug_callback",
+                  "debug_print"}
 COLLECTIVE_PRIMS = {"psum", "psum2", "pbroadcast", "pmax", "pmin",
                     "ppermute", "all_gather", "all_to_all", "psum_scatter",
                     "pgather", "axis_index"}
@@ -160,7 +163,7 @@ def _taint_walk(jaxpr, tainted, findings, stack, seen_f64):
                 seen_f64.append(source_of(eqn) or name)
         if name in MXU_PRIMS:
             hit = [tainted[v] for v in eqn.invars
-                   if not isinstance(v, jax.core.Literal) and v in tainted]
+                   if not isinstance(v, jex_core.Literal) and v in tainted]
             if hit:
                 out_dt = _np_dtype(eqn.outvars[0].aval.dtype)
                 findings.append(Finding(
@@ -184,15 +187,15 @@ def _taint_walk(jaxpr, tainted, findings, stack, seen_f64):
                     and _is_float(src_aval.dtype)):
                 if (jnp.dtype(src_aval.dtype) in NARROW and dst in WIDE):
                     tainted[eqn.outvars[0]] = jnp.dtype(src_aval.dtype).name
-                elif (not isinstance(src, jax.core.Literal)
+                elif (not isinstance(src, jex_core.Literal)
                       and src in tainted and dst in NARROW):
                     pass  # downcast back: taint does not propagate
-                elif (not isinstance(src, jax.core.Literal)
+                elif (not isinstance(src, jex_core.Literal)
                       and src in tainted):
                     tainted[eqn.outvars[0]] = tainted[src]
         elif name in LAYOUT_PRIMS:
             src = eqn.invars[0]
-            if not isinstance(src, jax.core.Literal) and src in tainted:
+            if not isinstance(src, jex_core.Literal) and src in tainted:
                 tainted[eqn.outvars[0]] = tainted[src]
         # recurse with taint mapped across the sub-jaxpr boundary
         subs = _sub_jaxprs(eqn.params)
@@ -207,7 +210,7 @@ def _taint_walk(jaxpr, tainted, findings, stack, seen_f64):
             else:
                 pairs = ()
             for outer_v, inner_v in pairs:
-                if (not isinstance(outer_v, jax.core.Literal)
+                if (not isinstance(outer_v, jex_core.Literal)
                         and outer_v in tainted):
                     inner[inner_v] = tainted[outer_v]
             _taint_walk(open_sub, inner, findings, stack + (name,), seen_f64)
@@ -270,16 +273,15 @@ def rule_constant_bloat(prog):
     HBM cost per executable + trace-time hashing + re-staging per compile."""
     th = prog.thresholds
     findings = []
-    for var, val, stack in iter_consts(prog.closed_jaxpr):
-        try:
-            nbytes = int(getattr(val, "nbytes", 0))
-        except Exception:
-            nbytes = 0
+    for var, _val, stack in iter_consts(prog.closed_jaxpr):
+        # sized off the constvar's aval: the value may be a jax literal
+        # wrapper (TypedNdArray) that carries no nbytes
+        nbytes = aval_bytes(var.aval)
         if nbytes < th.const_warn_bytes:
             continue
         sev = HIGH if nbytes >= th.const_high_bytes else WARN
-        shape = tuple(getattr(val, "shape", ()))
-        dtype = getattr(val, "dtype", "?")
+        shape = tuple(var.aval.shape)
+        dtype = var.aval.dtype
         findings.append(Finding(
             "constant-bloat", sev,
             f"constant {dtype}{list(shape)} ({fmt_bytes(nbytes)}) is baked "

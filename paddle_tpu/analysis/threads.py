@@ -774,7 +774,7 @@ def _emit_findings(model):
 
     for rel, err in model.parse_errors:
         findings.append(Finding(
-            "rule-error", INFO, f"{rel} failed to parse: {err}"[:300]))
+            "rule-error", HIGH, f"{rel} failed to parse: {err}"[:300]))
     return findings
 
 

@@ -32,16 +32,9 @@ def shard_map_unchecked(fn, mesh, in_specs, out_specs):
     """shard_map with the value-replication check off: collective results
     (all_gather/psum) are replicated across the axis but jax's
     varying-manual-axes check cannot infer that for replicated out_specs like
-    P(None); the collectives themselves guarantee it. The disabling kwarg was
-    renamed check_rep -> check_vma across jax releases — support both."""
-    from jax.experimental.shard_map import shard_map as _smap
-
-    try:
-        return _smap(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_vma=False)
-    except TypeError:
-        return _smap(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    P(None); the collectives themselves guarantee it."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 class ReduceOp:

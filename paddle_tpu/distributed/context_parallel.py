@@ -149,17 +149,11 @@ def ulysses_attention(q, k, v, axis_name="sep", causal=False, scale=None,
 def _local_attention(q, k, v, causal, scale):
     """Single-device attention on [B, S, H, D]; Pallas flash kernel when the
     shapes support it on TPU, fused-XLA softmax otherwise."""
-    try:
-        from ..ops.pallas import flash_attention as pfa
+    from ..ops.pallas import flash_attention as pfa
 
-        use_pallas = (jax.default_backend() == "tpu"
-                      and pfa.supports(tuple(q.shape), tuple(k.shape)))
-    except Exception:
-        use_pallas = False
-    if use_pallas:
-        from ..ops.pallas.flash_attention import flash_attention as _pallas_fa
-
-        return _pallas_fa(q, k, v, causal=causal, scale=scale)
+    if (jax.default_backend() == "tpu"
+            and pfa.supports(tuple(q.shape), tuple(k.shape))):
+        return pfa.flash_attention(q, k, v, causal=causal, scale=scale)
     qh = _bhsd(q).astype(jnp.float32)
     kh, vh = _broadcast_kv(qh, _bhsd(k).astype(jnp.float32),
                            _bhsd(v).astype(jnp.float32))

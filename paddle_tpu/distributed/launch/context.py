@@ -21,7 +21,11 @@ def free_port():
 def parse_args(argv=None):
     p = argparse.ArgumentParser(
         prog="python -m paddle_tpu.distributed.launch",
-        description="Launch a distributed paddle_tpu job (collective controller).",
+        description="Launch a distributed paddle_tpu job (collective "
+                    "controller). A TPU host takes ONE worker process, which "
+                    "drives every chip of the host: a chip belongs to one "
+                    "process at a time, so --backend tpu refuses "
+                    "--nproc_per_node > 1.",
     )
     p.add_argument("--master", default=None,
                    help="host:port of the rendezvous store / jax coordinator "
@@ -33,7 +37,8 @@ def parse_args(argv=None):
                    help="rank of this node [0, nnodes)")
     p.add_argument("--nproc_per_node", type=int,
                    default=int(os.environ.get("PADDLE_NPROC_PER_NODE", "1")),
-                   help="worker processes to spawn on this node")
+                   help="worker processes to spawn on this node (1 with "
+                        "--backend tpu: the one process owns all its chips)")
     p.add_argument("--backend", default=os.environ.get("PADDLE_DISTRI_BACKEND", "tpu"),
                    choices=["tpu", "cpu"],
                    help="device backend for workers (cpu = gloo collectives, for "
@@ -68,6 +73,15 @@ class Context:
         self.nnodes = args.nnodes
         self.node_rank = args.node_rank
         self.nproc_per_node = args.nproc_per_node
+        if args.backend == "tpu" and self.nproc_per_node > 1:
+            # nothing binds a worker to a subset of the host's chips, so N
+            # workers would each claim all of them and all but one hang
+            raise ValueError(
+                f"--backend tpu takes one worker process per host (got "
+                f"--nproc_per_node {self.nproc_per_node}): a chip belongs to "
+                f"one process, and that process drives every chip of the "
+                f"host. Use --nnodes for more hosts, or --backend cpu for a "
+                f"multi-process test job.")
         self.world_size = self.nnodes * self.nproc_per_node
         if args.master:
             host, port = args.master.rsplit(":", 1)

@@ -297,6 +297,52 @@ def mesh_axis_size(name, jax_mesh=None) -> int:
     return int(dict(zip(jm.axis_names, jm.devices.shape))[name])
 
 
+def per_shard(local_fn, args, dims, out_dims):
+    """Run `local_fn(*args)` once per (batch, head) shard of the current
+    compute mesh — for a call GSPMD cannot partition (a Mosaic kernel: "wrap
+    the call in a shard_map") whose math is independent per batch row and per
+    head. `dims` gives one string per argument, one letter per leading
+    dimension: "b" batch, "h" heads, "." anything else ("b.h." for
+    [B, S, H, D], "h" for a head-leading page pool, "" for a table every
+    shard reads whole); `out_dims` the same for the single output.
+
+    Batch shards over `dp` and heads over the tensor axis (`mp` | `tp`), each
+    only where the axis divides every such dimension. A head dimension of 1
+    broadcasts and stays replicated; GQA head counts must nest, so that a
+    block of q heads meets its own kv heads. Every other axis (sep, pp, ...)
+    sees the operands replicated. No mesh, one device, or already inside a
+    shard_map (ring attention made everything local): a direct call."""
+    jm = current_jax_mesh()
+    if (jm is None or jm.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return local_fn(*args)
+    sizes = dict(zip(jm.axis_names, jm.devices.shape))
+
+    def extents(letter):
+        return [a.shape[i] for a, d in zip(args, dims)
+                for i, c in enumerate(d) if c == letter]
+
+    def axis_for(names, ns):
+        return next((ax for ax in names if sizes.get(ax, 1) > 1
+                     and all(n % sizes[ax] == 0 for n in ns)), None)
+
+    nh = [n for n in extents("h") if n > 1]
+    batch = axis_for(("dp",), extents("b")) if extents("b") else None
+    heads = (axis_for(("mp", "tp"), nh)
+             if nh and all(n % min(nh) == 0 for n in nh) else None)
+
+    def spec(d, shape=None):
+        return PartitionSpec(*[
+            batch if c == "b"
+            else heads if c == "h" and (shape is None or shape[i] > 1)
+            else None for i, c in enumerate(d)])
+
+    return jax.shard_map(
+        local_fn, mesh=jm,
+        in_specs=tuple(spec(d, a.shape) for a, d in zip(args, dims)),
+        out_specs=spec(out_dims), check_vma=False)(*args)
+
+
 # ------------------------------------------------------------ serving layouts
 class SpecLayout:
     """Canonical partition entries for the ("dp","tp") serving mesh (SNIPPETS

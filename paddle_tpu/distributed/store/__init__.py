@@ -10,6 +10,7 @@ the same length-prefixed wire protocol documented in tcp_store.cc.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import socket
 import socketserver
@@ -18,19 +19,26 @@ import subprocess
 import threading
 import time
 
-_SO_NAME = "libtcp_store.so"
 _SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "tcp_store.cc")
 
 _CMD_SET, _CMD_GET, _CMD_ADD, _CMD_WAIT, _CMD_DEL, _CMD_NUM, _CMD_CLR = 1, 2, 3, 4, 5, 6, 7
 
 
 def _build_native():
-    """Compile tcp_store.cc to a shared library next to it (cached)."""
-    so_path = os.path.join(os.path.dirname(_SRC), _SO_NAME)
-    if os.path.exists(so_path) and os.path.getmtime(so_path) >= os.path.getmtime(_SRC):
-        return so_path
-    cmd = ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", "-pthread", _SRC, "-o", so_path]
-    subprocess.run(cmd, check=True, capture_output=True)
+    """Compile tcp_store.cc to a shared library next to it, cached under the
+    source's content hash (utils/cpp_extension.load's key): a copied tree
+    rewrites mtimes, so "the .so is newer" proves nothing about which source
+    built it."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    so_path = os.path.join(os.path.dirname(_SRC), f"libtcp_store_{digest}.so")
+    if not os.path.exists(so_path):
+        # build aside and rename: workers starting together must never load
+        # a half-written library
+        tmp = f"{so_path}.{os.getpid()}.tmp"
+        cmd = ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", "-pthread", _SRC, "-o", tmp]
+        subprocess.run(cmd, check=True, capture_output=True)
+        os.replace(tmp, so_path)
     return so_path
 
 

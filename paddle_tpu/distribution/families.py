@@ -370,11 +370,9 @@ class Binomial(Distribution):
         # jax.random.binomial mixes a f32 literal into lax.clamp internally,
         # which breaks under the global x64 flag (f64 operands) — sample in
         # plain f32 with x64 off; the return dtype is f32 either way
-        from jax.experimental import enable_x64
-
         n = jnp.asarray(self.total_count, jnp.float32)
         p = jnp.asarray(self.probs, jnp.float32)
-        with enable_x64(False):
+        with jax.enable_x64(False):
             out = jax.random.binomial(_rng.next_key(), n, p, shape=shape)
         return Tensor(out.astype(jnp.float32))
 

@@ -52,8 +52,20 @@ class CPUPlace(Place):
 
 
 class TPUPlace(Place):
+    """The accelerator with this index. Asking for one that is not there
+    raises: a CPU handed back under the name of a TPU hides the device."""
+
     def __init__(self, device_id: int = 0):
-        super().__init__(jax.devices()[device_id])
+        chips = [d for d in jax.devices() if d.platform != "cpu"]
+        if not chips:
+            raise RuntimeError(
+                f"no accelerator in this process: jax.devices() is "
+                f"{jax.devices()}")
+        if not 0 <= device_id < len(chips):
+            raise ValueError(
+                f"device index {device_id} out of range: this process has "
+                f"{len(chips)} {chips[0].platform} device(s)")
+        super().__init__(chips[device_id])
 
 
 # Alias so scripts written for the reference's `CUDAPlace(0)` keep running on the accelerator.
@@ -85,11 +97,7 @@ def set_device(device) -> Place:
         idx = int(idx)
     else:
         plat, idx = name, 0
-    if plat == "cpu":
-        _current_device = CPUPlace()
-    else:
-        devs = jax.devices()
-        _current_device = Place(devs[min(idx, len(devs) - 1)])
+    _current_device = CPUPlace() if plat == "cpu" else TPUPlace(idx)
     return _current_device
 
 

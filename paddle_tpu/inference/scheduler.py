@@ -252,7 +252,9 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                          (inference/warmup.py). Default False: ready
                          immediately, programs build lazily, sentinel off.
     compile_cache_dir    optional persistent XLA compile-cache directory
-                         (warmup runs point the process at it); a restarted
+                         (warmup runs point the process at it unless
+                         JAX_COMPILATION_CACHE_DIR already names one — the
+                         environment wins, jit/compile_cache.py); a restarted
                          process reuses the serialized executables and pays
                          trace time only — the docs/DEPLOYMENT.md cold-start
                          runbook knob. Meaningful with warmup=True.

@@ -923,9 +923,10 @@ class InferenceServer:
 
             def _sampler_knobs(self):
                 """Per-request sampler knobs over HTTP (ROADMAP item 1):
-                X-Temperature / X-Top-K / X-Spec ride the continuous
-                scheduler's traced infer(temperature=, top_k=, spec=) path
-                — no recompile, no server restart. A malformed value is a
+                X-Temperature / X-Top-K / X-Spec / X-Max-New-Tokens ride
+                the continuous scheduler's traced infer(temperature=,
+                top_k=, spec=, max_new_tokens=) path — no recompile, no
+                server restart. A malformed value is a
                 client bug: ValueError -> 400 via _fail_http, never a
                 silently-applied default (unlike X-Timeout-Ms, where
                 clamping is the safe interpretation)."""
@@ -960,6 +961,20 @@ class InferenceServer:
                         raise ValueError(
                             f"malformed X-Spec {s!r} (on|off)")
                     kw["spec"] = sv == "on"
+                n = self.headers.get("X-Max-New-Tokens")
+                if n is not None:
+                    # the per-request output budget infer(max_new_tokens=)
+                    # already takes (clamped to the server cap there)
+                    try:
+                        nv = int(n)
+                    except ValueError:
+                        raise ValueError(
+                            f"malformed X-Max-New-Tokens {n!r}") from None
+                    if nv < 1:
+                        raise ValueError(
+                            f"X-Max-New-Tokens out of range: {n!r} "
+                            "(need >= 1)")
+                    kw["max_new_tokens"] = nv
                 if kw and not getattr(outer.generator,
                                       "supports_sampler_knobs", False):
                     raise ValueError(

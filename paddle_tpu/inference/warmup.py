@@ -10,7 +10,8 @@ honor it:
   lands in the shared ``GenerationMixin._generate_cache`` before the
   predictor reports ready. Idle launches are write-free: the valid masks
   drop every KV scatter and commit() re-installs byte-identical pools, so
-  warmup is safe next to a live pool. With ``cache_dir`` set, XLA's
+  warmup is safe next to a live pool. With ``cache_dir`` set
+  (jit/compile_cache.py; ``JAX_COMPILATION_CACHE_DIR`` overrides it), XLA's
   persistent compilation cache turns a process restart into a warm start
   (trace only — the docs/DEPLOYMENT.md cold-start runbook).
 
@@ -34,11 +35,11 @@ import numpy as np
 
 from ..analysis.compilesurface import ServingConfig
 from ..analysis.lockwitness import make_lock
+from ..jit.compile_cache import enable_compile_cache
 from ..jit.fingerprint import aval_fingerprint
 
 __all__ = ["AOTWarmup", "CompileSentinel", "serving_config_of",
-           "enable_persistent_compile_cache", "activate", "deactivate",
-           "notify"]
+           "activate", "deactivate", "notify"]
 
 
 # ------------------------------------------------------------ the sentinel
@@ -98,33 +99,6 @@ def serving_config_of(predictor) -> ServingConfig:
             predictor.adapters.signature()
             if getattr(predictor, "adapters", None) is not None else None),
     )
-
-
-def enable_persistent_compile_cache(cache_dir):
-    """Point XLA's persistent compilation cache at `cache_dir` and lower
-    the entry thresholds so every step program caches (the defaults skip
-    fast compiles). A restarted process with the same dir pays trace time
-    only — the cold-start runbook knob (docs/DEPLOYMENT.md).
-
-    The cache backend initializes lazily at the process's FIRST compile
-    and ignores later config updates — and by the time the warmup thread
-    runs, building the model has already compiled something. reset_cache()
-    forces re-initialization against the new dir (it only drops the stale
-    backend handle, not any compiled program)."""
-    import jax
-
-    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:       # older jax: knob absent, defaults apply
-            pass
-    try:
-        from jax._src.compilation_cache import reset_cache
-        reset_cache()
-    except Exception:           # private API moved: first-compile-wins then
-        pass
 
 
 class AOTWarmup:
@@ -199,7 +173,7 @@ class AOTWarmup:
         tr = self._tracer
         t_us = tr.now_us() if tr is not None and tr.enabled else None
         if self._cache_dir:
-            enable_persistent_compile_cache(self._cache_dir)
+            enable_compile_cache(self._cache_dir)
         cfg = self.config()
         keys = cfg.program_keys()
         cache = pred.model._runner_cache()

@@ -1,7 +1,7 @@
 """Reference training models (SURVEY.md §7.0: the model zoo lives downstream in the
 reference; these are the in-repo baseline-config drivers)."""
 from .gpt import (  # noqa: F401
-    GPTConfig, GPTForCausalLM, GPTModel, gpt3_1p3b, gpt_tiny,
+    GPTConfig, GPTForCausalLM, GPTModel, gpt3_1p3b, gpt_350m, gpt_tiny,
 )
 from .llama import (  # noqa: F401
     LlamaConfig, LlamaForCausalLM, LlamaModel, llama2_7b, llama_tiny,

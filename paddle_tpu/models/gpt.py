@@ -344,6 +344,14 @@ def gpt3_1p3b():
                      use_rope=False, use_rms_norm=False, use_swiglu=False)
 
 
+def gpt_350m(max_position=1024):
+    """GPT-350M (GPT-medium class, rope / RMSNorm / SwiGLU): the ONE width
+    bench.py times and chip_smoke.py brings up, so they stay comparable."""
+    return GPTConfig(vocab_size=50304, hidden_size=1024, num_layers=24,
+                     num_heads=16, max_position=max_position, use_rope=True,
+                     use_rms_norm=True, use_swiglu=True)
+
+
 def gpt_tiny():
     return GPTConfig(vocab_size=1024, hidden_size=64, num_layers=2, num_heads=4,
                      max_position=128)

@@ -68,18 +68,10 @@ def _same_cu(cu_q, cu_k):
 
 
 def _use_pallas(q_shape, k_shape) -> bool:
-    if not _sdp_config["enable_flash"]:
+    if not _sdp_config["enable_flash"] or jax.default_backend() != "tpu":
         return False
-    try:
-        dev = jax.devices()[0].platform
-    except Exception:
-        return False
-    if dev in ("cpu", "gpu"):
-        return False
-    try:
-        from ...ops.pallas import flash_attention as pfa
-    except ImportError:
-        return False
+    from ...ops.pallas import flash_attention as pfa
+
     # pallas pays off once the [B,H,S,S] score tensor would round-trip HBM
     return q_shape[1] >= 1024 and pfa.supports(tuple(q_shape), tuple(k_shape))
 

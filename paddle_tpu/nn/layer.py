@@ -14,9 +14,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..analysis.lockwitness import make_rlock
 from ..framework import dtype as _dt
 from ..tensor import Tensor
 from . import initializer as I
+
+# functional_call rebinds parameter payloads IN PLACE for the length of one
+# forward (a trace, under jit). Threads that share a model — fleet replicas
+# warming the same step programs — must neither trace at once nor snapshot
+# the state meanwhile: they would read, cast and cache the other thread's
+# tracers. One lock for every layer (a per-layer lock made lazily would
+# itself race); it is held while tracing a forward, never across a compile.
+_BIND_LOCK = make_rlock("layer._BIND_LOCK")
 
 
 class ParamAttr:
@@ -326,7 +335,8 @@ class Layer:
     # ------------------------------------------------------------------ functional path
     def raw_state(self):
         """pytree of raw jax arrays: {name: array} for params + persistable buffers."""
-        return {k: v._value for k, v in self.state_dict().items()}
+        with _BIND_LOCK:
+            return {k: v._value for k, v in self.state_dict().items()}
 
     def load_raw_state(self, raw):
         sd = self.state_dict()
@@ -343,25 +353,26 @@ class Layer:
         entries the forward reassigned in place (batch-norm running mean/var). The
         compiled TrainStep threads these out as aux outputs so running statistics
         survive the restore below."""
-        sd = self.state_dict()
-        saved = {k: t._value for k, t in sd.items()}
-        saved_sg = {k: t.stop_gradient for k, t in sd.items()}
-        try:
-            for k, v in raw_state.items():
-                if k in sd:
-                    sd[k]._value = v
-                    sd[k].stop_gradient = True  # tape off inside functional path
-            out = self(*args, **kwargs)
-            if _capture_mutations is not None:
+        with _BIND_LOCK:
+            sd = self.state_dict()
+            saved = {k: t._value for k, t in sd.items()}
+            saved_sg = {k: t.stop_gradient for k, t in sd.items()}
+            try:
+                for k, v in raw_state.items():
+                    if k in sd:
+                        sd[k]._value = v
+                        sd[k].stop_gradient = True  # tape off inside functional path
+                out = self(*args, **kwargs)
+                if _capture_mutations is not None:
+                    for k, t in sd.items():
+                        set_to = raw_state.get(k, saved[k])
+                        if t._value is not set_to:
+                            _capture_mutations[k] = t._value
+                return out
+            finally:
                 for k, t in sd.items():
-                    set_to = raw_state.get(k, saved[k])
-                    if t._value is not set_to:
-                        _capture_mutations[k] = t._value
-            return out
-        finally:
-            for k, t in sd.items():
-                t._value = saved[k]
-                t.stop_gradient = saved_sg[k]
+                    t._value = saved[k]
+                    t.stop_gradient = saved_sg[k]
 
     def clear_gradients(self, set_to_zero=False):
         for p in self.parameters():

@@ -287,12 +287,9 @@ class StepMonitor:
 
     def peak_flops(self):
         if self._peak_flops == "auto":
-            try:
-                import jax
+            import jax
 
-                self._peak_flops = device_peak_flops(jax.devices()[0])
-            except Exception:
-                self._peak_flops = None
+            self._peak_flops = device_peak_flops(jax.devices()[0])
         return self._peak_flops
 
     # ------------------------------------------------------- TrainStep hooks

@@ -111,14 +111,10 @@ class UtilizationLedger:
                  gap_samples=1024):
         if peak_flops is None:
             if device is None:
-                try:
-                    import jax
+                import jax
 
-                    device = jax.devices()[0]
-                except Exception:
-                    device = None
-            if device is not None:
-                peak_flops = device_peak_flops(device)
+                device = jax.devices()[0]
+            peak_flops = device_peak_flops(device)
         self.peak_flops = peak_flops
         self._clock = clock
         self.mfu_window_s = float(mfu_window_s)

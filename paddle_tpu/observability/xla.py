@@ -17,12 +17,13 @@ surfaces across jax versions and backends:
   creeping-toward-OOM alert wants.  Backends with no CompiledMemoryStats fall
   back to the static estimator (analysis/hbm.py), tagged ``estimated=True``.
 * ``device_peak_flops`` — per-chip dense bf16 peak (public TPU specs), the
-  denominator of MFU.  ``None`` off-accelerator so MFU degrades to "absent",
-  never to a made-up number.
+  denominator of MFU.  ``None`` on CPU so MFU degrades to "absent", never to
+  a made-up number; an accelerator whose ``device_kind`` is not in the table
+  RAISES — a chip that reports ``mfu: null`` and "ok" hides the device.
 
-Everything here is defensive: an introspection surface a backend does not
-implement yields ``{}`` / ``0.0`` / ``None``, never an exception — telemetry
-must not be able to take down the training loop it watches.
+The compiled-program readers are defensive: an introspection surface a
+backend does not implement yields ``{}`` / ``0.0``, never an exception —
+telemetry must not be able to take down the training loop it watches.
 """
 from __future__ import annotations
 
@@ -44,14 +45,23 @@ PEAK_BF16_FLOPS = {
 }
 
 
-def device_peak_flops(device) -> float | None:
-    """Dense bf16 peak FLOP/s of `device`, or None when unknown (CPU, new
-    chip revisions): MFU is reported only when the denominator is real."""
-    kind = getattr(device, "device_kind", "") or ""
-    for name, peak in PEAK_BF16_FLOPS.items():
+def _per_chip(table, device, what):
+    if device.platform == "cpu":
+        return None
+    kind = device.device_kind
+    for name, value in table.items():
         if kind.startswith(name):
-            return peak
-    return None
+            return value
+    raise ValueError(
+        f"no {what} for device_kind {kind!r} (platform {device.platform!r}):"
+        " add the chip to the table in observability/xla.py with its source")
+
+
+def device_peak_flops(device) -> float | None:
+    """Dense bf16 peak FLOP/s of `device`; None on CPU (MFU is reported only
+    when the denominator is real). An accelerator missing from the table is
+    a ValueError, not a silent None."""
+    return _per_chip(PEAK_BF16_FLOPS, device, "bf16 peak FLOP/s")
 
 
 # Per-chip aggregate ICI bandwidth in BYTES/s (public TPU specs: v3
@@ -72,14 +82,10 @@ ICI_BANDWIDTH_BYTES = {
 
 
 def device_ici_bandwidth(device) -> float | None:
-    """Per-chip ICI bandwidth of `device` in bytes/s, or None when unknown
-    (CPU, new chip revisions): the comms budget gate runs only when the
-    denominator is real, same contract as device_peak_flops."""
-    kind = getattr(device, "device_kind", "") or ""
-    for name, bw in ICI_BANDWIDTH_BYTES.items():
-        if kind.startswith(name):
-            return bw
-    return None
+    """Per-chip ICI bandwidth of `device` in bytes/s; None on CPU, ValueError
+    for an accelerator missing from the table — same contract as
+    device_peak_flops."""
+    return _per_chip(ICI_BANDWIDTH_BYTES, device, "ICI bandwidth")
 
 
 def cost_analysis(compiled) -> dict:

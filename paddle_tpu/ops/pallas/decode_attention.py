@@ -98,8 +98,8 @@ def _norm_lengths(lengths, B):
 
 
 # -------------------------------------------------------------- kernel body
-def _partials_body(length, col0, q, k, v, o_ref, m_ref, l_ref, *, scale, g):
-    """Block-local (m, l, o) partials for one (batch*head, kv-block) step.
+def _partials(length, col0, q, k, v, *, scale, g):
+    """Block-local (o, m, l) partials for one (batch*head, kv-block) step.
     q: [SG, D] (S query steps × G grouped q heads, row-major (s, g));
     k/v: [BK, D]."""
     sg, bk = q.shape[0], k.shape[0]
@@ -120,16 +120,24 @@ def _partials_body(length, col0, q, k, v, o_ref, m_ref, l_ref, *, scale, g):
     l = jnp.sum(e, axis=1, keepdims=True)
     o = jax.lax.dot_general(e.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    o_ref[0, 0] = o
-    m_ref[0, 0] = m
-    l_ref[0, 0] = l
+    return o, m, l
 
 
-def _write_dead(o_ref, m_ref, l_ref):
+def _dead_partials(sg, d):
     # partials that contribute nothing under the stage-2 rescale
-    o_ref[0, 0] = jnp.zeros_like(o_ref[0, 0])
-    m_ref[0, 0] = jnp.full_like(m_ref[0, 0], jnp.float32(_NEG))
-    l_ref[0, 0] = jnp.zeros_like(l_ref[0, 0])
+    return (jnp.zeros((sg, d), jnp.float32),
+            jnp.full((sg, 1), _NEG, jnp.float32),
+            jnp.zeros((sg, 1), jnp.float32))
+
+
+def _store_partials(lead, refs, partials):
+    """Write (o, m, l) at the block's leading unit indices. Indexed stores
+    only: a `ref.at[...]` view of an output block whose lane extent (D=64, or
+    the 1-wide m/l columns) is narrower than the 128-lane tile is a
+    tpu.memref_slice Mosaic refuses ("Slice shape ... must be aligned to
+    tiling (128)", libtpu 0.0.34)."""
+    for ref, val in zip(refs, partials):
+        ref[lead] = val
 
 
 def _splitkv_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
@@ -139,15 +147,16 @@ def _splitkv_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
     length = len_ref[jax.lax.div(bh, jnp.int32(hkv)), 0]
     col0 = j * block_k
     live = col0 < length + s_new
+    outs = (o_ref, m_ref, l_ref)
 
     @pl.when(live)
     def _body():
-        _partials_body(length, col0, q_ref[0], k_ref[0], v_ref[0],
-                       o_ref, m_ref, l_ref, scale=scale, g=g)
+        _store_partials((0, 0), outs, _partials(
+            length, col0, q_ref[0], k_ref[0], v_ref[0], scale=scale, g=g))
 
     @pl.when(jnp.logical_not(live))
     def _dead():
-        _write_dead(o_ref, m_ref, l_ref)
+        _store_partials((0, 0), outs, _dead_partials(*q_ref.shape[1:]))
 
 
 def _combine_partials(o_p, m_p, l_p, B, Hkv, S, G, D, dtype):
@@ -252,30 +261,17 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     length = len_ref[b]
     col0 = j * block_size
     live = col0 < length + s_new
+    outs = (o_ref, m_ref, l_ref)
 
     @pl.when(live)
     def _body():
-        _partials_body(length, col0, q_ref[0, 0], k_ref[0, 0], v_ref[0, 0],
-                       o_ref.at[0], m_ref.at[0], l_ref.at[0], scale=scale, g=g)
+        _store_partials((0, 0, 0), outs, _partials(
+            length, col0, q_ref[0, 0], k_ref[0, 0], v_ref[0, 0],
+            scale=scale, g=g))
 
     @pl.when(jnp.logical_not(live))
     def _dead():
-        _write_dead(o_ref.at[0], m_ref.at[0], l_ref.at[0])
-
-
-def _tp_shard_mesh(Hq, Hkv):
-    """The active jax mesh iff it carries a tp axis that head-shards this
-    shape: tp > 1 dividing Hkv (Hq follows — GQA groups are contiguous, so a
-    block-shard of Hq aligns with the local kv heads). None otherwise."""
-    from ...distributed.mesh import current_jax_mesh, mesh_axis_size
-
-    jm = current_jax_mesh()
-    if jm is None or "tp" not in jm.axis_names:
-        return None
-    tp = mesh_axis_size("tp", jm)
-    if tp <= 1 or Hkv % tp != 0 or Hq % Hkv != 0:
-        return None
-    return jm
+        _store_partials((0, 0, 0), outs, _dead_partials(*q_ref.shape[2:]))
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
@@ -291,34 +287,25 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
     BlockSpec index_map picks page tbl[b, j] directly — the PagedAttention
     access pattern, no gather materialization.
 
-    Under a serving mesh with a tp axis (ISSUE-12), the whole call shard_maps
-    over the head axis: each chip runs the split-KV kernel on its LOCAL heads
-    against its LOCAL pool shard (attention is head-local, so no collective is
-    needed here — the only cross-chip exchange per launch is the sampled-logit
-    gather after the vocab-sharded lm_head).
+    Under a mesh with a tensor axis (the serving mesh's tp, ISSUE-12), the
+    whole call runs per head shard (`distributed.mesh.per_shard`): each chip
+    runs the split-KV kernel on its LOCAL heads against its LOCAL pool shard
+    (attention is head-local, so no collective is needed here — the only
+    cross-chip exchange per launch is the sampled-logit gather after the
+    vocab-sharded lm_head). The slot dimension stays replicated: the serving
+    mesh's dp is the replica axis, not a batch axis.
     """
-    B, S, Hq, D = q.shape
-    Hkv = k_pages.shape[0]
+    B, D = q.shape[0], q.shape[3]
     if scale is None:
         scale = 1.0 / math.sqrt(D)
-    jm = _tp_shard_mesh(Hq, Hkv)
-    if jm is not None:
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as P
+    from ...distributed.mesh import per_shard
 
-        inner = functools.partial(_paged_decode_attention_impl,
-                                  scale=float(scale), kernel=kernel)
-        fn = shard_map(
-            inner, mesh=jm,
-            in_specs=(P(None, None, "tp", None), P("tp"), P("tp"),
-                      P(None, None), P(None)),
-            out_specs=P(None, None, "tp", None),
-            check_rep=False)
-        return fn(q, k_pages, v_pages,
-                  jnp.asarray(block_tables, jnp.int32),
-                  _norm_lengths(lengths, B))
-    return _paged_decode_attention_impl(q, k_pages, v_pages, block_tables,
-                                        lengths, scale=scale, kernel=kernel)
+    return per_shard(
+        functools.partial(_paged_decode_attention_impl, scale=float(scale),
+                          kernel=kernel),
+        (q, k_pages, v_pages, jnp.asarray(block_tables, jnp.int32),
+         _norm_lengths(lengths, B)),
+        ("..h.", "h", "h", "", ""), "..h.")
 
 
 def _paged_decode_attention_impl(q, k_pages, v_pages, block_tables, lengths,

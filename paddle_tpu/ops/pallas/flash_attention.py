@@ -31,10 +31,17 @@ _NEG = float(jnp.finfo(jnp.float32).min)
 
 
 def _interpret() -> bool:
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
+    """Interpret mode is the CPU path (CI) and nothing else: on the TPU the
+    kernels compile through Mosaic, and any other backend is an error rather
+    than a quiet interpreter run under the kernel's name."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
         return True
+    raise RuntimeError(
+        f"the Pallas kernels run compiled on 'tpu' and interpreted on 'cpu'; "
+        f"jax.default_backend() is {backend!r}")
 
 
 def _no_x64():
@@ -42,9 +49,7 @@ def _no_x64():
     under x64 pallas' internal index arithmetic emits i64 ops Mosaic cannot
     legalize. Kernel dtypes here are all explicit, so tracing the pallas_call
     with x64 off is semantics-preserving."""
-    from jax.experimental import enable_x64
-
-    return enable_x64(False)
+    return jax.enable_x64(False)
 
 
 # --------------------------------------------------------------------------- masks
@@ -521,17 +526,23 @@ def supports(q_shape, k_shape, block_q=128) -> bool:
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None):
     """Pallas flash attention over paddle layout [B, S, H, D]; GQA via kv-head
     broadcast. Differentiable (custom VJP flash backward)."""
-    b, s, h, d = q.shape
+    s, d = q.shape[1], q.shape[3]
     if block_q is None:
         block_q = _auto_block_q(s)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    n_rep = h // k.shape[2]
-    k = _repeat_kv(k, n_rep)
-    v = _repeat_kv(v, n_rep)
-    out = _flash(_to_bhsd(q), _to_bhsd(k), _to_bhsd(v), bool(causal), float(scale),
-                 int(block_q))
-    return _from_bhsd(out, b, h)
+
+    def local(q, k, v):
+        b, h = q.shape[0], q.shape[2]
+        n_rep = h // k.shape[2]
+        k, v = _repeat_kv(k, n_rep), _repeat_kv(v, n_rep)
+        out = _flash(_to_bhsd(q), _to_bhsd(k), _to_bhsd(v), bool(causal),
+                     float(scale), int(block_q))
+        return _from_bhsd(out, b, h)
+
+    from ...distributed.mesh import per_shard
+
+    return per_shard(local, (q, k, v), ("b.h.",) * 3, "b.h.")
 
 
 def flashmask_attention(q, k, v, startend_row_indices, causal=True, scale=None,
@@ -539,19 +550,25 @@ def flashmask_attention(q, k, v, startend_row_indices, causal=True, scale=None,
     """FlashMask (reference flash_attention.py:1299): startend_row_indices
     [B, H'|1, S, n] sparse-mask encoding evaluated inside the kernel — no
     [B, H, S, S] mask materialisation."""
-    b, s, h, d = q.shape
+    s, d = q.shape[1], q.shape[3]
     if block_q is None:
         block_q = _auto_block_q(s)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    n_rep = h // k.shape[2]
-    k = _repeat_kv(k, n_rep)
-    v = _repeat_kv(v, n_rep)
-    sri = startend_row_indices.astype(jnp.int32)
-    hp = sri.shape[1]
-    if hp == 1 and h > 1:
-        sri = jnp.broadcast_to(sri, (b, h, sri.shape[2], sri.shape[3]))
-    sri = sri.reshape(b * h, sri.shape[2], sri.shape[3])
-    out = _flash_masked(_to_bhsd(q), _to_bhsd(k), _to_bhsd(v), sri, bool(causal),
-                        float(scale), int(block_q))
-    return _from_bhsd(out, b, h)
+
+    def local(q, k, v, sri):
+        b, h = q.shape[0], q.shape[2]
+        n_rep = h // k.shape[2]
+        k, v = _repeat_kv(k, n_rep), _repeat_kv(v, n_rep)
+        if sri.shape[1] == 1 and h > 1:
+            sri = jnp.broadcast_to(sri, (b, h, sri.shape[2], sri.shape[3]))
+        sri = sri.reshape(b * h, sri.shape[2], sri.shape[3])
+        out = _flash_masked(_to_bhsd(q), _to_bhsd(k), _to_bhsd(v), sri,
+                            bool(causal), float(scale), int(block_q))
+        return _from_bhsd(out, b, h)
+
+    from ...distributed.mesh import per_shard
+
+    return per_shard(
+        local, (q, k, v, startend_row_indices.astype(jnp.int32)),
+        ("b.h.", "b.h.", "b.h.", "bh.."), "b.h.")

@@ -389,7 +389,9 @@ def from_dlpack(dlpack):
 
 
 def to_dlpack(x):
-    return jax.dlpack.to_dlpack(_val(x)) if hasattr(jax, "dlpack") else _val(x)
+    """The array is its own DLPack exporter (`__dlpack__`/`__dlpack_device__`),
+    which is what `from_dlpack` consumers take; bare capsules are refused."""
+    return _val(x)
 
 
 def set_printoptions(precision=None, threshold=None, edgeitems=None,

@@ -69,7 +69,7 @@ def test_all_reduce_prod_in_trace():
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     import paddle_tpu.distributed as dist
 
@@ -134,7 +134,7 @@ def test_batch_isend_irecv_bidirectional():
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     import paddle_tpu.distributed as dist
     from paddle_tpu.distributed.collective import P2POp, batch_isend_irecv, isend, irecv
@@ -163,7 +163,7 @@ def test_batch_isend_irecv_bidirectional():
     vals = jnp.arange(W, dtype=jnp.float32)
     out = np.asarray(
         jax.jit(shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
-                          check_rep=False))(vals))
+                          check_vma=False))(vals))
     # rank r receives fwd payload from r-1 (= r-1+100) and bwd from r+1 (= r+1+200)
     for r in range(W):
         assert out[r, 0] == (r - 1) % W + 100.0, out

@@ -43,10 +43,30 @@ multichip = pytest.mark.skipif(
 # -done that must NOT be counted (the -start carries the transfer). The
 # all-reduce lives inside the decode scan (``/while/`` in op_name) so it
 # multiplies by loop_steps.
+# Provenance is printed as an index: ops carry ``stack_frame_id=N`` and the
+# FileNames / FileLocations / StackFrames header blocks resolve it.
 _HLO = """\
+HloModule jit_step
+
+FileNames
+1 "/w/paddle_tpu/models/generation.py"
+2 "/w/paddle_tpu/nn/functional/common.py"
+
+FunctionNames
+1 "sample"
+2 "linear"
+
+FileLocations
+1 {file_name_id=1 function_name_id=1 line=149 end_line=149 column=4 end_column=9}
+2 {file_name_id=2 function_name_id=2 line=25 end_line=25 column=4 end_column=9}
+
+StackFrames
+1 {file_location_id=1 parent_frame_id=1}
+2 {file_location_id=2 parent_frame_id=2}
+
 ENTRY %main {
-  %ag = f32[2,512]{1,0} all-gather(f32[2,256]{1,0} %p0), replica_groups=[1,2]<=[2], dimensions={1}, metadata={op_name="jit(step)/reduce" source_file="/w/paddle_tpu/models/generation.py" source_line=149}
-  %ar = f32[8]{0} all-reduce(f32[8]{0} %x), replica_groups={{0,1}}, to_apply=%add, metadata={op_name="jit(step)/while/body/dot_general" source_file="/w/paddle_tpu/nn/functional/common.py" source_line=25}
+  %ag = f32[2,512]{1,0} all-gather(f32[2,256]{1,0} %p0), replica_groups=[1,2]<=[2], dimensions={1}, metadata={op_name="jit(step)/reduce" stack_frame_id=1}
+  %ar = f32[8]{0} all-reduce(f32[8]{0} %x), replica_groups={{0,1}}, to_apply=%add, metadata={op_name="jit(step)/while/body/dot_general" stack_frame_id=2}
   %rs = f32[4]{0} reduce-scatter(f32[8]{0} %x), replica_groups={{0,1}}, dimensions={0}
   %aa = f32[8]{0} all-to-all(f32[8]{0} %x), replica_groups={{0,1}}, metadata={op_name="jit(sort)/sort"}
   %cp = f32[4]{0} collective-permute(f32[4]{0} %x), source_target_pairs={{0,1},{1,0}}

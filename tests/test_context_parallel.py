@@ -7,7 +7,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import paddle_tpu as paddle
@@ -51,7 +51,7 @@ def test_ring_attention_parity(causal, n):
 
     out = jax.jit(shard_map(
         f, mesh=mesh, in_specs=P(None, "sep"), out_specs=P(None, "sep"),
-        check_rep=False))(q, k, v)
+        check_vma=False))(q, k, v)
     ref = _reference(q, k, v, causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
@@ -65,7 +65,7 @@ def test_ring_attention_grad_parity(causal):
         f = shard_map(
             lambda a, b, c: ring_attention(a, b, c, axis_name="sep", causal=causal),
             mesh=mesh, in_specs=P(None, "sep"), out_specs=P(None, "sep"),
-            check_rep=False)
+            check_vma=False)
         return jnp.sum(f(q_, k_, v_) ** 2)
 
     def loss_ref(q_, k_, v_):
@@ -87,7 +87,7 @@ def test_ulysses_attention_parity(causal):
 
     out = jax.jit(shard_map(
         f, mesh=mesh, in_specs=P(None, "sep"), out_specs=P(None, "sep"),
-        check_rep=False))(q, k, v)
+        check_vma=False))(q, k, v)
     ref = _reference(q, k, v, causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
@@ -101,7 +101,7 @@ def test_ring_attention_gqa():
     out = jax.jit(shard_map(
         lambda a, b, c: ring_attention(a, b, c, axis_name="sep", causal=True),
         mesh=mesh, in_specs=P(None, "sep"), out_specs=P(None, "sep"),
-        check_rep=False))(q, k, v)
+        check_vma=False))(q, k, v)
     kr = jnp.repeat(k, 2, axis=2)
     vr = jnp.repeat(v, 2, axis=2)
     ref = _reference(q, kr, vr, True)
@@ -113,7 +113,7 @@ def test_split_sequence():
     mesh = _sep_mesh(4)
     out = jax.jit(shard_map(
         lambda v: split_sequence(v, "sep", seq_dim=1),
-        mesh=mesh, in_specs=P(), out_specs=P(None, "sep"), check_rep=False))(x)
+        mesh=mesh, in_specs=P(), out_specs=P(None, "sep"), check_vma=False))(x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x))
 
 
@@ -160,5 +160,5 @@ def test_sp_scatter_gather_explicit():
         return all_gather(shard, seq_dim=1)
 
     out = jax.jit(shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-                            check_rep=False))(x)
+                            check_vma=False))(x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x))

@@ -469,7 +469,10 @@ def test_admit_policy_shortest_prompt_first_parity(small_gpt):
     every request still completes token-identical to dense, conservation
     holds, and the backlog drains to zero."""
     m = small_gpt
-    rng = np.random.default_rng(31)
+    # seed 37: the dense f32 reference's smallest top-2 logit margin over
+    # these 48 tokens is 0.05, wide of the default bf16 pool's rounding
+    # (seed 31 had a 0.004 near-tie that the bf16 step programs flipped)
+    rng = np.random.default_rng(37)
     plens = [13, 3, 9, 4, 11, 5, 7, 6]
     prompts = [rng.integers(0, 160, n).astype("int64") for n in plens]
     refs = [_dense_ref(m, p, 6) for p in prompts]
@@ -539,9 +542,9 @@ def test_chaos_shortest_prompt_first_spec_conservation(small_gpt):
 
 # --------------------------------------- sampler headers on /generate (HTTP)
 def test_server_sampler_headers_roundtrip(small_gpt):
-    """X-Temperature / X-Top-K / X-Spec ride /generate into the continuous
-    scheduler's traced per-request knobs; malformed values are client bugs
-    and come back 400, not silently-defaulted."""
+    """X-Temperature / X-Top-K / X-Spec / X-Max-New-Tokens ride /generate
+    into the continuous scheduler's traced per-request knobs; malformed
+    values are client bugs and come back 400, not silently-defaulted."""
     from paddle_tpu.inference.serving import InferenceServer
 
     m = small_gpt
@@ -571,13 +574,21 @@ def test_server_sampler_headers_roundtrip(small_gpt):
         assert status == 200
         assert out.shape == ref.shape
         assert (out >= 0).all() and (out < 160).all()
+        # a per-request output budget under the server's cap of 6: the same
+        # greedy tokens, cut short
+        status, out = post({"X-Max-New-Tokens": "2"})
+        assert status == 200
+        np.testing.assert_array_equal(out, ref[:len(prompt) + 2])
         # malformed values: one 400 per knob, each with the offending value
         for hdrs in ({"X-Temperature": "hot"},
                      {"X-Temperature": "-0.5"},
                      {"X-Temperature": "inf"},
                      {"X-Top-K": "-3"},
                      {"X-Top-K": "2.5"},
-                     {"X-Spec": "maybe"}):
+                     {"X-Spec": "maybe"},
+                     {"X-Max-New-Tokens": "many"},
+                     {"X-Max-New-Tokens": "2.5"},
+                     {"X-Max-New-Tokens": "0"}):
             with pytest.raises(urllib.error.HTTPError) as ei:
                 post(hdrs)
             assert ei.value.code == 400, hdrs

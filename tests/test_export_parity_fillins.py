@@ -1,6 +1,7 @@
 """Round-4 export-parity fill-ins: correctness spot-checks (torch goldens
 where torch has the op) + the three-surface parity assertion."""
 import ast
+import os
 
 import numpy as np
 import pytest
@@ -34,6 +35,8 @@ def test_full_export_parity():
          paddle.nn.functional),
         ("/root/reference/python/paddle/static/__init__.py", paddle.static),
     ]
+    if not os.path.isdir("/root/reference"):
+        pytest.skip("the reference checkout (/root/reference) is not mounted")
     for path, mod in pairs[:3]:
         missing = _ref_all(path) - set(dir(mod))
         assert not missing, (path, sorted(missing))

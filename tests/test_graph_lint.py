@@ -94,7 +94,7 @@ def test_rule_host_sync_fires_inside_scan():
     r = A.analyze(hs, jnp.float32(1.0), _name="fix.hostsync")
     assert rules_of(r) == ["host-sync"]
     f = r.findings[0]
-    assert f.severity == A.HIGH and "debug_callback" in f.message
+    assert f.severity == A.HIGH and "debug_print" in f.message
     # cold-path programs only warn when the callback is outside any loop
     @jax.jit
     def warm(x):
@@ -157,7 +157,7 @@ def test_rule_recompile_hazard_static_args_and_weak_scalars():
 
 
 def test_rule_collective_axis_fires_on_mesh_mismatch():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(8), ("mp",))

@@ -101,6 +101,36 @@ _FLEET_KW = dict(max_slots=2, prefill_chunk=4, decode_steps=2,
 _PROMPT = np.array([5, 9, 2, 11], np.int64)
 
 
+def test_a_trace_of_the_shared_model_hides_its_tracers_from_other_replicas():
+    """Fleet replicas share one model, and a trace binds tracers into its
+    parameters for its duration (Layer.functional_call). Another replica that
+    reads the decode state meanwhile must wait for the real arrays, not carry
+    the first one's tracers off: on the chip, where tracing 24 layers takes
+    seconds, that killed the second replica's AOT warmup (PR 21)."""
+    import jax
+
+    m = _small_gpt()
+    mid_trace, seen = threading.Event(), {}
+
+    def pause(_layer, _inputs):     # runs inside the trace, parameters bound
+        mid_trace.set()
+        threading.Event().wait(0.5)
+
+    hook = m.gpt.blocks[0].register_forward_pre_hook(pause)
+    tracing = threading.Thread(target=lambda: seen.update(
+        toks=_paged_tokens(m, [np.arange(5, dtype=np.int64)], 3)[0]))
+    tracing.start()
+    try:
+        assert mid_trace.wait(60)
+        state = m._decode_state(jax.numpy.bfloat16)
+    finally:
+        tracing.join(120)
+        hook.remove()
+    leaked = [k for k, v in state.items() if isinstance(v, jax.core.Tracer)]
+    assert not leaked, leaked
+    assert seen["toks"].shape == (1, 3)
+
+
 def _reference(m):
     from paddle_tpu.inference.scheduler import (
         ContinuousGenerateBatchingPredictor,

@@ -144,7 +144,7 @@ def test_moe_expert_parallel_sharded():
 def test_global_scatter_gather_roundtrip():
     """a2a exchange on the 8-device mesh: gather(scatter(x)) == x, and scatter
     actually permutes rank-major blocks across devices."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     import paddle_tpu.distributed as dist

@@ -126,6 +126,18 @@ def test_flashmask_grad_parity():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5)
 
 
+def test_flash_under_a_mesh_runs_per_shard():
+    """Under a dp x mp mesh the kernels run per (batch, head) shard, and
+    inside an enclosing shard_map as they are: same values and gradients as
+    with no mesh, GQA and the flashmask index included. The check is the one
+    `chip_smoke.py --four-chips` runs on real chips."""
+    import chip_smoke
+
+    out = chip_smoke.mesh_kernel_parity(2, 128, 4, 2, 16, "float32", 2e-5,
+                                        expect_mosaic=False)
+    assert out["max_err"] <= 2e-5
+
+
 def test_supports_gate():
     assert pfa.supports((2, 256, 4, 64), (2, 256, 4, 64))
     assert not pfa.supports((2, 250, 4, 64), (2, 250, 4, 64))  # seq not divisible

@@ -332,7 +332,9 @@ def test_chaos_lookup_fault_degrades_to_cold_miss(small_gpt):
     from paddle_tpu.inference.faults import FaultInjector
 
     m = small_gpt
-    rng = np.random.default_rng(43)
+    # seed 46: smallest top-2 margin of the f32 reference 0.39 (seed 43 had
+    # a 0.006 near-tie that the default bf16 pool flipped on the cold path)
+    rng = np.random.default_rng(46)
     prompt = rng.integers(0, 160, 13).astype("int64")
     ref = _dense_ref(m, prompt, 6)
     f = FaultInjector()

@@ -1,7 +1,7 @@
 """Serving audit regression guard (ISSUE-1 satellite: CI/tooling).
 
-The round-5 serving regression class (per-call tunneled cache allocation;
-first-burst warm-up) is pinned by bench.py's scan-vs-e2e audit: the serving
+The serving regression class (per-call cache allocation in the host
+wrapper) is pinned by bench.py's scan-vs-e2e audit: the serving
 section must emit `bN_tokens_per_sec` / `bN_scan_tokens_per_sec` AND the
 derived gap fields, with the gap computed correctly. If someone rewires the
 serving bench and drops the audit, these tests fail before the next bench run
@@ -362,6 +362,12 @@ def test_cold_start_bench_wires_subprocess_children_and_fields():
     assert "PADDLE_T0" in src
     assert "cold_start_fields(" in src
     assert 'for leg in ("cold", "warm")' in src
+    # one process per chip: the parent of the children never initialises a
+    # JAX backend (own entry point, no device argument), and the cache is a
+    # fixed directory, never a temp dir
+    assert "jax.devices" not in src and "mkdtemp" not in src
+    assert inspect.signature(bench.bench_cold_start).parameters == {}
+    assert "bench_cold_start" not in inspect.getsource(bench.main)
 
     child = inspect.getsource(bench._cold_start_child_impl)
     assert "warmup=True" in child

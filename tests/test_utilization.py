@@ -279,9 +279,10 @@ def test_ledger_series_render_and_mfu_gauge_absent_iff_no_peak():
             assert v2 and float(v2[0].rsplit(" ", 1)[1]) >= float(v1), \
                 f"counter went backwards: {name}"
 
-    # peak-less ledger: everything renders EXCEPT the MFU gauge
+    # peak-less ledger (this suite's CPU device has no peak): everything
+    # renders EXCEPT the MFU gauge
     reg2 = MetricsRegistry()
-    UtilizationLedger(peak_flops=None, clock=clk, device=()) \
+    UtilizationLedger(peak_flops=None, clock=clk) \
         .bind_metrics(reg2, component="c2")
     text3 = render_prometheus(reg2)
     assert "paddle_serving_flops_total" in text3
