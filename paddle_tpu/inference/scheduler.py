@@ -58,6 +58,7 @@ import threading
 import numpy as np
 
 from ..analysis.lockwitness import make_lock
+from ..profiler.profiler import RecordEvent
 from .faults import ThreadDeath
 from .kv_cache import CacheOutOfBlocks
 from .resilience import DeadlineExceeded, ServiceUnavailable
@@ -317,25 +318,29 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                          conftest fixture on test failure. Overhead is
                          bench-gated <= 5% (slo_observability leg). Default
                          False: no capture, tick loop byte-identical.
-    utilization          ISSUE-19: per-tick FLOPs attribution. True builds
-                         a default `observability.utilization.
-                         UtilizationLedger`; pass an instance to configure
-                         (injected clock / peak_flops). Every tick's
-                         issued step-program FLOPs (cost_flops on the
-                         lowered runner, one trace per program key) split
-                         into useful / pad / spec_waste with EXACT integer
-                         conservation, useful FLOPs bill per tenant
-                         (paused time never bills — preempted sequences
-                         are off-slot), and tick wall splits into launch
-                         vs host gap. Exports `paddle_serving_flops_total{
-                         kind}`, `paddle_tenant_flops_total{tenant}`,
-                         `paddle_serving_host_gap_seconds` and (with a
-                         known device peak) `paddle_serving_mfu`; JSON at
+    utilization          ISSUE-19: per-tick FLOPs attribution, shown. Every
+                         scheduler keeps an `observability.utilization.
+                         UtilizationLedger` of its ticks — wall split into
+                         dispatch, read-back wait and host gap; issued /
+                         useful / pad positions and live / table K,V rows
+                         a launch — readable through `utilization.
+                         ledgers()`, also after close(). True adds the
+                         FLOPs probe (cost_flops on the lowered runner, one
+                         trace per program key: issued FLOPs split into
+                         useful / pad / spec_waste with EXACT integer
+                         conservation, useful FLOPs billed per tenant —
+                         paused time never bills, preempted sequences are
+                         off-slot) and SHOWS the ledger: `self.util`,
+                         `paddle_serving_flops_total{kind}`,
+                         `paddle_tenant_flops_total{tenant}`,
+                         `paddle_serving_host_gap_seconds`, (with a known
+                         device peak) `paddle_serving_mfu`, JSON at
                          `/utilization`, per-tick fields on the flight
-                         ring. Overhead is bench-gated <= 5%
+                         ring. Pass an instance to configure (injected
+                         clock / peak_flops). Overhead is bench-gated <= 5%
                          (serving_utilization leg) with zero new compiled
-                         programs. Default False: no attribution, launches
-                         carry no flops probe.
+                         programs. Default False: nothing exported,
+                         launches carry no flops probe.
     """
 
     _component = "continuous"
@@ -438,21 +443,21 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             self.flight = FlightRecorder(capacity=flight_recorder)
         else:
             self.flight = flight_recorder
-        # ISSUE-19 utilization ledger: published before the tick thread
-        # starts (tick fns and _flight_tick read it). The timing hook grows
-        # a wants_flops marker ONLY when a ledger is installed — that is
-        # what gates the one-trace-per-program flops probe in generation.py,
-        # so a bare scheduler's launch path is byte-identical.
-        if utilization is False or utilization is None:
-            self.util = None
-        elif utilization is True:
-            from ..observability.utilization import UtilizationLedger
-            self.util = UtilizationLedger()
-        else:
-            self.util = utilization
+        # Every scheduler keeps a tick ledger (time split, positions, K,V
+        # rows: plain integers off the tick's own numbers), published before
+        # the tick thread starts. `utilization=` decides what is SHOWN and
+        # probed: the `util` property is the ledger when it is on and None
+        # when off — the exported series, /utilization and the flight ring's
+        # `util` block key on it — and the timing hook grows its wants_flops
+        # marker only then, which is what gates the one-trace-per-program
+        # FLOPs probe in generation.py.
+        from ..observability.utilization import UtilizationLedger
+        self._util_shown = utilization is not False and utilization is not None
+        self._ledger = (utilization if self._util_shown
+                        and utilization is not True else UtilizationLedger())
         self._last_launch = None        # tick-thread-only hook stash
         hook = self._gen_timing
-        if self.util is not None:
+        if self._util_shown:
             def hook(info, _h=self._gen_timing):
                 _h(info)
             hook.wants_flops = True
@@ -691,12 +696,12 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                         p.name, state=p.state(),
                         burn_fast=round(p.burn_rate("fast"), 4),
                         burn_slow=round(p.burn_rate("slow"), 4)))
-        # ISSUE-19 utilization series exist IFF a ledger is installed (same
+        # ISSUE-19 utilization series exist IFF `utilization=` is on (same
         # absent-iff-off exposition contract); the MFU gauge additionally
         # needs a known device peak — the ledger itself enforces that.
-        if self.util is not None:
-            self.util.bind_metrics(reg, component=self._component)
-            self.metrics.attach_utilization(self.util)
+        if self._util_shown:
+            self._ledger.bind_metrics(reg, component=self._component)
+            self.metrics.attach_utilization(self._ledger)
         if self.flight is not None:
             occ = reg.gauge(
                 "paddle_flightrec_ticks",
@@ -717,18 +722,19 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
         return a / d if d else 0.0
 
     def _gen_timing(self, info):
-        """Launch-latency histogram only: the base hook also counts
-        batch*new_tokens as generated, but a tick's width includes masked
-        idle slots — actual tokens are counted per sequence at retirement
-        (_retire_ok) instead.
+        """Stash the launch's hook record for the tick function that made
+        it: the launch-latency histogram is observed AFTER the read-back
+        (`_launch_done`), because the hook fires when the launch has been
+        dispatched, not when the device has finished. The base hook also
+        counts batch*new_tokens as generated, but a tick's width includes
+        masked idle slots — actual tokens are counted per sequence at
+        retirement (_retire_ok) instead.
 
         Doubles as the post-ready compile sentinel's tap (ISSUE-13): once
         the AOT warmup armed it, any launch that had to cold-build its step
         program is a compile-surface violation — counted per program and
         reported to the chaos-suite witness (inference/warmup.py)."""
-        self._last_launch = info    # ISSUE-19: tick fns read flops/launch_s
-        self._decode_hist.labels(self._component, info["path"]).observe(
-            info["launch_s"])
+        self._last_launch = info    # tick fns read flops/dispatch_s/compiled
         if info["compiled"] and self._warm_armed.is_set():
             self._recompile_counter.labels(
                 self._component, info["path"]).inc()
@@ -986,20 +992,36 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                 try:
                     if self._faults is not None:
                         self._faults.check("batcher.tick")  # ThreadDeath
-                    self._admit()
-                    if self._phase_count(None) == 0:
-                        continue        # _admit parked briefly on the queue
-                    self._busy = True
-                    if self.util is not None:   # ISSUE-19 tick window opens
-                        self.util.tick_begin()
+                    # A tick is one pass with at least one live slot. A
+                    # pass that finds slots live follows a tick directly:
+                    # its window opens where that one closed, admission
+                    # inside. A pass that finds none lets _admit park on
+                    # the queue, and that wait (and the admission that
+                    # ended it) is in no tick and no span.
+                    contiguous = self._phase_count(None) > 0
+                    if not contiguous:
+                        self._admit()
+                        if self._phase_count(None) == 0:
+                            self._ledger.poll_session()
+                            continue
+                    tick = RecordEvent("serve.tick")
+                    tick.begin()
+                    self._ledger.tick_begin(contiguous)
                     try:
-                        self._retire_unserviceable()
+                        if contiguous:
+                            self._admit_span()
+                        if RecordEvent.capturing():
+                            tick.set_stats(**self._tick_stats())
+                        self._busy = True
+                        with RecordEvent("serve.retire"):
+                            self._retire_unserviceable()
                         self._prefill_tick()
                         self._decode_tick()
-                        self._util_tick()       # ISSUE-19 close BEFORE the
+                        self._util_tick()       # close BEFORE the flight
                         self._flight_tick()     # ring captures last_tick
                     finally:
                         self._busy = False
+                        tick.end()
                 except ThreadDeath:
                     # the dying thread strands no sequence: blocks go back to
                     # the pool, pending requests re-enter the queue, and the
@@ -1463,32 +1485,69 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                 tenant=getattr(req, "tenant", None))
         return won
 
+    @property
+    def util(self):
+        """The tick ledger where `utilization=` is on, else None: what
+        /utilization, /metrics and the flight ring show."""
+        return self._ledger if self._util_shown else None
+
+    def _admit_span(self):
+        with RecordEvent("serve.admit") as ev:
+            if not RecordEvent.capturing():     # stats only for a capture
+                return self._admit()
+            before = self.metrics.get("admitted_seqs")
+            self._admit()
+            ev.set_stats(admitted=self.metrics.get("admitted_seqs") - before)
+
+    def _tick_stats(self):
+        """What the tick starts on, for the `serve.tick` span: live slots,
+        how many of them prefill and decode, requests still waiting."""
+        with self._slot_lock:
+            phases = [s.phase for s in self._slots if s is not None]
+        return dict(live=len(phases), prefill=phases.count(_PREFILL),
+                    decode=phases.count(_DECODE),
+                    pending=self._queue.qsize() + len(self._backlog))
+
     def _util_tick(self):
-        """Close the utilization ledger's tick window (ISSUE-19): tick wall
-        minus the recorded launch walls becomes the host gap, the per-kind
-        flops land on the counters. Ledger failures never take the tick
-        loop down (same contract as the flight ring)."""
-        if self.util is None:
-            return
+        """Close the ledger's tick window: tick wall minus the recorded
+        launches (dispatch and read-back wait) becomes the host gap, and
+        with `utilization=` on the per-kind flops land on the counters.
+        Ledger failures never take the tick loop down (same contract as
+        the flight ring)."""
         try:
-            self.util.tick_end()
+            self._ledger.tick_end()
         except ThreadDeath:
             raise
         except Exception:       # pragma: no cover - telemetry must not bite
             pass
 
-    def _util_launch(self, program, total_units, slot_units, spec_units=0):
-        """Attribute the tick's just-returned launch to the ledger. The
-        timing hook stashed the launch's flops/launch_s on this thread; a
-        path mismatch means the hook never fired for this program (warmup
+    def _read_back(self, phase, *arrays):
+        """The launch's results on the host: the tick thread blocks here
+        until the device has finished (`serve.<phase>.wait`). Returns the
+        arrays and the seconds waited, on the ledger's clock."""
+        clock = self._ledger.clock
+        with RecordEvent(f"serve.{phase}.wait"):
+            t0 = clock()
+            out = [np.asarray(a._value if hasattr(a, "_value") else a)
+                   for a in arrays]
+            return out, clock() - t0
+
+    def _util_launch(self, program, wait_s, total_units, slot_units,
+                     spec_units=0, live_rows=0, table_rows=0):
+        """Account for the tick's launch, read back and absorbed: observe
+        `paddle_decode_launch_seconds` with the launch THROUGH its
+        read-back, and hand the ledger its time split, positions and rows.
+        The timing hook stashed the launch's record on this thread; a path
+        mismatch means the hook never fired for this program (warmup
         interleave) — skip rather than misattribute."""
-        info = self._last_launch
-        if info is None or info.get("path") != program:
+        if (self._last_launch or {}).get("path") != program:
             return
+        info, launch_s = self._launch_done(wait_s)
         try:
-            self.util.record_launch(program, info.get("flops"),
-                                    info.get("launch_s", 0.0),
-                                    total_units, slot_units, spec_units)
+            self._ledger.record_launch(
+                program, info.get("flops"), launch_s, total_units,
+                slot_units, spec_units, wait_s=wait_s, live_rows=live_rows,
+                table_rows=table_rows)
         except ThreadDeath:
             raise
         except Exception:       # pragma: no cover - telemetry must not bite
@@ -1528,11 +1587,11 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             }
             if self.qos is not None:
                 snap["fair_ratios"] = self.qos.fair_snapshot()
-            if self.util is not None and self.util.last_tick is not None:
+            if self._util_shown and self._ledger.last_tick is not None:
                 # ISSUE-19: the tick's own flops/gap decomposition rides
                 # the ring — /debug/ticks shows WHY MFU dipped (which
                 # slots were empty, which drafts died)
-                snap["util"] = self.util.last_tick
+                snap["util"] = self._ledger.last_tick
             rec.record(snap)
         except ThreadDeath:
             raise
@@ -1706,32 +1765,37 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
         if not picks:
             return
         S, C = self.max_slots, self.prefill_chunk
-        chunk = np.zeros((S, C), np.int64)
-        offs = np.zeros(S, np.int64)
-        lens = np.zeros(S, np.int64)
-        temps = np.zeros(S, np.float32)
-        tks = np.zeros(S, np.int32)
-        tables = np.zeros((S, self.table_width), np.int32)
-        for i, s, take in picks:
-            chunk[i, :take] = s.ids[s.pos:s.pos + take]
-            offs[i] = s.pos
-            lens[i] = take
-            temps[i] = s.temperature
-            tks[i] = s.top_k
-            tables[i] = s.table
-        reqs = [s.req for _, s, _ in picks]
-        akw = self._adapter_tick_kwargs([(i, s) for i, s, _ in picks], reqs)
+        with RecordEvent("serve.prefill.assemble"):
+            chunk = np.zeros((S, C), np.int64)
+            offs = np.zeros(S, np.int64)
+            lens = np.zeros(S, np.int64)
+            temps = np.zeros(S, np.float32)
+            tks = np.zeros(S, np.int32)
+            tables = np.zeros((S, self.table_width), np.int32)
+            for i, s, take in picks:
+                chunk[i, :take] = s.ids[s.pos:s.pos + take]
+                offs[i] = s.pos
+                lens[i] = take
+                temps[i] = s.temperature
+                tks[i] = s.top_k
+                tables[i] = s.table
+            reqs = [s.req for _, s, _ in picks]
+            akw = self._adapter_tick_kwargs([(i, s) for i, s, _ in picks],
+                                            reqs)
         traced = self.tracer.enabled
         t0 = self.tracer.now_us() if traced else 0.0
         try:
             if self._faults is not None:
                 self._faults.check("predictor.generate")
-            tk = self.model.prefill_chunk(
-                chunk, offs, lens, self.kv_cache, tables,
-                temperature=temps, top_k=tks,
-                eos_token_id=self.eos_token_id,
-                decode_kernel=self.decode_kernel, seed=next(self._seed),
-                timing_hook=self._timing_hook, **akw)
+            with RecordEvent("serve.prefill.dispatch") as ev:
+                tk = self.model.prefill_chunk(
+                    chunk, offs, lens, self.kv_cache, tables,
+                    temperature=temps, top_k=tks,
+                    eos_token_id=self.eos_token_id,
+                    decode_kernel=self.decode_kernel, seed=next(self._seed),
+                    timing_hook=self._timing_hook, **akw)
+                if RecordEvent.capturing():
+                    ev.set_stats(compiled=self._compiled_now("prefill_chunk"))
         except ThreadDeath:
             raise
         except Exception as e:
@@ -1740,27 +1804,28 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             return
         self.breaker.record_success()
         self.metrics.inc("prefill_ticks")
-        if self.util is not None:
-            # ISSUE-19: useful positions are exactly each pick's take; the
-            # S*C - sum(take) remainder (idle slots, chunk tail) is pad
-            self._util_launch("prefill_chunk", S * C,
-                              [(s.tenant, take) for _, s, take in picks])
-        tk = np.asarray(tk._value if hasattr(tk, "_value") else tk)
+        (tk,), wait_s = self._read_back("prefill", tk)
+        useful = int(sum(t for _, _, t in picks))
         self._span_each(reqs, "prefill_chunk", t0, self.tracer.now_us(),
-                        slots=len(picks),
-                        tokens=int(sum(t for _, _, t in picks)))
-        for i, s, take in picks:
-            s.pos += take
-            s.length = s.pos
-            try:
-                self.kv_cache.append_tokens(s.rid, take)
-            except KeyError:    # pragma: no cover - raced an eviction
-                pass
-            self._register_prefix(s, s.ids, s.pos)
-            if s.pos >= s.plen:
-                s.phase = _DECODE
-                s.tok = int(tk[i])
-                self._absorb(i, s, [s.tok])
+                        slots=len(picks), tokens=useful)
+        with RecordEvent("serve.prefill.absorb", useful=useful,
+                         issued=S * C):
+            for i, s, take in picks:
+                s.pos += take
+                s.length = s.pos
+                try:
+                    self.kv_cache.append_tokens(s.rid, take)
+                except KeyError:    # pragma: no cover - raced an eviction
+                    pass
+                self._register_prefix(s, s.ids, s.pos)
+                if s.pos >= s.plen:
+                    s.phase = _DECODE
+                    s.tok = int(tk[i])
+                    self._absorb(i, s, [s.tok])
+        # useful positions are exactly each pick's take; the S*C - sum(take)
+        # remainder (idle slots, chunk tail) is pad
+        self._util_launch("prefill_chunk", wait_s, S * C,
+                          [(s.tenant, take) for _, s, take in picks])
 
     def _register_prefix(self, s, tokens, committed, digests="prompt"):
         """Index this sequence's freshly COMMITTED full blocks (prefill
@@ -1790,34 +1855,38 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
         if not dec:
             return
         S, T = self.max_slots, self.decode_steps
-        tok = np.zeros(S, np.int64)
-        lengths = np.zeros(S, np.int64)
-        maxlens = np.zeros(S, np.int64)
-        active = np.zeros(S, bool)
-        temps = np.zeros(S, np.float32)
-        tks = np.zeros(S, np.int32)
-        tables = np.zeros((S, self.table_width), np.int32)
-        for i, s in dec:
-            tok[i] = s.tok
-            lengths[i] = s.length
-            maxlens[i] = s.plen + s.max_new   # write ceiling: reserved rows
-            active[i] = True
-            temps[i] = s.temperature
-            tks[i] = s.top_k
-            tables[i] = s.table
-        reqs = [s.req for _, s in dec]
-        akw = self._adapter_tick_kwargs(dec, reqs)
+        with RecordEvent("serve.decode.assemble"):
+            tok = np.zeros(S, np.int64)
+            lengths = np.zeros(S, np.int64)
+            maxlens = np.zeros(S, np.int64)
+            active = np.zeros(S, bool)
+            temps = np.zeros(S, np.float32)
+            tks = np.zeros(S, np.int32)
+            tables = np.zeros((S, self.table_width), np.int32)
+            for i, s in dec:
+                tok[i] = s.tok
+                lengths[i] = s.length
+                maxlens[i] = s.plen + s.max_new  # write ceiling: reserved rows
+                active[i] = True
+                temps[i] = s.temperature
+                tks[i] = s.top_k
+                tables[i] = s.table
+            reqs = [s.req for _, s in dec]
+            akw = self._adapter_tick_kwargs(dec, reqs)
         traced = self.tracer.enabled
         t0 = self.tracer.now_us() if traced else 0.0
         try:
             if self._faults is not None:
                 self._faults.check("predictor.generate")
-            toks = self.model.decode_step(
-                tok, lengths, active, self.kv_cache, tables, steps=T,
-                max_lens=maxlens, temperature=temps, top_k=tks,
-                eos_token_id=self.eos_token_id,
-                decode_kernel=self.decode_kernel, seed=next(self._seed),
-                timing_hook=self._timing_hook, **akw)
+            with RecordEvent("serve.decode.dispatch") as ev:
+                toks = self.model.decode_step(
+                    tok, lengths, active, self.kv_cache, tables, steps=T,
+                    max_lens=maxlens, temperature=temps, top_k=tks,
+                    eos_token_id=self.eos_token_id,
+                    decode_kernel=self.decode_kernel, seed=next(self._seed),
+                    timing_hook=self._timing_hook, **akw)
+                if RecordEvent.capturing():
+                    ev.set_stats(compiled=self._compiled_now("decode_step"))
         except ThreadDeath:
             raise
         except Exception as e:
@@ -1825,20 +1894,42 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             return
         self.breaker.record_success()
         self.metrics.inc("decode_ticks")
-        toks = np.asarray(toks._value if hasattr(toks, "_value") else toks)
+        (toks,), wait_s = self._read_back("decode", toks)
         self._span_each(reqs, "decode_step", t0, self.tracer.now_us(),
                         slots=len(dec), steps=T)
+        live_rows, table_rows = self._kv_rows(lengths[active], T)
         units = []
-        for i, s in dec:
-            s.length += T
-            s.tok = int(toks[i, -1])
-            n0 = s.n_tok
-            self._absorb(i, s, toks[i])
-            # ISSUE-19: useful = tokens the sequence actually ABSORBED this
-            # tick (EOS-frozen / over-cap rows are pad, like idle slots)
-            units.append((s.tenant, s.n_tok - n0))
-        if self.util is not None:
-            self._util_launch("decode_step", S * T, units)
+        with RecordEvent("serve.decode.absorb") as ev:
+            for i, s in dec:
+                s.length += T
+                s.tok = int(toks[i, -1])
+                n0 = s.n_tok
+                self._absorb(i, s, toks[i])
+                # useful = tokens the sequence actually ABSORBED this tick
+                # (EOS-frozen / over-cap rows are pad, like idle slots)
+                units.append((s.tenant, s.n_tok - n0))
+            if RecordEvent.capturing():
+                ev.set_stats(useful=sum(u for _, u in units), issued=S * T,
+                             rows=live_rows)
+        self._util_launch("decode_step", wait_s, S * T, units,
+                          live_rows=live_rows, table_rows=table_rows)
+
+    def _kv_rows(self, lengths, steps):
+        """(live_rows, table_rows) of one decode or verify launch: the
+        context lengths of the active slots summed over the launch's token
+        steps — a slot with `length` rows in the pool attends over
+        length + t + 1 at step t — against the rows the block tables handed
+        to the launch span, slots x table width x block size x steps."""
+        live = int(steps * np.sum(lengths)
+                   + len(lengths) * steps * (steps + 1) // 2)
+        return live, (self.max_slots * self.table_width
+                      * self.kv_cache.block_size * steps)
+
+    def _compiled_now(self, program):
+        """1 if the launch the hook just stashed had to build `program`."""
+        info = self._last_launch
+        return int(bool(info and info.get("path") == program
+                        and info["compiled"]))
 
     def _verify_tick(self):
         """Speculative decode tick (spec_k > 0): draft on the host, verify
@@ -1856,52 +1947,57 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
         if not dec:
             return
         S, K = self.max_slots, self.spec_k
-        chunk = np.zeros((S, K + 1), np.int64)
-        offs = np.zeros(S, np.int64)
-        dlens = np.zeros(S, np.int64)
-        maxlens = np.zeros(S, np.int64)
-        active = np.zeros(S, bool)
-        temps = np.zeros(S, np.float32)
-        tks = np.zeros(S, np.int32)
-        tables = np.zeros((S, self.table_width), np.int32)
-        for i, s in dec:
-            # shared-prefix safety (ISSUE-11): a verify launch writes its
-            # whole window at [length, length+1+K) and rejection "rollback"
-            # is length bookkeeping only — length never drops below plen,
-            # and a prefix hit covers at most plen-1 tokens, so a verify
-            # tick can never write into (or roll back into) a shared block
-            assert s.length >= s.plen > s.prefix_hit, \
-                (f"verify tick would touch shared prefix rows: "
-                 f"length={s.length} plen={s.plen} hit={s.prefix_hit}")
-            chunk[i, 0] = s.tok
-            offs[i] = s.length
-            maxlens[i] = s.plen + s.max_new
-            active[i] = True
-            temps[i] = s.temperature
-            tks[i] = s.top_k
-            tables[i] = s.table
-            spare = s.max_new - len(s.generated) - 1
-            if s.spec and spare > 0:
-                hist = np.concatenate(
-                    [s.ids, np.asarray(s.generated, np.int64)])
-                prop = np.asarray(self._drafter.draft(hist, K),
-                                  np.int64).reshape(-1)[:K]
-                n = min(len(prop), spare)
-                if n > 0:
-                    chunk[i, 1:1 + n] = prop[:n]
-                    dlens[i] = n
-        reqs = [s.req for _, s in dec]
-        akw = self._adapter_tick_kwargs(dec, reqs)
+        with RecordEvent("serve.decode.assemble"):
+            chunk = np.zeros((S, K + 1), np.int64)
+            offs = np.zeros(S, np.int64)
+            dlens = np.zeros(S, np.int64)
+            maxlens = np.zeros(S, np.int64)
+            active = np.zeros(S, bool)
+            temps = np.zeros(S, np.float32)
+            tks = np.zeros(S, np.int32)
+            tables = np.zeros((S, self.table_width), np.int32)
+            for i, s in dec:
+                # shared-prefix safety (ISSUE-11): a verify launch writes
+                # its whole window at [length, length+1+K) and rejection
+                # "rollback" is length bookkeeping only — length never
+                # drops below plen, and a prefix hit covers at most plen-1
+                # tokens, so a verify tick can never write into (or roll
+                # back into) a shared block
+                assert s.length >= s.plen > s.prefix_hit, \
+                    (f"verify tick would touch shared prefix rows: "
+                     f"length={s.length} plen={s.plen} hit={s.prefix_hit}")
+                chunk[i, 0] = s.tok
+                offs[i] = s.length
+                maxlens[i] = s.plen + s.max_new
+                active[i] = True
+                temps[i] = s.temperature
+                tks[i] = s.top_k
+                tables[i] = s.table
+                spare = s.max_new - len(s.generated) - 1
+                if s.spec and spare > 0:
+                    hist = np.concatenate(
+                        [s.ids, np.asarray(s.generated, np.int64)])
+                    prop = np.asarray(self._drafter.draft(hist, K),
+                                      np.int64).reshape(-1)[:K]
+                    n = min(len(prop), spare)
+                    if n > 0:
+                        chunk[i, 1:1 + n] = prop[:n]
+                        dlens[i] = n
+            reqs = [s.req for _, s in dec]
+            akw = self._adapter_tick_kwargs(dec, reqs)
         traced = self.tracer.enabled
         t0 = self.tracer.now_us() if traced else 0.0
         try:
             if self._faults is not None:
                 self._faults.check("predictor.generate")
-            acc, nxt = self.model.verify_step(
-                chunk, offs, dlens, active, self.kv_cache, tables,
-                max_lens=maxlens, temperature=temps, top_k=tks,
-                decode_kernel=self.decode_kernel, seed=next(self._seed),
-                timing_hook=self._timing_hook, **akw)
+            with RecordEvent("serve.decode.dispatch") as ev:
+                acc, nxt = self.model.verify_step(
+                    chunk, offs, dlens, active, self.kv_cache, tables,
+                    max_lens=maxlens, temperature=temps, top_k=tks,
+                    decode_kernel=self.decode_kernel, seed=next(self._seed),
+                    timing_hook=self._timing_hook, **akw)
+                if RecordEvent.capturing():
+                    ev.set_stats(compiled=self._compiled_now("verify_step"))
         except ThreadDeath:
             raise
         except Exception as e:
@@ -1909,8 +2005,7 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             return
         self.breaker.record_success()
         self.metrics.inc("verify_ticks")
-        acc = np.asarray(acc._value if hasattr(acc, "_value") else acc)
-        nxt = np.asarray(nxt._value if hasattr(nxt, "_value") else nxt)
+        (acc, nxt), wait_s = self._read_back("decode", acc, nxt)
         drafted = int(sum(dlens[i] for i, _ in dec))
         accepted = int(sum(acc[i] for i, _ in dec))
         self._span_each(reqs, "verify_step", t0, self.tracer.now_us(),
@@ -1922,21 +2017,26 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
         with self._slot_lock:
             self._spec_drafted += drafted
             self._spec_accepted += accepted
+        live_rows, table_rows = self._kv_rows(offs[active], K + 1)
         units = []
-        for i, s in dec:
-            a = int(acc[i])
-            s.length += 1 + a   # committed rows: accepted prefix + emitted
-            s.tok = int(nxt[i])
-            n0 = s.n_tok
-            self._absorb(i, s, [int(t) for t in chunk[i, 1:1 + a]]
-                         + [s.tok])
-            # ISSUE-19: useful = absorbed (accepted prefix + the emitted
-            # token, minus any over-cap shortfall); rejected drafts are
-            # spec_waste; the rest of the S*(K+1) window is pad
-            units.append((s.tenant, s.n_tok - n0))
-        if self.util is not None:
-            self._util_launch("verify_step", S * (K + 1), units,
-                              spec_units=drafted - accepted)
+        with RecordEvent("serve.decode.absorb") as ev:
+            for i, s in dec:
+                a = int(acc[i])
+                s.length += 1 + a   # committed rows: accepted prefix + emitted
+                s.tok = int(nxt[i])
+                n0 = s.n_tok
+                self._absorb(i, s, [int(t) for t in chunk[i, 1:1 + a]]
+                             + [s.tok])
+                # useful = absorbed (accepted prefix + the emitted token,
+                # minus any over-cap shortfall); rejected drafts are
+                # spec_waste; the rest of the S*(K+1) window is pad
+                units.append((s.tenant, s.n_tok - n0))
+            if RecordEvent.capturing():
+                ev.set_stats(useful=sum(u for _, u in units),
+                             issued=S * (K + 1), rows=live_rows)
+        self._util_launch("verify_step", wait_s, S * (K + 1), units,
+                          spec_units=drafted - accepted,
+                          live_rows=live_rows, table_rows=table_rows)
 
     # ------------------------------------------------------------- lifecycle
     def _abandon_slots(self):
@@ -1990,3 +2090,4 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
     def close(self):
         super().close()
         self._drain_backlog()
+        self._ledger.close()    # readable through utilization.ledgers()

@@ -638,18 +638,33 @@ class GenerateBatchingPredictor(BatchingPredictor):
         kv_cache.bind_metrics(self.metrics.registry, pool=self._component)
         self._decode_hist = self.metrics.registry.histogram(
             "paddle_decode_launch_seconds",
-            "Host wall of one decode launch (prefill + compiled scan "
-            "dispatch) by path", labels=("component", "path"))
+            "Host wall of one decode launch by path, from its dispatch "
+            "through the read-back of its tokens (the device's work "
+            "included)", labels=("component", "path"))
         self._tokens_total = self.metrics.registry.counter(
             "paddle_generated_tokens_total", "Tokens generated (batch * new)",
             labels=("component",))
+        self._last_launch = None        # the hook's stash (_gen_timing)
 
     def _gen_timing(self, info):
-        """models/generation.py timing hook -> registry series."""
-        self._decode_hist.labels(self._component, info["path"]).observe(
-            info["launch_s"])
+        """models/generation.py timing hook -> registry series. The hook
+        fires when the launch is DISPATCHED; the worker observes the
+        launch's wall once it has read the tokens back (`_launch_done`)."""
+        self._last_launch = info        # worker-thread-only stash
         self._tokens_total.labels(self._component).inc(
             info["batch"] * info["new_tokens"])
+
+    def _launch_done(self, wait_s):
+        """Observe the launch the hook last stashed, now that its result is
+        on the host: dispatch plus the `wait_s` of the read-back. Returns
+        (hook record, launch seconds), or (None, 0.0) if no hook fired."""
+        info, self._last_launch = self._last_launch, None
+        if info is None:
+            return None, 0.0
+        launch_s = info["dispatch_s"] + wait_s
+        self._decode_hist.labels(self._component, info["path"]).observe(
+            launch_s)
+        return info, launch_s
 
     def infer(self, ids, timeout=None, deadline=None, trace_id=None):
         """One prompt (1-D int ids) in -> full generated sequence out."""
@@ -737,7 +752,9 @@ class GenerateBatchingPredictor(BatchingPredictor):
                 max_new_tokens=self.max_new_tokens,
                 decode_kernel=self.decode_kernel, deadline=batch_dl,
                 timing_hook=self._gen_timing)
+            t_wait = time.perf_counter()
             toks = np.asarray(toks._value if hasattr(toks, "_value") else toks)
+            self._launch_done(time.perf_counter() - t_wait)
             self.breaker.record_success()
             adm = [r for _, r in admitted]
             self._span_each(adm, "decode_launch", t_launch0, t_dec,
@@ -784,8 +801,10 @@ class GenerateBatchingPredictor(BatchingPredictor):
                     dtype=dtype, decode_kernel=self.decode_kernel,
                     deadline=r.deadline, timing_hook=self._gen_timing)
                 self.breaker.record_success()
+                t_wait = time.perf_counter()
                 out = np.asarray(out._value if hasattr(out, "_value")
                                  else out)[0]
+                self._launch_done(time.perf_counter() - t_wait)
                 self._span_each([r], "decode", t_dec, self.tracer.now_us(),
                                 path="dense_fallback")
                 self._finish_req(r, out.astype(r.arrays[0].dtype))
@@ -1183,10 +1202,12 @@ class InferenceServer:
 
             def _do_profile(self, query):
                 """ISSUE-19: GET /debug/profile?ms=N — capture N ms of
-                jax.profiler device trace, join it with the serving tracer
-                (shared perf_counter timebase), answer JSON naming the
-                artifacts. Taxonomy: malformed/absent/oversized ms= is a
-                client bug (400); a concurrent capture answers 409 (the
+                jax.profiler device trace (the tick thread's serve.*
+                spans are in it, on the device ops' clock), write the
+                serving tracer's request spans beside it (their own
+                perf_counter timebase), answer JSON naming the artifacts.
+                Taxonomy: malformed/absent/oversized ms= is a client bug
+                (400); a concurrent capture answers 409 (the
                 profiler is a process-global singleton — two start_trace
                 calls corrupt each other); a profiler failure answers 503
                 (retryable: the runtime may just be busy)."""
@@ -1372,10 +1393,11 @@ class InferenceServer:
         Runs under self._profile_lock (the handler holds it): starts the
         device trace into a fresh numbered directory under profile_dir,
         sleeps out the window on the handler thread, stops the trace, then
-        writes a joined chrome view (host tracer spans + any profiler
-        events share the perf_counter-µs timebase) next to the raw trace.
-        The join is best-effort — a tracer-less server still returns the
-        raw trace directory."""
+        writes a chrome view of the host tracer's request spans (on
+        perf_counter µs, not the capture's clock) next to the raw trace,
+        which holds the tick thread's serve.* spans itself. The chrome view
+        is best-effort — a tracer-less server still returns the raw trace
+        directory."""
         import os
         import tempfile
 

@@ -219,23 +219,20 @@ class GenerationMixin:
     @staticmethod
     def _emit_timing(timing_hook, path, B, P, new_tokens, compiled, t0,
                      flops=None):
-        """Decode timing hook (observability layer): called once per launch
-        with host-wall phase numbers. The decode loop itself is ONE compiled
-        scan — there is no host boundary per token to hook — so the per-step
-        number is launch wall / tokens, which is exactly the figure the
-        serving metrics and the `observability_overhead` bench track. The
-        same interval is also recorded as a profiler RecordEvent (when a
-        Profiler is recording), so serving spans, this hook and profiler
-        step markers all land on one timebase. ``flops`` (ISSUE-19) is the
+        """Decode timing hook (observability layer): called once per launch,
+        when the call that enqueued the program has RETURNED. ``dispatch_s``
+        is that call alone: the device arrays made and the program handed to
+        the runtime, which returns before the device has finished. Whoever
+        reads the result back times the wait and adds it (the serving layer
+        does: ``launch = dispatch + wait``). The same interval is the
+        ``generate.<path>`` RecordEvent. ``flops`` (ISSUE-19) is the
         program's issued FLOPs per launch — present only when the hook
         asked for it (``wants_flops``), None otherwise."""
         if timing_hook is None:
             return
-        dt = time.perf_counter() - t0
         timing_hook({"path": path, "batch": int(B), "prompt_len": int(P),
                      "new_tokens": int(new_tokens), "compiled": bool(compiled),
-                     "launch_s": dt, "flops": flops,
-                     "per_token_s": dt / max(1, int(new_tokens))})
+                     "dispatch_s": time.perf_counter() - t0, "flops": flops})
 
     def _flops_of(self, cache_key, run, args):
         """Issued FLOPs of one execution of the step program behind
@@ -311,7 +308,7 @@ class GenerationMixin:
         `deadline`: optional inference.resilience.Deadline — raises
         DeadlineExceeded instead of launching an already-expired decode.
         `timing_hook`: optional fn(dict) receiving per-launch host timing
-        (launch_s, per_token_s, compiled, ...) — the serving layer feeds the
+        (dispatch_s, compiled, ...) — the serving layer feeds the
         observability metrics/histograms through it.
         """
         ids = (input_ids._value if isinstance(input_ids, Tensor)
@@ -661,7 +658,7 @@ class GenerationMixin:
                     *self._adapter_extra(adapters, adapter_slots, S),
                     jax.random.key(seed))
             # ISSUE-19: probe BEFORE the launch (donation deletes the pool
-            # args after) and before t0 (the trace must not pollute launch_s)
+            # args after) and before t0 (the trace must not pollute dispatch_s)
             flops = (self._flops_of(cache_key, run, args)
                      if self._wants_flops(timing_hook) else None)
             t0 = time.perf_counter()

@@ -311,9 +311,10 @@ def export_joined_chrome(path, tracer=None, profiler=None, extra_events=()):
     Both sides timestamp with ``time.perf_counter`` microseconds (the tracer
     by default, the profiler always), so the merged view needs no clock
     alignment: serving spans, model RecordEvents and ProfileStep markers land
-    on one shared timeline. Device-side traces captured by ``jax.profiler``
-    live in TensorBoard/perfetto format next to this file — join them by the
-    wall-clock anchor tag documented in docs/OBSERVABILITY.md."""
+    on one shared timeline. The device's ops are NOT on it: a ``jax.profiler``
+    capture times its events from the session's start. What has to be read
+    against device ops is a ``profiler.RecordEvent``, which puts its range
+    into the capture itself, on ``/host:CPU`` of the same xplane."""
     events = []
     if tracer is not None:
         events.extend(tracer.chrome_events())

@@ -25,11 +25,36 @@ chargeback sum closes on useful by construction too; preempted (paused)
 sequences are off-slot and contribute no units, so paused time can never
 bill a tenant.
 
-Tick wall-time splits the same way: launch wall (the device-side work,
-summed from the generation timing hook) vs HOST GAP (everything else the
-tick spent on the host — admission bookkeeping, numpy assembly, absorb).
-The gap histogram is the dispatch-efficiency dial ROADMAP's disaggregated
-prefill/decode item needs before tiers can be sized.
+Tick wall-time splits three ways. A launch is ``dispatch_s`` (the call
+that hands the program to the runtime, which returns before the device has
+finished; from the generation timing hook) plus ``wait_s`` (the tick thread
+blocked in the read-back of the launch's tokens; timed by the scheduler):
+``launch_s = dispatch_s + wait_s``. HOST GAP is everything else of the tick
+— admission, numpy assembly, absorb, stream flushes. The window of a tick
+opens where the previous one closed (admission included) and time parked
+on an empty queue is in no tick, so ``wait_s / wall_s`` is the share of the
+tick thread's time in which the device, not the host, was the one working.
+
+Beside the FLOPs, every launch counts POSITIONS with the same conservation
+(``issued_positions == useful + pad + spec``: S x C, S x T, S x (K+1)
+issued; prompt tokens taken / tokens absorbed useful) and, for decode and
+verify, K,V ROWS: ``live_rows``, the context lengths of the active slots
+summed over the launch's token steps, against ``table_rows``, the rows the
+block tables handed to the launch span (slots x table width x block size x
+token steps: the scheduler's geometry, not the kernel's grid). These are
+plain integers and need no FLOPs probe, so every continuous scheduler keeps
+them; ``utilization=True`` adds the probe and the exported series.
+
+Every total is kept twice: for the process, and for the ticks that ran
+wholly inside the current (or, with none running, the last) ``jax.profiler``
+session (``snapshot()["profiled"]``), so a capture's tick accounting needs
+no clock to align. The ledger cannot ask the profiler which session it is
+in, only whether one runs: it asks at every tick's begin and end, at every
+launch and on every pass of a parked loop, and a session found running
+where the last answer was "none" starts the account afresh. A stop and a
+restart that both fall between two such questions read as one session.
+``ledgers()`` lists the live ledgers and the last few whose scheduler was
+closed.
 
 Exported series (absent-iff-off, like every optional subsystem):
 
@@ -47,10 +72,26 @@ from __future__ import annotations
 import collections
 import threading
 import time
+import weakref
+
+from jax.profiler import TraceAnnotation
 
 from .xla import device_peak_flops
 
-__all__ = ["UtilizationLedger", "attribute_launch", "HOST_GAP_BUCKETS"]
+__all__ = ["UtilizationLedger", "attribute_launch", "ledgers",
+           "HOST_GAP_BUCKETS"]
+
+_LIVE: "weakref.WeakSet[UtilizationLedger]" = weakref.WeakSet()
+# ledgers whose scheduler was closed: readable after the server is gone. A
+# ledger refers to no scheduler, model or pool, so keeping it keeps only it.
+_CLOSED: collections.deque = collections.deque(maxlen=4)
+
+
+def ledgers():
+    """Every live ledger and the last few closed ones, oldest first."""
+    closed = list(_CLOSED)
+    return closed + [led for led in _LIVE if led not in closed]
+
 
 # per-tick host gaps are sub-millisecond on a healthy scheduler and spike
 # to tens of ms when the host falls behind — finer-than-latency buckets
@@ -92,8 +133,51 @@ def attribute_launch(flops, total_units, slot_units, spec_units=0):
     return issued, useful, pad, spec, bills
 
 
+_FLOPS = ("issued", "useful", "pad", "spec_waste")
+_POSITIONS = ("issued_positions", "useful_positions", "pad_positions",
+              "spec_positions")
+_PROGRAM_KEYS = (_FLOPS + ("launches",) + _POSITIONS
+                 + ("live_rows", "table_rows", "dispatch_s", "wait_s"))
+_TICK_KEYS = _FLOPS + ("launch_s", "dispatch_s", "wait_s")
+
+
+def _new_account():
+    """Totals over ticks: one for the process, one for profiled ticks."""
+    acc = dict.fromkeys(_TICK_KEYS + ("wall_s", "host_gap_s"), 0)
+    acc.update(ticks=0, launches=0, programs={})
+    return acc
+
+
+def _fold(acc, tick):
+    """Add one closed tick to an account."""
+    for key in _TICK_KEYS + ("wall_s", "host_gap_s"):
+        acc[key] += tick[key]
+    acc["ticks"] += 1
+    for name, p in tick["programs"].items():
+        acc["launches"] += p["launches"]
+        tot = acc["programs"].setdefault(name, dict.fromkeys(_PROGRAM_KEYS, 0))
+        for key in _PROGRAM_KEYS:
+            tot[key] += p[key]
+
+
+def _account_view(acc):
+    """JSON form of an account, FLOPs under the names /utilization has."""
+    return {
+        "flops": {"issued": acc["issued"], "useful": acc["useful"],
+                  "pad_waste": acc["pad"], "spec_waste": acc["spec_waste"]},
+        "ticks": acc["ticks"], "launches": acc["launches"],
+        "wall_s": round(acc["wall_s"], 6),
+        "launch_wall_s": round(acc["launch_s"], 6),
+        "dispatch_s": round(acc["dispatch_s"], 6),
+        "wait_s": round(acc["wait_s"], 6),
+        "host_gap_s": round(acc["host_gap_s"], 6),
+        "programs": {name: dict(p) for name, p in acc["programs"].items()},
+    }
+
+
 class UtilizationLedger:
-    """Per-tick FLOPs/wall decomposition for one continuous scheduler.
+    """Per-tick FLOPs/positions/rows/wall decomposition for one continuous
+    scheduler.
 
     The tick thread drives ``tick_begin`` / ``record_launch`` /
     ``tick_end``; gauges and the ``/utilization`` endpoint read
@@ -103,7 +187,8 @@ class UtilizationLedger:
     ``peak_flops``: MFU denominator (FLOP/s). Default resolves
     ``device_peak_flops`` of the first jax device — None on CPU, which
     leaves the MFU gauge unregistered (absent-iff-off). ``clock`` is
-    injectable for deterministic tests.
+    injectable for deterministic tests; the scheduler times its read-backs
+    on it too.
     """
 
     def __init__(self, *, peak_flops=None, device=None,
@@ -116,30 +201,33 @@ class UtilizationLedger:
                 device = jax.devices()[0]
             peak_flops = device_peak_flops(device)
         self.peak_flops = peak_flops
-        self._clock = clock
+        self.clock = clock
         self.mfu_window_s = float(mfu_window_s)
         self._lock = threading.Lock()
-        # lifetime totals (integer flops, exact)
-        self.issued = 0
-        self.useful = 0
-        self.pad_waste = 0
-        self.spec_waste = 0
+        # lifetime totals (integers exact), and the same over the ticks of
+        # the current or last profiler session (poll_session starts it anew)
+        self._process = _new_account()
+        self._profiled = _new_account()
+        self._in_session = False
         self.by_tenant: dict = {}
-        self.ticks = 0
-        self.launches = 0
-        self.launch_wall_s = 0.0
-        self.host_gap_s = 0.0
         self._gaps = collections.deque(maxlen=int(gap_samples))
         # MFU window: (t_end, tick_wall_s, useful_flops) per tick
         self._window: collections.deque = collections.deque()
         self.last_tick = None
         # in-tick state — tick thread only
         self._t0 = None
+        self._t_end = None      # where the last tick closed
         self._tick = None
         # metric children, bound by bind_metrics (None = no registry)
         self._flops_counter = None
         self._tenant_counter = None
         self._gap_hist = None
+        _LIVE.add(self)
+
+    def close(self):
+        """The scheduler is gone: stay readable through ``ledgers()``."""
+        if self not in _CLOSED:
+            _CLOSED.append(self)
 
     # ------------------------------------------------------------- metrics
     def bind_metrics(self, registry, component="continuous"):
@@ -161,7 +249,8 @@ class UtilizationLedger:
         self._gap_hist = registry.histogram(
             "paddle_serving_host_gap_seconds",
             "Per-tick host time outside step-program launches (tick wall "
-            "minus launch wall) — the dispatch-efficiency dial",
+            "minus dispatch and read-back wait) — the dispatch-efficiency "
+            "dial",
             labels=("component",), buckets=HOST_GAP_BUCKETS).labels(
                 component)
         if self.peak_flops:
@@ -175,62 +264,90 @@ class UtilizationLedger:
         return self
 
     # ------------------------------------------------------------ tick API
-    def tick_begin(self):
-        self._t0 = self._clock()
-        self._tick = {
-            "issued": 0, "useful": 0, "pad": 0, "spec_waste": 0,
-            "launch_s": 0.0, "tenants": {}, "programs": {},
-        }
+    def poll_session(self):
+        """Ask whether a profiler session runs (tick thread). One found
+        running where the last answer was no is a NEW session: its ticks
+        get a fresh ``profiled`` account, and the open tick, which began
+        before it, is not one of them."""
+        on = TraceAnnotation.is_enabled()
+        if on and not self._in_session:
+            with self._lock:
+                self._profiled = _new_account()
+        self._in_session = on
+        if self._tick is not None and not on:
+            self._tick["profiled"] = False
+        return on
+
+    def tick_begin(self, contiguous=False):
+        """Open a tick's window: where the last tick closed if this pass of
+        the loop follows it directly (``contiguous``: admission and the
+        loop's own bookkeeping are then inside), else now (after a park on
+        an empty queue, which is in no tick)."""
+        now = self.clock()
+        self._t0 = (self._t_end if contiguous and self._t_end is not None
+                    else now)
+        self._t_end = None
+        profiled = self.poll_session()
+        self._tick = dict.fromkeys(_TICK_KEYS, 0)
+        self._tick.update(tenants={}, programs={}, profiled=profiled)
 
     def record_launch(self, program, flops, launch_s, total_units,
-                      slot_units, spec_units=0):
-        """Attribute one launch inside the current tick. ``slot_units`` is
-        ``[(tenant_or_None, useful_units), ...]`` per live slot — the
-        scheduler's ground truth of which positions carried live tokens."""
+                      slot_units, spec_units=0, *, wait_s=0.0, live_rows=0,
+                      table_rows=0):
+        """Attribute one launch inside the current tick. ``launch_s`` is
+        the launch THROUGH its read-back, ``wait_s`` the read-back's part
+        of it. ``total_units`` are the positions the program issued,
+        ``slot_units`` ``[(tenant_or_None, useful_units), ...]`` per live
+        slot — the scheduler's ground truth of which positions carried live
+        tokens — and ``spec_units`` rejected draft positions."""
         if self._tick is None:      # launch outside a tick (warmup): skip
             return
+        self.poll_session()
+        slot_units = list(slot_units)
         issued, useful, pad, spec, bills = attribute_launch(
             flops, total_units, slot_units, spec_units)
+        launch_s = float(launch_s or 0.0)
+        wait_s = min(float(wait_s or 0.0), launch_s)
+        useful_pos = sum(max(0, int(u)) for _, u in slot_units)
         t = self._tick
-        t["issued"] += issued
-        t["useful"] += useful
-        t["pad"] += pad
-        t["spec_waste"] += spec
-        t["launch_s"] += float(launch_s or 0.0)
+        p = t["programs"].setdefault(program,
+                                     dict.fromkeys(_PROGRAM_KEYS, 0))
+        for acc in (t, p):
+            acc["issued"] += issued
+            acc["useful"] += useful
+            acc["pad"] += pad
+            acc["spec_waste"] += spec
+            acc["dispatch_s"] += launch_s - wait_s
+            acc["wait_s"] += wait_s
+        t["launch_s"] += launch_s
         for tenant, share in bills.items():
             t["tenants"][tenant] = t["tenants"].get(tenant, 0) + share
-        p = t["programs"].setdefault(
-            program, {"issued": 0, "useful": 0, "pad": 0, "spec_waste": 0,
-                      "launches": 0})
-        p["issued"] += issued
-        p["useful"] += useful
-        p["pad"] += pad
-        p["spec_waste"] += spec
         p["launches"] += 1
+        p["issued_positions"] += int(total_units)
+        p["useful_positions"] += useful_pos
+        p["spec_positions"] += int(spec_units)
+        p["pad_positions"] += int(total_units) - useful_pos - int(spec_units)
+        p["live_rows"] += int(live_rows)
+        p["table_rows"] += int(table_rows)
 
     def tick_end(self):
         if self._tick is None:
             return None
+        self.poll_session()
         t, self._tick = self._tick, None
-        now = self._clock()
+        now = self._t_end = self.clock()
         wall = max(0.0, now - (self._t0 if self._t0 is not None else now))
         self._t0 = None
         gap = max(0.0, wall - t["launch_s"])
         t["wall_s"] = wall
         t["host_gap_s"] = gap
-        launches = sum(p["launches"] for p in t["programs"].values())
         with self._lock:
-            self.issued += t["issued"]
-            self.useful += t["useful"]
-            self.pad_waste += t["pad"]
-            self.spec_waste += t["spec_waste"]
+            _fold(self._process, t)
+            if t["profiled"]:
+                _fold(self._profiled, t)
             for tenant, share in t["tenants"].items():
                 self.by_tenant[tenant] = (self.by_tenant.get(tenant, 0)
                                           + share)
-            self.ticks += 1
-            self.launches += launches
-            self.launch_wall_s += t["launch_s"]
-            self.host_gap_s += gap
             self._gaps.append(gap)
             self._window.append((now, wall, t["useful"]))
             self._prune_window(now)
@@ -259,7 +376,7 @@ class UtilizationLedger:
         tick's BEGIN to now, so a single tick reads its own wall."""
         if not self.peak_flops:
             return 0.0
-        now = self._clock()
+        now = self.clock()
         with self._lock:
             self._prune_window(now)
             if not self._window:
@@ -277,26 +394,20 @@ class UtilizationLedger:
         return sorted_vals[i]
 
     def snapshot(self) -> dict:
-        """Full JSON state for ``/utilization``: lifetime totals (integer
-        flops, conservation checkable by the reader), per-tenant bills,
-        host-gap percentiles and the last tick's decomposition."""
+        """Full JSON state for ``/utilization``: lifetime totals (integers,
+        conservation checkable by the reader) of FLOPs, time and, per
+        program, positions and K,V rows; the same totals over the ticks
+        that ran wholly inside a profiler session (``profiled``); per-tenant
+        bills, host-gap percentiles and the last tick's decomposition."""
         with self._lock:
             gaps = sorted(self._gaps)
-            out = {
-                "flops": {
-                    "issued": self.issued, "useful": self.useful,
-                    "pad_waste": self.pad_waste,
-                    "spec_waste": self.spec_waste,
-                },
-                "tenants": dict(self.by_tenant),
-                "ticks": self.ticks,
-                "launches": self.launches,
-                "launch_wall_s": round(self.launch_wall_s, 6),
-                "host_gap_s": round(self.host_gap_s, 6),
-                "last_tick": self.last_tick,
-            }
-        if self.issued:
-            out["useful_ratio"] = round(self.useful / self.issued, 6)
+            out = _account_view(self._process)
+            out["profiled"] = _account_view(self._profiled)
+            out["tenants"] = dict(self.by_tenant)
+            out["last_tick"] = self.last_tick
+        issued = out["flops"]["issued"]
+        if issued:
+            out["useful_ratio"] = round(out["flops"]["useful"] / issued, 6)
         for q, name in ((0.50, "host_gap_p50_s"), (0.99, "host_gap_p99_s")):
             v = self._pct(gaps, q)
             if v is not None:

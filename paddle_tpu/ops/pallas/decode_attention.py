@@ -246,6 +246,7 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None, block_k=None,
                 jax.ShapeDtypeStruct((BH, nb, sg, 1), jnp.float32),
             ],
             interpret=_interpret(),
+            name="_splitkv_kernel",
         )(lengths, qr, kf, vf)
     return _combine_partials(o_p, m_p, l_p, B, Hkv, S, G, D, q.dtype)
 
@@ -362,6 +363,7 @@ def _paged_decode_attention_impl(q, k_pages, v_pages, block_tables, lengths,
                 jax.ShapeDtypeStruct((B, Hkv, NB, sg, 1), jnp.float32),
             ],
             interpret=_interpret(),
+            name="_paged_kernel",
         )(block_tables, lengths, qr, k_pages, v_pages)
     o_p = o_p.reshape(BH, NB, sg, D)
     m_p = m_p.reshape(BH, NB, sg, 1)

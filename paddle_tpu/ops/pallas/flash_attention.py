@@ -163,6 +163,7 @@ def _mha_fwd(q, k, v, sri, causal, scale, block_q):
                 jax.ShapeDtypeStruct((bh, seq, 1), jnp.float32),
             ],
             interpret=_interpret(),
+            name="_fwd_kernel",
         )(*args)
     return out, lse.reshape(bh, seq)
 
@@ -344,6 +345,7 @@ def _mha_bwd_chunked(q, k, v, out, lse, g, causal, scale):
             out_specs=pl.BlockSpec((1, blk, d), lambda b, i, j: (b, i, 0)),
             out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
             interpret=_interpret(),
+            name="_dq_kernel_chunked",
         )(q, k, v, g, lse, delta)
         dk, dv = pl.pallas_call(
             functools.partial(_dkv_kernel_chunked, scale=scale, causal=causal,
@@ -366,6 +368,7 @@ def _mha_bwd_chunked(q, k, v, out, lse, g, causal, scale):
                 jax.ShapeDtypeStruct(v.shape, jnp.float32),
             ],
             interpret=_interpret(),
+            name="_dkv_kernel_chunked",
         )(q, k, v, g, lse, delta)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
@@ -404,6 +407,7 @@ def _mha_bwd(q, k, v, sri, out, lse, g, causal, scale, block_q):
             out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             interpret=_interpret(),
+            name="_dq_kernel",
         )(*dq_args)
 
     dkv_in_specs = [
@@ -436,6 +440,7 @@ def _mha_bwd(q, k, v, sri, out, lse, g, causal, scale, block_q):
                 jax.ShapeDtypeStruct(v.shape, v.dtype),
             ],
             interpret=_interpret(),
+            name="_dkv_kernel",
         )(*dkv_args)
     return dq, dk, dv
 

@@ -15,6 +15,8 @@ import threading
 import time
 from enum import Enum
 
+from jax.profiler import TraceAnnotation
+
 
 class ProfilerState(Enum):
     # reference profiler.py:89
@@ -115,26 +117,53 @@ _now_us = lambda: time.perf_counter_ns() / 1e3  # noqa: E731
 class RecordEvent:
     """Reference utils.py:47 — context manager/decorator marking a host range.
 
-    Events land in the active Profiler's buffer. Usable standalone::
+    The range is a ``jax.profiler.TraceAnnotation``: inside any
+    ``jax.profiler`` capture it lands on ``/host:CPU`` of the same xplane as
+    the device's ``XLA Ops``, on the capture's one clock, with ``stats`` as
+    the event's stats; with no capture running it is a no-op. While a
+    Profiler of this module records, the range also lands in its host
+    buffer, on ``time.perf_counter``. Usable standalone::
 
-        with profiler.RecordEvent("data_copy"):
+        with profiler.RecordEvent("data_copy", rows=n) as ev:
             ...
+            ev.set_stats(copied=m)      # what is only known at the end
     """
 
     def __init__(self, name: str,
-                 event_type: TracerEventType = TracerEventType.PythonUserDefined):
+                 event_type: TracerEventType = TracerEventType.PythonUserDefined,
+                 **stats):
         self.name = name
         self.event_type = event_type
+        self.stats = stats
         self._start = None
+        self._annotation = None
 
     def begin(self):
+        self._annotation = TraceAnnotation(self.name, **self.stats)
+        self._annotation.__enter__()
         self._start = _now_us()
+
+    @staticmethod
+    def capturing():
+        """True inside a ``jax.profiler`` capture: the only time a range's
+        stats are kept, so work done only for them can be skipped."""
+        return TraceAnnotation.is_enabled()
+
+    def set_stats(self, **stats):
+        """Add stats to the open range's annotation."""
+        if self._annotation is not None:
+            self._annotation.set_metadata(**stats)
 
     def end(self):
         if self._start is None:
             return
-        _collector.add(_HostEvent(self.name, self._start, _now_us(),
-                                  threading.get_ident(), self.event_type))
+        end_us = _now_us()
+        self._annotation.__exit__(None, None, None)
+        self._annotation = None
+        if _collector.recording:
+            _collector.add(_HostEvent(self.name, self._start, end_us,
+                                      threading.get_ident(),
+                                      self.event_type))
         self._start = None
 
     def __enter__(self):
@@ -150,7 +179,7 @@ class RecordEvent:
 
         @functools.wraps(fn)
         def wrapped(*args, **kwargs):
-            with RecordEvent(self.name, self.event_type):
+            with RecordEvent(self.name, self.event_type, **self.stats):
                 return fn(*args, **kwargs)
 
         return wrapped
@@ -306,9 +335,10 @@ class Profiler:
     def chrome_events(self):
         """Complete-event ("X") dicts of the collected host events, sorted by
         start time. Timestamps are ``time.perf_counter`` microseconds — the
-        same timebase paddle_tpu.observability.trace uses, so these merge
-        with serving spans via observability.export_joined_chrome with no
-        clock alignment."""
+        timebase of paddle_tpu.observability.trace, so these merge with
+        serving spans via observability.export_joined_chrome. (The device's
+        ops are not on it: RecordEvent puts the same ranges into a
+        ``jax.profiler`` capture for that.)"""
         trace = []
         for ev in self.events:
             trace.append({

@@ -97,7 +97,9 @@ def test_einsum():
     a = np.random.randn(2, 3).astype(np.float32)
     b = np.random.randn(3, 4).astype(np.float32)
     out = paddle.einsum("ij,jk->ik", paddle.to_tensor(a), paddle.to_tensor(b))
-    np.testing.assert_allclose(out.numpy(), a @ b, rtol=1e-5)
+    # atol: unseeded normals now and then land a product near 0, where a
+    # relative bound alone fails on the last f32 bit
+    np.testing.assert_allclose(out.numpy(), a @ b, rtol=1e-5, atol=1e-6)
 
 
 def test_creation():

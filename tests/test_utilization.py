@@ -183,12 +183,13 @@ def test_ledger_tick_math_on_fake_clock():
     assert t["host_gap_s"] == pytest.approx(0.60)
     assert set(t["programs"]) == {"prefill_chunk", "decode_step"}
     assert t["programs"]["prefill_chunk"]["launches"] == 1
-    assert led.last_tick is t and led.ticks == 1 and led.launches == 2
+    assert led.last_tick is t
     # MFU: 1300 useful flops over 1.0s at peak 10k FLOP/s
     assert led.mfu() == pytest.approx(1300 / 10_000.0)
     snap = led.snapshot()
     assert snap["flops"] == {"issued": 4000, "useful": 1300,
                              "pad_waste": 2700, "spec_waste": 0}
+    assert snap["ticks"] == 1 and snap["launches"] == 2
     assert snap["tenants"] == {"gold": 1000, "bronze": 300}
     assert snap["useful_ratio"] == pytest.approx(1300 / 4000)
     assert snap["host_gap_p50_s"] == pytest.approx(0.60)
@@ -203,7 +204,7 @@ def test_ledger_warmup_and_clamp_guards():
     led = UtilizationLedger(peak_flops=None, clock=clk)
     # a launch OUTSIDE any tick (compile warmup) must not count
     led.record_launch("prefill_chunk", 999, 0.1, 8, [(None, 8)])
-    assert led.issued == 0 and led.last_tick is None
+    assert led.snapshot()["flops"]["issued"] == 0 and led.last_tick is None
     # launch wall can exceed tick wall on clock jitter: gap clamps to 0
     led.tick_begin()
     led.record_launch("decode_step", 100, 5.0, 8, [(None, 2)])
@@ -231,7 +232,8 @@ def test_ledger_mfu_window_prunes_old_ticks():
     clk.tick(20.0)  # window passed: nothing retained -> 0.0
     assert led.mfu() == 0.0
     # lifetime totals are NOT windowed
-    assert led.useful == 500 and led.issued == 500
+    assert led.snapshot()["flops"]["useful"] == 500
+    assert led.snapshot()["flops"]["issued"] == 500
 
 
 # ------------------------------------------------------- exposition lint
@@ -261,7 +263,7 @@ def test_ledger_series_render_and_mfu_gauge_absent_iff_no_peak():
         if line.startswith("paddle_serving_flops_total{"):
             k = line.split('kind="', 1)[1].split('"', 1)[0]
             vals[k] = float(line.rsplit(" ", 1)[1])
-    assert sum(vals.values()) == led.issued == 1000
+    assert sum(vals.values()) == led.snapshot()["flops"]["issued"] == 1000
 
     # counter monotonicity across scrapes
     led.tick_begin()
@@ -345,7 +347,16 @@ def test_scheduler_conservation_after_every_tick_mixed_traffic(small_gpt):
             for p in t["programs"].values():
                 assert p["issued"] == (p["useful"] + p["pad"]
                                        + p["spec_waste"])
+                # ISSUE-26: the same law in positions, off no FLOPs probe
+                assert p["issued_positions"] == (
+                    p["useful_positions"] + p["pad_positions"]
+                    + p["spec_positions"])
+                assert min(p["useful_positions"], p["pad_positions"],
+                           p["spec_positions"]) >= 0
+                assert 0 <= p["live_rows"] <= p["table_rows"]
             assert t["wall_s"] >= 0 and t["host_gap_s"] >= 0
+            assert t["launch_s"] == pytest.approx(
+                t["dispatch_s"] + t["wait_s"])
         snap = sched.util.snapshot()
         fl = snap["flops"]
         assert fl["issued"] == sum(t["issued"] for t, _ in ticks)
@@ -356,6 +367,15 @@ def test_scheduler_conservation_after_every_tick_mixed_traffic(small_gpt):
         assert fl["useful"] > 0 and fl["pad_waste"] > 0
         assert fl["spec_waste"] > 0, \
             "spec traffic ran but no rejected-draft FLOPs were attributed"
+        progs = snap["programs"]
+        assert set(progs) == {"prefill_chunk", "verify_step"}
+        ver = progs["verify_step"]
+        assert ver["spec_positions"] > 0 and ver["pad_positions"] > 0
+        assert 0 < ver["live_rows"] < ver["table_rows"]
+        assert sum(p["issued_positions"] for p in progs.values()) == sum(
+            q["issued_positions"] for t, _ in ticks
+            for q in t["programs"].values())
+        assert snap["launches"] == sum(p["launches"] for p in progs.values())
         assert snap["mfu"] is None          # CPU: no peak, no made-up MFU
         # flight-recorder snapshots carry the tick decomposition
         d = sched.flight.dump()
@@ -415,8 +435,10 @@ def test_preemption_pause_never_bills_the_paused_tenant(small_gpt):
 
 
 def test_scheduler_off_means_off(small_gpt):
-    """utilization=False (the default): no ledger object, no wants_flops
-    hook, none of the series in the exposition."""
+    """utilization=False (the default): nothing shown and nothing probed —
+    `util` is None, no wants_flops hook, none of the series in the
+    exposition. The tick ledger every scheduler keeps (ISSUE-26) counts
+    on, off plain integers, and carries no FLOPs."""
     sched = _make(small_gpt, utilization=False)
     try:
         assert sched.util is None
@@ -427,6 +449,9 @@ def test_scheduler_off_means_off(small_gpt):
         assert "paddle_tenant_flops_total" not in text
         assert "paddle_serving_mfu" not in text
         assert "paddle_serving_host_gap_seconds" not in text
+        snap = sched._ledger.snapshot()
+        assert snap["ticks"] > 0 and snap["flops"]["issued"] == 0
+        assert snap["programs"]["decode_step"]["issued_positions"] > 0
     finally:
         sched.close()
 
