@@ -5,8 +5,8 @@ kernel.cu + the BlockManager half of vLLM's design (Kwon et al., SOSP 2023).
 TPU-native shape: one shared per-layer page pool on device ([num_blocks,
 block_size, Hkv, D]); each request owns a block TABLE (host ints) handed to
 the paged decode-attention kernel (ops/pallas/decode_attention.py), which
-reads pages through a scalar-prefetched index map — no gather
-materialization. Mixed-length requests in a batch therefore hold
+DMAs each request's live pages through its scalar-prefetched table row — no
+gather materialization, and nothing read past a request's length. Mixed-length requests in a batch therefore hold
 ceil(len/block_size) blocks each instead of every request padding to the
 server-wide max length.
 

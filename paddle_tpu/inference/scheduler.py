@@ -1533,7 +1533,7 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             return out, clock() - t0
 
     def _util_launch(self, program, wait_s, total_units, slot_units,
-                     spec_units=0, live_rows=0, table_rows=0):
+                     spec_units=0, live_rows=0, walked_rows=0, table_rows=0):
         """Account for the tick's launch, read back and absorbed: observe
         `paddle_decode_launch_seconds` with the launch THROUGH its
         read-back, and hand the ledger its time split, positions and rows.
@@ -1547,7 +1547,7 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             self._ledger.record_launch(
                 program, info.get("flops"), launch_s, total_units,
                 slot_units, spec_units, wait_s=wait_s, live_rows=live_rows,
-                table_rows=table_rows)
+                walked_rows=walked_rows, table_rows=table_rows)
         except ThreadDeath:
             raise
         except Exception:       # pragma: no cover - telemetry must not bite
@@ -1897,7 +1897,7 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
         (toks,), wait_s = self._read_back("decode", toks)
         self._span_each(reqs, "decode_step", t0, self.tracer.now_us(),
                         slots=len(dec), steps=T)
-        live_rows, table_rows = self._kv_rows(lengths[active], T)
+        live_rows, walked_rows, table_rows = self._kv_rows(lengths[active], T)
         units = []
         with RecordEvent("serve.decode.absorb") as ev:
             for i, s in dec:
@@ -1912,18 +1912,35 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                 ev.set_stats(useful=sum(u for _, u in units), issued=S * T,
                              rows=live_rows)
         self._util_launch("decode_step", wait_s, S * T, units,
-                          live_rows=live_rows, table_rows=table_rows)
+                          live_rows=live_rows, walked_rows=walked_rows,
+                          table_rows=table_rows)
 
-    def _kv_rows(self, lengths, steps):
-        """(live_rows, table_rows) of one decode or verify launch: the
-        context lengths of the active slots summed over the launch's token
-        steps — a slot with `length` rows in the pool attends over
-        length + t + 1 at step t — against the rows the block tables handed
-        to the launch span, slots x table width x block size x steps."""
+    def _kv_rows(self, lengths, steps, one_call=False):
+        """(live_rows, walked_rows, table_rows) of one decode or verify
+        launch. live: the context lengths of the active slots summed over
+        the launch's token steps — a slot with `length` rows in the pool
+        attends over length + t + 1 at step t. walked: the rows the paged
+        kernel's loop visits for them, the same lengths rounded up to its
+        block of pages (`paged_walk_blocks`, which also gives the kernel its
+        trip count): a decode tick is one call a token step with one new
+        row each, a verify launch (`one_call`) one call whose `steps` query
+        rows all walk the slot's length + steps. table: the rows the block
+        tables handed to the launch span, slots x table width x block size
+        x steps."""
+        from ..ops.pallas import decode_attention as da
+
         live = int(steps * np.sum(lengths)
                    + len(lengths) * steps * (steps + 1) // 2)
-        return live, (self.max_slots * self.table_width
-                      * self.kv_cache.block_size * steps)
+        span = self.table_width * self.kv_cache.block_size
+        block = (da.paged_pages_per_step(self.table_width,
+                                         self.kv_cache.block_size)
+                 * self.kv_cache.block_size)
+        lengths = np.asarray(lengths, np.int64)
+        calls = ([(lengths, steps)] * steps if one_call
+                 else [(lengths + t, 1) for t in range(steps)])
+        walked = block * sum(int(da.paged_walk_blocks(ln, new, block).sum())
+                             for ln, new in calls)
+        return live, walked, self.max_slots * span * steps
 
     def _compiled_now(self, program):
         """1 if the launch the hook just stashed had to build `program`."""
@@ -2017,7 +2034,8 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
         with self._slot_lock:
             self._spec_drafted += drafted
             self._spec_accepted += accepted
-        live_rows, table_rows = self._kv_rows(offs[active], K + 1)
+        live_rows, walked_rows, table_rows = self._kv_rows(
+            offs[active], K + 1, one_call=True)
         units = []
         with RecordEvent("serve.decode.absorb") as ev:
             for i, s in dec:
@@ -2036,7 +2054,8 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                              issued=S * (K + 1), rows=live_rows)
         self._util_launch("verify_step", wait_s, S * (K + 1), units,
                           spec_units=drafted - accepted,
-                          live_rows=live_rows, table_rows=table_rows)
+                          live_rows=live_rows, walked_rows=walked_rows,
+                          table_rows=table_rows)
 
     # ------------------------------------------------------------- lifecycle
     def _abandon_slots(self):

@@ -144,8 +144,9 @@ class GPTAttention(Layer):
                     pos = da.write_positions(ln, S, valid=vld,
                                              capacity=capacity)
                     kp, vp = da.paged_cache_update(kp, vp, kv, vv, tbl, pos)
-                    out = da.paged_decode_attention(qv, kp, vp, tbl, ln,
-                                                    scale=scale, kernel=kernel)
+                    out = da.paged_decode_attention(
+                        qv, kp, vp, tbl, ln, scale=scale, kernel=kernel,
+                        new_rows=da.valid_new_rows(vld, S))
                     return out, kp, vp
 
                 out, k_cache, v_cache = apply_op(

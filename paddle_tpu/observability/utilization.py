@@ -39,9 +39,13 @@ Beside the FLOPs, every launch counts POSITIONS with the same conservation
 (``issued_positions == useful + pad + spec``: S x C, S x T, S x (K+1)
 issued; prompt tokens taken / tokens absorbed useful) and, for decode and
 verify, K,V ROWS: ``live_rows``, the context lengths of the active slots
-summed over the launch's token steps, against ``table_rows``, the rows the
-block tables handed to the launch span (slots x table width x block size x
-token steps: the scheduler's geometry, not the kernel's grid). These are
+summed over the launch's token steps; ``walked_rows``, the rows the paged
+kernel's loop visits for them (each length rounded up to the kernel's block
+of pages: ``live / walked`` is the kernel's efficiency); and ``table_rows``,
+the rows the block tables handed to the launch span (slots x table width x
+block size x token steps: the scheduler's geometry, which the kernel's grid
+walked before it followed the lengths: ``walked / table`` is what that
+saved). These are
 plain integers and need no FLOPs probe, so every continuous scheduler keeps
 them; ``utilization=True`` adds the probe and the exported series.
 
@@ -137,7 +141,8 @@ _FLOPS = ("issued", "useful", "pad", "spec_waste")
 _POSITIONS = ("issued_positions", "useful_positions", "pad_positions",
               "spec_positions")
 _PROGRAM_KEYS = (_FLOPS + ("launches",) + _POSITIONS
-                 + ("live_rows", "table_rows", "dispatch_s", "wait_s"))
+                 + ("live_rows", "walked_rows", "table_rows", "dispatch_s",
+                    "wait_s"))
 _TICK_KEYS = _FLOPS + ("launch_s", "dispatch_s", "wait_s")
 
 
@@ -293,7 +298,7 @@ class UtilizationLedger:
 
     def record_launch(self, program, flops, launch_s, total_units,
                       slot_units, spec_units=0, *, wait_s=0.0, live_rows=0,
-                      table_rows=0):
+                      walked_rows=0, table_rows=0):
         """Attribute one launch inside the current tick. ``launch_s`` is
         the launch THROUGH its read-back, ``wait_s`` the read-back's part
         of it. ``total_units`` are the positions the program issued,
@@ -328,6 +333,7 @@ class UtilizationLedger:
         p["spec_positions"] += int(spec_units)
         p["pad_positions"] += int(total_units) - useful_pos - int(spec_units)
         p["live_rows"] += int(live_rows)
+        p["walked_rows"] += int(walked_rows)
         p["table_rows"] += int(table_rows)
 
     def tick_end(self):

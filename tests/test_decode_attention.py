@@ -130,6 +130,147 @@ def test_paged_parity_and_update():
     assert changed.sum() == 2                   # pages 1 and 2 only
 
 
+def _paged_batch(S, G, D, dtype, rng, Hkv=2):
+    """Six slots over one pool of 128-row pages, table 3 wide (384 rows; the
+    kernel folds 2 pages a trip, so the table is 1.5 blocks): lengths 0,
+    k*BS - 1, k*BS and the full table, and two IDLE slots (table of page 0,
+    no valid new row). Returns the arguments and, per slot, the pages it
+    may read."""
+    BS, NB, P = 128, 3, 14
+    lengths = np.array([0, 2 * BS - 1, 2 * BS, NB * BS - S, 0, 77])
+    new_rows = np.array([S, S, S, S, 0, 0])
+    tables = np.zeros((6, NB), np.int32)
+    pages = iter(rng.permutation(np.arange(1, P)))
+    owned = []
+    for b in range(6):
+        n = -(-(lengths[b] + new_rows[b]) // BS) if new_rows[b] else 0
+        tables[b, :n] = [next(pages) for _ in range(n)]
+        owned.append(tables[b, :n].copy())
+    dt = jnp.dtype(dtype)
+    q = _rand((6, S, Hkv * G, D), dt, rng)
+    k_pages = _rand((Hkv, P, BS, D), dt, rng)
+    v_pages = _rand((Hkv, P, BS, D), dt, rng)
+    return (q, k_pages, v_pages, jnp.asarray(tables),
+            jnp.asarray(lengths, jnp.int32),
+            jnp.asarray(new_rows, jnp.int32)), owned
+
+
+@pytest.mark.parametrize("S,G,D,dtype,Hkv,budget", [
+    (1, 1, 64, "bfloat16", 2, None),
+    (1, 4, 128, "float32", 2, None),
+    (1, 1, 64, "float32", 3, 2),      # 3 heads, room for 2: one a step
+    (3, 4, 64, "bfloat16", 6, 4),     # 6 heads, room for 4: three a step
+    (3, 1, 128, "float32", 2, None),
+    (128, 1, 64, "bfloat16", 2, None),
+    (128, 4, 128, "bfloat16", 1, None),
+])
+def test_paged_kernel_follows_lengths(S, G, D, dtype, Hkv, budget,
+                                      monkeypatch):
+    """The length-bounded paged kernel against `decode_attention_xla` through
+    the gather path: decode, verify and prefill-chunk widths, grouped heads,
+    both head sizes (D = 64 runs the lane-packed form), both pool dtypes,
+    lengths on every side of a page and a block edge, idle slots beside
+    live ones, and a head count the VMEM budget does not divide."""
+    rng = np.random.default_rng(11)
+    args, _ = _paged_batch(S, G, D, dtype, rng, Hkv)
+    if budget is not None:
+        per_head = da._VMEM_BUDGET // da.paged_tiling(
+            1 << 10, 3, 128, D, S * G, jnp.dtype(dtype).itemsize)[0]
+        monkeypatch.setattr(da, "_VMEM_BUDGET", budget * per_head)
+        hps, pps = da.paged_tiling(Hkv, 3, 128, D, S * G,
+                                   jnp.dtype(dtype).itemsize)
+        assert Hkv % budget and hps < budget and Hkv % hps == 0 and pps == 2
+    *dense, new_rows = args
+    got = np.asarray(da.paged_decode_attention(*dense, kernel="pallas",
+                                               new_rows=new_rows), np.float32)
+    ref = np.asarray(da.paged_decode_attention(*dense, kernel="xla"),
+                     np.float32)
+    live = np.asarray(new_rows) > 0
+    tol = 2e-5 if dtype == "float32" else 3e-2
+    np.testing.assert_allclose(got[live], ref[live], atol=tol, rtol=tol)
+    assert np.isfinite(got).all()          # idle slots: finite, ignored
+
+
+def test_paged_kernel_reads_nothing_past_a_length():
+    """Work follows length: with NaN in every page no live slot owns (page
+    0, which the idle slots' tables and every table's tail name, among
+    them) and in every owned page past the one a slot's last row is in, the
+    kernel's output is finite and equal to the clean pool's. A kernel that
+    fetched a dead table column, or walked an idle slot, would read NaN
+    into a 0 x NaN product."""
+    rng = np.random.default_rng(12)
+    for S in (3,):
+        (q, k_pages, v_pages, tables, lengths, new_rows), owned = \
+            _paged_batch(S, 2, 64, "float32", rng)
+        # the prefill-chunk shape of a valid mask: slot 1 has one valid row
+        valid = np.arange(S)[None, :] < np.asarray(new_rows)[:, None]
+        valid[1, 1:] = False
+        new_rows = da.valid_new_rows(jnp.asarray(valid), S)
+        assert list(np.asarray(new_rows)) == [S, 1, S, S, 0, 0]
+        clean = np.ones(k_pages.shape[1], bool)
+        for b, pages in enumerate(owned):
+            rows = int(lengths[b]) + int(new_rows[b])
+            clean[pages[:-(-rows // 128)]] = False
+        poison = jnp.asarray(clean)[None, :, None, None]
+        bad_k = jnp.where(poison, jnp.nan, k_pages)
+        bad_v = jnp.where(poison, jnp.nan, v_pages)
+        want = np.asarray(da.paged_decode_attention(
+            q, k_pages, v_pages, tables, lengths, new_rows=new_rows))
+        got = np.asarray(da.paged_decode_attention(
+            q, bad_k, bad_v, tables, lengths, new_rows=new_rows))
+        assert np.isfinite(got).all()
+        np.testing.assert_array_equal(got, want)
+        assert not np.asarray(got[4:]).any()    # idle slots: zeros
+
+
+# Compiled for the chip, without the chip: the TPU's compiler is installed
+# here and compiles for a described v5e. The interpreter proves the math;
+# only this says Mosaic takes the kernel at the serving shapes. The topology
+# is described inside the fixture (never at import: one worker at a time may
+# load libtpu) and these tests live in this one file.
+@pytest.fixture(scope="module")
+def v5e_chip():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:     # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("B,S,Hq,Hkv,D,BS,P,NB", [
+    (32, 1, 20, 20, 64, 32, 640, 32),      # gpt2-large decode_step
+    (32, 128, 20, 20, 64, 32, 640, 32),    # gpt2-large prefill_chunk
+    (32, 3, 10, 10, 64, 32, 640, 32),      # verify_step on a tp=2 shard
+    (8, 64, 32, 8, 128, 16, 256, 64),      # llama GQA, D=128
+])
+def test_paged_kernel_compiles_for_the_v5e(v5e_chip, monkeypatch, B, S, Hq,
+                                           Hkv, D, BS, P, NB):
+    import jax
+
+    monkeypatch.setattr(da, "_interpret", lambda: False)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    pool = arg((Hkv, P, BS, D), jnp.bfloat16)
+    # the suite's "highest" matmul precision is for comparisons with numpy;
+    # the chip runs the default, and Mosaic has no f32 pass over bf16 operands
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(
+            lambda q, kp, vp, tbl, ln, new: da._paged_decode_attention_impl(
+                q, kp, vp, tbl, ln, new)).lower(
+            arg((B, S, Hq, D), jnp.bfloat16), pool, pool,
+            arg((B, NB), jnp.int32), arg((B,), jnp.int32),
+            arg((B,), jnp.int32)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
 def test_no_x64_leak_into_pallas_calls():
     """paddle_tpu runs with jax_enable_x64 on; any f64/i64 operand reaching a
     pallas_call breaks Mosaic on the real chip (no f64 vector ops). Trace both

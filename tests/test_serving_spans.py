@@ -188,7 +188,7 @@ def test_ledger_launch_is_dispatch_plus_wait_and_positions_conserve():
                       [("a", 128), (None, 60)], wait_s=0.6)
     led.record_launch("verify_step", 1000, 0.30, 12, [("a", 2), ("b", 1)],
                       spec_units=3, wait_s=0.25, live_rows=70,
-                      table_rows=4 * 5 * 8 * 3)
+                      walked_rows=96, table_rows=4 * 5 * 8 * 3)
     clk.tick(1.0)
     t = led.tick_end()
     assert t["launch_s"] == pytest.approx(0.95)
@@ -201,7 +201,9 @@ def test_ledger_launch_is_dispatch_plus_wait_and_positions_conserve():
                                                              3908, 0)
     assert (ver["issued_positions"], ver["useful_positions"],
             ver["pad_positions"], ver["spec_positions"]) == (12, 3, 6, 3)
-    assert (ver["live_rows"], ver["table_rows"]) == (70, 480)
+    assert (ver["live_rows"], ver["walked_rows"],
+            ver["table_rows"]) == (70, 96, 480)
+    assert pre["walked_rows"] == 0
     assert pre["dispatch_s"] == pytest.approx(0.05)
     snap = led.snapshot()
     assert snap["programs"]["verify_step"] == ver
@@ -418,8 +420,65 @@ def test_positions_and_rows_against_a_hand_count_for_two_slots(small_gpt):
     hand = sum(2 * ((L + 1) + (L + 2)) for L in (4, 6, 8))
     assert dec["live_rows"] == hand == 90
     assert dec["table_rows"] == 3 * (4 * 5 * 8 * 2) == 960
+    # a table of 40 rows is under one block of the kernel's walk: each of
+    # the 2 active slots walks all of it at each of a launch's 2 steps
+    assert dec["walked_rows"] == 3 * (2 * 2 * 40) == 480
     assert len(ticks) == 3 and not any(t["profiled"] for t in ticks)
-    assert sched._kv_rows(np.array([7, 20]), 2) == (2 * 27 + 2 * 3, 320)
+    assert sched._kv_rows(np.array([7, 20]), 2) == (2 * 27 + 2 * 3, 160, 320)
+
+
+def test_walked_rows_are_the_kernels_own_trip_counts(monkeypatch):
+    """The ledger's `walked_rows` cannot drift from the kernel: `_kv_rows`
+    and the kernel's wrapper take their blocks from one helper, so the trip
+    counts the wrapper hands the kernel's call (caught here, at the width of
+    a decode step and of a verify launch) times the block's rows ARE the
+    count, on every side of a page and a block edge."""
+    from types import SimpleNamespace
+
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import decode_attention as da
+
+    BS, NB, Hkv, D = 32, 12, 2, 64
+    caught = []
+    real = da._paged_pallas
+
+    def spy(q, k_pages, v_pages, tables, lengths, trips, pages, **static):
+        caught.append(np.asarray(trips))
+        return real(q, k_pages, v_pages, tables, lengths, trips, pages,
+                    **static)
+
+    monkeypatch.setattr(da, "_paged_pallas", spy)
+    pool = jnp.zeros((Hkv, 2, BS, D), jnp.bfloat16)
+    tables = jnp.zeros((4, NB), jnp.int32)
+    lengths = np.array([0, 255, 256, 300])
+    T = 3
+
+    def trips(S, at):
+        da.paged_decode_attention(jnp.zeros((4, S, Hkv, D), jnp.bfloat16),
+                                  pool, pool, tables, at)
+        return int(caught[-1].sum())
+
+    block = BS * da.paged_tiling(Hkv, NB, BS, D, 1, 2)[1]
+    assert block == 256
+    sched = SimpleNamespace(max_slots=6, table_width=NB,
+                            kv_cache=SimpleNamespace(block_size=BS))
+    rows = ContinuousGenerateBatchingPredictor._kv_rows
+    live, walked, table = rows(sched, lengths, T)
+    assert walked == block * sum(trips(1, lengths + t) for t in range(T))
+    # blocks at step 0, 1, 2 for lengths 0 / 255 / 256 / 300
+    assert walked == block * ((1 + 1 + 2 + 2) + (1 + 2 + 2 + 2) * 2)
+    assert live <= walked <= table == 6 * NB * BS * T
+    # a verify launch is ONE call whose T rows all walk length + T
+    assert rows(sched, lengths, T, one_call=True)[1] == (
+        T * block * trips(T, lengths))
+    # a slot with no valid new row is not walked; a table the block does not
+    # divide is walked to the block's end (the kernel fetches its last live
+    # page again for the columns past it, masked)
+    da.paged_decode_attention(jnp.zeros((4, 1, Hkv, D), jnp.bfloat16), pool,
+                              pool, tables, lengths,
+                              new_rows=np.array([1, 0, 1, 0]))
+    assert list(caught[-1]) == [1, 0, 2, 0]
+    assert rows(sched, np.array([NB * BS - 1]), 1)[1] == 2 * block
 
 
 # ------------------------------------------------ spans on the trace's clock
@@ -459,6 +518,8 @@ def test_profiler_session_holds_the_tick_spans_and_only_its_ticks(
     acc = snap["profiled"]
     assert acc["ticks"] == 3 < snap["ticks"] == 2 * before["ticks"] + 3
     assert acc["programs"]["decode_step"]["live_rows"] == 90
+    assert acc["programs"]["decode_step"]["walked_rows"] == 480
+    assert snap["programs"]["decode_step"]["walked_rows"] > 480
     assert acc["programs"]["prefill_chunk"]["useful_positions"] == 8
     assert 0 < acc["wait_s"] < acc["wall_s"]
     assert acc["launch_wall_s"] == pytest.approx(

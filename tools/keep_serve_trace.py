@@ -19,7 +19,9 @@ writes under `--out`:
   launch_histogram.txt     `paddle_decode_launch_seconds` as /metrics
                            rendered it just before the server closed
   program_counters.json    the three `program_counter` readers beside
-                           `device_idle.serve` of the same window
+                           `device_idle.serve` of the same window, and the
+                           decode launches' walked rows as a share of the
+                           tables' and the live rows as a share of those
 
 The trace itself is as large as the run is long (hundreds of MB): it is
 read here and removed by `run.finish`, as in every traced run."""
@@ -71,8 +73,8 @@ def keep(job, outcome, out_dir):
         trace.summarize(outcome.trace_dir, out=f)
     with open(os.path.join(out_dir, "trace_gaps.txt"), "w") as f:
         trace_gaps.report(*trace_gaps.read(outcome.trace_dir), out=f)
-    write("ledgers.json", json.dumps(
-        [led.snapshot() for led in utilization.ledgers()], indent=1))
+    snaps = [led.snapshot() for led in utilization.ledgers()]
+    write("ledgers.json", json.dumps(snaps, indent=1))
     reduced = trace.reduce(trace.read_xplane(outcome.trace_dir))
     write("mosaic_names.json", json.dumps(mosaic_names(reduced["events"][0])))
     view = View(cfg=job.cfg, mix=job.mix, peaks=job.peaks, chips=job.chips,
@@ -81,6 +83,15 @@ def keep(job, outcome, out_dir):
     values = {name: layer_reader(ROOT, name)(view)
               for name in COUNTER_READERS + ("device_idle.serve",)}
     values.update(window_s=reduced["window_s"], busy_s=reduced["busy_s"])
+    # how far the paged kernel's walk follows the lengths (PR 27): the rows
+    # its loop visited against the tables' and against the live ones
+    rows = [p for s in snaps for name, p in s["profiled"]["programs"].items()
+            if name == "decode_step" and p.get("walked_rows")]
+    if len(rows) == 1:
+        values["walked_of_table_rows_pct"] = (
+            100.0 * rows[0]["walked_rows"] / rows[0]["table_rows"])
+        values["live_of_walked_rows_pct"] = (
+            100.0 * rows[0]["live_rows"] / rows[0]["walked_rows"])
     write("program_counters.json", json.dumps(values))
     return values
 
