@@ -1,9 +1,11 @@
-"""Operations and bytes the algorithms need, from the configuration and the
-shapes alone. No recompute is counted, and no padding: these are the
-numerators of `mfu.*` and of the kernels' rooflines.
+"""Operations and bytes the `gpt2` family's algorithms need, from the
+configuration and the shapes alone. No recompute is counted, and no padding:
+these are the numerators of `mfu.*` and of the kernels' rooflines.
 
 A configuration is the dict of its file (`n_embd`, `n_layer`, `n_head`,
-`vocab_size`, `n_inner`)."""
+`vocab_size`, `n_inner`). Every family gives `prompt_work`, `token_work`,
+`kv_bytes_per_row` and `train_flops_per_token`; the rest is this family's
+own, and `flash_train_cost` is the cost of a kernel only its cells run."""
 
 
 def _dims(cfg):
@@ -77,8 +79,19 @@ def prompt_flops(cfg, plen):
             + lm_head_flops_per_token(cfg))
 
 
-def roofline_seconds(flops, nbytes, peaks):
-    """Least time the chip could take, and which peak sets it."""
-    compute = flops / peaks["bf16_flops"]
-    memory = nbytes / peaks["hbm_bytes_per_s"]
-    return (compute, "compute") if compute >= memory else (memory, "memory")
+def prompt_work(cfg, plen):
+    """What serving a prompt of `plen` tokens takes: all operations of its
+    forward, those of them that are attention's, and the K,V rows the
+    attention must read (each row at least once)."""
+    return {"model_flops": prompt_flops(cfg, plen),
+            "attention_flops": cfg["n_layer"] * attention_flops_per_token(
+                cfg, plen * (plen + 1) // 2),
+            "kv_rows": plen}
+
+
+def token_work(cfg, context):
+    """The same for one output token that attends to `context` keys."""
+    return {"model_flops": serve_token_flops(cfg, context, sampled=True),
+            "attention_flops": cfg["n_layer"] * attention_flops_per_token(
+                cfg, context),
+            "kv_rows": context}

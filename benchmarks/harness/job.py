@@ -1,7 +1,9 @@
 """What one run is given and what it hands back, whatever the kind of mix."""
 import dataclasses
+import functools
 import importlib.util
 import os
+import re
 import shutil
 import sys
 import time
@@ -14,6 +16,71 @@ def log(msg):
     started: where set-up goes is read off these."""
     print(f"[bench {time.perf_counter() - START:6.1f}s] {msg}",
           file=sys.stderr, flush=True)
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(
+        re.sub(r"\W", "_", name), path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+class Family:
+    """What is one architecture's own, found by file: the directory
+    `benchmarks/families/<model_type>/`, `model_type` being the key every
+    configuration file carries. Two halves kept apart:
+
+      model.py    the plain half: `leaves`, `layer_kinds`, `embed_leaves`,
+                  `layer_leaves`, `head_leaves`, `embed`, `layer`, `head`,
+                  `stack`, `leaf_names`, `parts`, `leaf_norms`,
+                  `forward_bytes`
+      counts.py   operations and bytes: `prompt_work`, `token_work`,
+                  `kv_bytes_per_row`, `train_flops_per_token`, and the costs
+                  of the family's own kernels
+      program.py  the only file that imports the program: `build_model`,
+                  `state_key`; loaded when first asked for, so the reference
+                  and the readers never import the program
+
+    A later PR adds a family as a directory and edits nothing."""
+    MODEL = ("leaves", "layer_kinds", "embed_leaves", "layer_leaves",
+             "head_leaves", "embed", "layer", "head", "stack", "leaf_names",
+             "parts", "leaf_norms", "forward_bytes")
+    COUNTS = ("prompt_work", "token_work", "kv_bytes_per_row",
+              "train_flops_per_token")
+    PROGRAM = ("build_model", "state_key")
+
+    def __init__(self, root, model_type):
+        self.name = model_type
+        self.folder = os.path.join(root, "benchmarks", "families", model_type)
+        if not os.path.isdir(self.folder):
+            raise FileNotFoundError(
+                f"no family {model_type!r}: {self.folder} is the directory a "
+                f"configuration of that model_type needs")
+        self.model = self._half("model", self.MODEL)
+        self.counts = self._half("counts", self.COUNTS)
+
+    def _half(self, part, contract):
+        module = _load(os.path.join(self.folder, f"{part}.py"),
+                       f"bench_family_{self.name}_{part}")
+        missing = [f for f in contract if not callable(getattr(module, f, None))]
+        if missing:
+            raise AttributeError(f"{module.__file__} lacks {missing}")
+        return module
+
+    @functools.cached_property
+    def program(self):
+        return self._half("program", self.PROGRAM)
+
+
+@functools.lru_cache(maxsize=None)
+def load_family(root, model_type):
+    """One Family a (checkout, model_type) in a process."""
+    return Family(root, model_type)
+
+
+HERE = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))         # the checkout this harness is in
 
 
 @dataclasses.dataclass
@@ -29,6 +96,11 @@ class Job:
     trace: bool
     t0: float               # time.perf_counter() at process start
     peaks: dict = None      # the chip's peaks (None off the chip)
+    family: Family = None   # the configuration's family, loaded from `root`
+
+    def __post_init__(self):
+        if self.family is None:
+            self.family = load_family(self.root, self.cfg["model_type"])
 
     @property
     def chips(self):
@@ -67,11 +139,7 @@ def layer_reader(root, name):
     path = os.path.join(folder, f"{name}.py")
     if not os.path.isfile(path):
         path = os.path.join(folder, f"{name.rsplit('.', 1)[0]}.py")
-    spec = importlib.util.spec_from_file_location(
-        "layer_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return _load(path, "layer_metric_" + name).read
 
 
 @dataclasses.dataclass
@@ -85,3 +153,8 @@ class View:
     window_s: float
     busy_s: float
     events: list            # per device plane, op events clipped to the window
+    family: Family = None   # the configuration's (this checkout's, if unsaid)
+
+    def __post_init__(self):
+        if self.family is None and self.cfg.get("model_type"):
+            self.family = load_family(HERE, self.cfg["model_type"])

@@ -10,6 +10,13 @@ PEAKS = {
 }
 
 
+def roofline_seconds(flops, nbytes, peaks):
+    """Least time the chip could take, and which peak sets it."""
+    compute = flops / peaks["bf16_flops"]
+    memory = nbytes / peaks["hbm_bytes_per_s"]
+    return (compute, "compute") if compute >= memory else (memory, "memory")
+
+
 def peaks_for(device_kind):
     try:
         return PEAKS[device_kind]

@@ -1,78 +1,74 @@
-"""The one module that touches the program (`paddle_tpu`): it builds the
-system under test from a configuration file, hands it the benchmark's
-weights, and reads its counters. Everything measured or compared lives in
-the other modules and never imports the program."""
+"""The harness's module that touches the program (`paddle_tpu`): it hands the
+model that the configuration's family builds (`families/<model_type>/
+program.py`) the benchmark's weights, and wraps the train step and the
+server. Everything measured or compared lives in the other modules and never
+imports the program."""
+import math
+
 import jax
 import jax.numpy as jnp
 
 from . import weights as W
 
-# the program's state_dict key of each leaf of the benchmark's tree
-TOP_KEYS = {"wte": "gpt.embed_tokens.weight",
-            "wpe": "gpt.embed_positions.weight",
-            "ln_f_w": "gpt.ln_f.weight", "ln_f_b": "gpt.ln_f.bias"}
-BLOCK_KEYS = {"ln1_w": "ln1.weight", "ln1_b": "ln1.bias",
-              "qkv_w": "attn.qkv_proj.weight", "qkv_b": "attn.qkv_proj.bias",
-              "out_w": "attn.out_proj.weight", "out_b": "attn.out_proj.bias",
-              "ln2_w": "ln2.weight", "ln2_b": "ln2.bias",
-              "fc1_w": "mlp.fc1.weight", "fc1_b": "mlp.fc1.bias",
-              "down_w": "mlp.down.weight", "down_b": "mlp.down.bias"}
+
+PART_BYTES = 2 ** 30    # of the checkpoint alive beside the model's leaves
 
 
-def state_key(kind, layer):
-    if layer is None:
-        return TOP_KEYS[kind]
-    return f"gpt.blocks.{layer}.{BLOCK_KEYS[kind]}"
+def state_parts(family, cfg, seed, *, round_to=None, out_dtype="float32"):
+    """The benchmark's weights as the program keeps them, a part at a time:
+    yields {state_dict key: value} for the family's leaves in sorted order,
+    as many a part as stay under PART_BYTES (one leaf at least), a leaf that
+    is stacked over layers as its layers. The whole tree is never made."""
+    model = family.model
+    stacked = {entry[0] for i in range(len(model.layer_kinds(cfg)))
+               for entry in model.layer_leaves(cfg, i).values()
+               if not isinstance(entry, str)}
+    size = jnp.dtype(out_dtype).itemsize
+    parts, room = [], 0
+    for name, (shape, _, _) in sorted(model.leaves(cfg).items()):
+        nbytes = size * math.prod(shape)
+        if not parts or nbytes > room:
+            parts.append({})
+            room = PART_BYTES
+        room -= nbytes
+        for i in (range(shape[0]) if name in stacked else (None,)):
+            parts[-1][name if i is None else (name, i)] = (
+                family.program.state_key(name, i))
+    for keys in parts:
+        drawn = W.make_weights(family, cfg, seed, only=list(keys),
+                               round_to=round_to, out_dtype=out_dtype)
+        yield {keys[entry]: value for entry, value in drawn.items()}
 
 
-def build_model(cfg):
-    """GPTForCausalLM with the GPT-2 block, at the file's sizes."""
-    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
-
-    return GPTForCausalLM(GPTConfig(
-        vocab_size=cfg["vocab_size"], hidden_size=cfg["n_embd"],
-        num_layers=cfg["n_layer"], num_heads=cfg["n_head"],
-        intermediate_size=cfg["n_inner"], max_position=cfg["n_positions"],
-        dropout=0.0, use_rope=False, use_rms_norm=False, use_swiglu=False,
-        tie_embeddings=True))
-
-
-@jax.jit
-def _unstack(tree):
-    out = {}
-    for kind, value in tree.items():
-        if kind in TOP_KEYS:
-            out[TOP_KEYS[kind]] = value
-        else:
-            for i in range(value.shape[0]):
-                out[state_key(kind, i)] = value[i]
-    return out
-
-
-def load_weights(model, cfg, seed, *, serve):
+def load_weights(family, model, cfg, seed, *, serve):
     """Replace the model's parameters by the benchmark's, made from the seed
-    on the device: bfloat16 for serving (the checkpoint's own values), float32
-    masters for training."""
-    tree = (W.make_weights(cfg, seed, round_to="bfloat16",
-                           out_dtype="bfloat16") if serve
-            else W.make_weights(cfg, seed))
-    flat = _unstack(tree)
+    on the device part by part: bfloat16 for serving (the checkpoint's own
+    values), float32 masters for training. Beside the model's own leaves at
+    most one part is alive."""
     state = model.state_dict()
-    if set(state) != set(flat):
-        raise KeyError(f"the model's leaves are not the benchmark's: "
-                       f"{sorted(set(state) ^ set(flat))[:6]}")
-    for key, tensor in state.items():
-        if tuple(tensor.shape) != tuple(flat[key].shape):
-            raise ValueError(f"{key}: model {tuple(tensor.shape)} against "
-                             f"{tuple(flat[key].shape)}")
-        tensor._value = flat[key]
+    todo = set(state)
+    kind = (dict(round_to="bfloat16", out_dtype="bfloat16") if serve else {})
+    for part in state_parts(family, cfg, seed, **kind):
+        for key, value in part.items():
+            if key not in todo:
+                raise KeyError(f"the benchmark's leaf {key!r} is none of the "
+                               f"model's, or came twice")
+            if tuple(state[key].shape) != tuple(value.shape):
+                raise ValueError(f"{key}: model {tuple(state[key].shape)} "
+                                 f"against {tuple(value.shape)}")
+            state[key]._value = value
+            todo.remove(key)
+    if todo:
+        raise KeyError(f"leaves of the model that the benchmark did not "
+                       f"make: {sorted(todo)[:6]}")
 
 
-def ordered_leaves(cfg, by_key):
-    """Values of a {state_dict key: value} map in `weights.leaf_names`
+def ordered_leaves(family, cfg, by_key):
+    """Values of a {state_dict key: value} map in the family's `leaf_names`
     order, fused projections split into their parts."""
-    return [W.parts(kind, by_key[state_key(kind, layer)])[part]
-            for kind, part, layer in W.leaf_names(cfg)]
+    return [family.model.parts(
+        kind, by_key[family.program.state_key(kind, layer)])[part]
+        for kind, part, layer in family.model.leaf_names(cfg)]
 
 
 @jax.jit
@@ -95,14 +91,14 @@ class Trainer:
     product runs in bfloat16. ONE object: set-up drives its first steps and
     hands it to the window."""
 
-    def __init__(self, cfg, opt, seed):
+    def __init__(self, family, cfg, opt, seed):
         import paddle_tpu as paddle
         from paddle_tpu.jit.train import TrainStep
         from paddle_tpu.nn.clip import ClipGradByGlobalNorm
 
-        self.paddle, self.cfg, self.opt = paddle, cfg, opt
-        self.model = build_model(cfg)
-        load_weights(self.model, cfg, seed, serve=False)
+        self.paddle, self.family, self.cfg, self.opt = paddle, family, cfg, opt
+        self.model = family.program.build_model(cfg)
+        load_weights(family, self.model, cfg, seed, serve=False)
         self.optimizer = paddle.optimizer.AdamW(
             learning_rate=opt["learning_rate"], beta1=opt["beta1"],
             beta2=opt["beta2"], epsilon=opt["epsilon"],
@@ -130,20 +126,24 @@ class Trainer:
         m1 = (1 - beta1) * g."""
         m1 = {self._keys[pid]: v for pid, v in
               self.optimizer._accumulators["moment1"].items()}
-        return _norms(ordered_leaves(self.cfg, m1)) / (1 - self.opt["beta1"])
+        return (_norms(ordered_leaves(self.family, self.cfg, m1))
+                / (1 - self.opt["beta1"]))
 
     def parameters_now(self):
-        return ordered_leaves(
-            self.cfg, {k: t._value for k, t in self.model.state_dict().items()})
+        return ordered_leaves(self.family, self.cfg, {
+            k: t._value for k, t in self.model.state_dict().items()})
 
     def delta_norms(self, start):
         return _delta_norms(self.parameters_now(), start)
 
 
-def start_leaves(cfg, seed):
+def start_leaves(family, cfg, seed):
     """The parameters a Trainer of this seed starts from, made again from
     the seed (its own are donated away by the first step)."""
-    return ordered_leaves(cfg, _unstack(W.make_weights(cfg, seed)))
+    by_key = {}
+    for part in state_parts(family, cfg, seed):
+        by_key.update(part)
+    return ordered_leaves(family, cfg, by_key)
 
 
 def memory_peak_bytes():
@@ -160,15 +160,15 @@ class Server:
     prefill_chunk, decode_steps, spec_k, max_new_tokens, decode_kernel,
     prefix_cache."""
 
-    def __init__(self, cfg, geometry, seed):
+    def __init__(self, family, cfg, geometry, seed):
         from paddle_tpu.inference.scheduler import (
             ContinuousGenerateBatchingPredictor,
         )
 
         self.cfg = cfg
-        self.model = build_model(cfg)
+        self.model = family.program.build_model(cfg)
         self.model.eval()
-        load_weights(self.model, cfg, seed, serve=True)
+        load_weights(family, self.model, cfg, seed, serve=True)
         self.pred = ContinuousGenerateBatchingPredictor(
             self.model, warmup=True, **geometry)
 

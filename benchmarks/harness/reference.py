@@ -1,22 +1,19 @@
-"""The plain reference: GPT-2 in straightforward jax.numpy, float32, matmuls
-at `highest` precision, no kernel, no cache, no batching tricks. It follows
-the published description (Radford et al. 2019; the `gpt2` model of the
-source's config.json): learned positions, pre-LayerNorm blocks with biases,
-fused qkv projection split as q | k | v, causal softmax attention, a 4h MLP
-with the activation the configuration names, a final LayerNorm, the output
-head tied to the token embedding; loss is the mean cross entropy over all
-positions; AdamW (Loshchilov & Hutter 2019) after clipping by the global norm.
+"""The plain reference, as far as it is the same for every family: float32,
+matmuls at `highest` precision, no kernel, no cache, no batching tricks. The
+forward itself is the family's (`families/<model_type>/model.py`: embed, one
+layer, head); here are the product it is handed, the loss (the mean cross
+entropy over all positions), AdamW (Loshchilov & Hutter 2019) after clipping
+by the global norm, and the served tokens' logit gaps.
 
 It imports nothing of the program and takes nothing the program made. Weights
-are `weights.make_weights(cfg, seed)`; block leaves are stacked over layers
-and scanned. `quant=True` is the CONTROL: every linear product (qkv, out, fc1,
-down, the head) with both operands rounded to fp8 (e4m3: 3 bits of mantissa,
-each tensor scaled so that its largest value sits at the format's 448) — the
-nearest precision below bfloat16 that a later PR might be tempted by. (int8
-per tensor keeps about 2 bits fewer than bfloat16 on bell-shaped values and
-read only 1.1-2.3 times the program's gaps on the chip; e4m3 keeps 5 fewer.)"""
+are `weights.make_weights(family, cfg, seed)`. `quant=True` is the CONTROL:
+every linear product the family sends through `mm` with both operands rounded
+to fp8 (e4m3: 3 bits of mantissa, each tensor scaled so that its largest
+value sits at the format's 448) — the nearest precision below bfloat16 that
+a later PR might be tempted by. (int8 per tensor keeps about 2 bits fewer
+than bfloat16 on bell-shaped values and read only 1.1-2.3 times the
+program's gaps on the chip; e4m3 keeps 5 fewer.)"""
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -48,93 +45,45 @@ def _mm(x, w, quant):
     return jnp.matmul(x, w, precision=HIGHEST)
 
 
-def _layer_norm(x, w, b, eps):
-    mean = jnp.mean(x, -1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mean), -1, keepdims=True)
-    return (x - mean) / jnp.sqrt(var + eps) * w + b
+def product(quant):
+    """The `mm(x, w)` a family's forward is handed: plain, or the control's."""
+    return functools.partial(_mm, quant=quant)
 
 
-def _gelu(x, form):
-    if form == "gelu_new":      # the tanh form of the GPT-2 source
-        return 0.5 * x * (1.0 + jnp.tanh(
-            math.sqrt(2.0 / math.pi) * (x + 0.044715 * x ** 3)))
-    if form == "gelu":
-        return 0.5 * x * (1.0 + jax.lax.erf(x / math.sqrt(2.0)))
-    raise ValueError(f"unknown activation_function {form!r}")
+def logits_fn(family, cfg, tree, ids, quant=False, remat=False):
+    """ids: [B, S] int -> logits [B, S, vocab] float32, over a whole tree:
+    the family's embed, its layers in turn, its head."""
+    model, mm = family.model, product(quant)
+    x = model.embed(cfg, W.pick(tree, model.embed_leaves(cfg)), ids)
+    x = model.stack(cfg, tree, x, mm, remat)
+    return model.head(cfg, W.pick(tree, model.head_leaves(cfg)), x, mm)
 
 
-def _block(cfg, quant, x, p):
-    """x: [B, S, h] float32; p: one layer's leaves."""
-    batch, seq, h = x.shape
-    heads = cfg["n_head"]
-    dim = h // heads
-    eps = cfg["layer_norm_epsilon"]
-    a = _layer_norm(x, p["ln1_w"], p["ln1_b"], eps)
-    qkv = _mm(a, p["qkv_w"], quant) + p["qkv_b"]
-    q, k, v = (t.reshape(batch, seq, heads, dim).transpose(0, 2, 1, 3)
-               for t in jnp.split(qkv, 3, axis=-1))
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision=HIGHEST)
-    scores = scores / math.sqrt(dim)
-    causal = jnp.tril(jnp.ones((seq, seq), bool))
-    scores = jnp.where(causal, scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    ctx = jnp.einsum("bhqk,bhkd->bhqd", probs, v, precision=HIGHEST)
-    ctx = ctx.transpose(0, 2, 1, 3).reshape(batch, seq, h)
-    x = x + _mm(ctx, p["out_w"], quant) + p["out_b"]
-    m = _layer_norm(x, p["ln2_w"], p["ln2_b"], eps)
-    m = _gelu(_mm(m, p["fc1_w"], quant) + p["fc1_b"],
-              cfg["activation_function"])
-    return x + _mm(m, p["down_w"], quant) + p["down_b"]
-
-
-def logits_fn(cfg, params, ids, quant=False, remat=False):
-    """ids: [B, S] int -> logits [B, S, vocab] float32."""
-    seq = ids.shape[1]
-    x = params["wte"][ids] + params["wpe"][:seq]
-    body = functools.partial(_block, cfg, quant)
-    if remat:
-        body = jax.checkpoint(body)
-    x, _ = jax.lax.scan(lambda c, p: (body(c, p), None), x,
-                        {k: params[k] for k in W.BLOCK_KINDS})
-    x = _layer_norm(x, params["ln_f_w"], params["ln_f_b"],
-                    cfg["layer_norm_epsilon"])
-    return _mm(x, params["wte"].T, quant)
-
-
-def loss_sum(cfg, params, ids, labels, quant=False):
+def loss_sum(family, cfg, tree, ids, labels, quant=False):
     """Summed token cross entropy of rows [B, S] (the caller divides)."""
-    logits = logits_fn(cfg, params, ids, quant, remat=True)
+    logits = logits_fn(family, cfg, tree, ids, quant, remat=True)
     logz = jax.nn.logsumexp(logits, axis=-1)
     picked = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
     return jnp.sum(logz - picked)
-
-
-def leaf_norms(cfg, tree):
-    """L2 norm of every leaf, in `weights.leaf_names` order."""
-    def norm(x, axes):
-        return jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)), axes))
-    top = [norm(tree[k], None).reshape(1) for k in W.TOP_KINDS]
-    blocks = [norm(part, tuple(range(1, part.ndim)))
-              for k in W.BLOCK_KINDS for part in W.parts(k, tree[k])]
-    return jnp.concatenate(top + blocks)
 
 
 class TrainReference:
     """Follows the first steps of a training job, row by row so that float32
     attention scores of one row are all that is alive at a time."""
 
-    def __init__(self, cfg, opt, seed, *, quant=False, drop_half=False):
+    def __init__(self, family, cfg, opt, seed, *, quant=False,
+                 drop_half=False):
         self.cfg, self.opt = cfg, opt
         self.quant, self.drop_half = quant, drop_half
-        self.params = W.make_weights(cfg, seed)
-        self.start = W.make_weights(cfg, seed)   # params are donated
+        self.params = W.make_weights(family, cfg, seed)
+        self.start = W.make_weights(family, cfg, seed)   # params are donated
         zeros = jax.jit(lambda t: jax.tree.map(jnp.zeros_like, t))
         self.m, self.v = zeros(self.params), zeros(self.params)
         self.t = 0
         self._row = jax.jit(jax.value_and_grad(
-            functools.partial(loss_sum, cfg, quant=quant)))
+            functools.partial(loss_sum, family, cfg, quant=quant)))
         self._add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b))
-        self._norms = jax.jit(functools.partial(leaf_norms, cfg))
+        self._norms = jax.jit(functools.partial(family.model.leaf_norms, cfg))
         self._update = jax.jit(self._adamw, donate_argnums=(0, 1, 2))
 
     def _adamw(self, params, m, v, grads, t, scale):
@@ -178,30 +127,128 @@ class TrainReference:
         return np.asarray(self._norms(diff))
 
 
-@functools.partial(jax.jit, static_argnames=("cfg_items", "control"))
-def _serve_gaps(params, ids, cfg_items, control):
-    cfg = dict(cfg_items)
-    ref = logits_fn(cfg, params, ids)[0]                  # [S, vocab]
+def gap_rows(ref, nxt, low=None):
+    """ref: [N, vocab] the reference's logits at N positions; nxt: [N] the
+    token served next at each. `served_gap[i]` is how far the reference's
+    logit of that token lies below the reference's best; with `low`, the
+    fp8 control's logits at the same positions, `control_gap[i]` is the same
+    for the token that the control puts first."""
     best = jnp.max(ref, -1)
-    nxt = jnp.concatenate([ids[0, 1:], ids[0, :1]])
-    served = jnp.take_along_axis(ref, nxt[:, None], -1)[:, 0]
-    out = {"served_gap": best - served}
-    if control:
-        low = logits_fn(cfg, params, ids, quant=True)[0]
-        first = jnp.argmax(low, -1)
-        out["control_gap"] = best - jnp.take_along_axis(
-            ref, first[:, None], -1)[:, 0]
+
+    def below(tokens):
+        return best - jnp.take_along_axis(ref, tokens[:, None], -1)[:, 0]
+    out = {"served_gap": below(nxt)}
+    if low is not None:
+        out["control_gap"] = below(jnp.argmax(low, -1))
     return out
 
 
-def serve_gaps(cfg, params, ids, *, control=False):
-    """ids: one request's prompt and served tokens, padded to a fixed length,
-    [1, S]. Position i predicts ids[i + 1]: `served_gap[i]` is how far the
-    reference's logit of that next token lies below the reference's best;
-    `control_gap[i]` the same for the token that the fp8 control puts
-    first. The caller keeps the positions that predict a served token."""
-    items = tuple(sorted((k, v) for k, v in cfg.items()
-                         if isinstance(v, (int, float, str))
-                         and not isinstance(v, bool)))
-    return {k: np.asarray(v) for k, v in
-            _serve_gaps(params, jnp.asarray(ids), items, control).items()}
+class ServeCheck:
+    """The reference over finished requests, by one of two routes made of
+    the same three pieces of the family. Where the float32 image of the
+    checkpoint and a row's activations fit the chip, the whole image is made
+    once and each row runs through it in one program. Where they do not (a
+    10 GB bfloat16 checkpoint has a 20 GB image), the image is never made:
+    one layer's float32 leaves are drawn, every row's activations carried
+    through that layer, the leaves freed, and so on; the head runs over the
+    positions that are compared. Which route is reckoned from the family's
+    shapes and the chip's memory, not from a switch."""
+    ROOM = 0.75         # of the chip's memory, for the image and a forward
+    PARKED = 0.125      # of it, for the rows' activations between layers
+
+    def __init__(self, family, cfg, seed, width, *, most_new=None,
+                 hbm_bytes=None, control=False):
+        self.family, self.cfg, self.seed = family, cfg, seed
+        self.width, self.hbm_bytes = width, hbm_bytes
+        # the reference alone, or the fp8 control (quant=True) beside it
+        self.sides = (False, True) if control else (False,)
+        # head rows a call: the compared ones, as many as an answer has at
+        # most; the control scales a tensor by its largest value, so it
+        # sees a whole row there as it does in the whole image's program
+        self.block = width if control else min(width, most_new or width)
+
+    def whole_image_fits(self):
+        """Off the chip (no `hbm_bytes`) there is nothing to fit."""
+        if self.hbm_bytes is None:
+            return True
+        need = (W.image_bytes(self.family, self.cfg) + len(self.sides)
+                * self.family.model.forward_bytes(self.cfg, self.width))
+        return need <= self.ROOM * self.hbm_bytes
+
+    def gaps(self, rows):
+        """rows: [(ids [1, width] int, keep)], a request's prompt and served
+        tokens padded to the width, and the slice of positions that predict
+        a served token (position i predicts ids[i + 1]). Returns, a row,
+        {"served_gap": [kept], "control_gap": [kept]} as numpy."""
+        route = self._whole if self.whole_image_fits() else self._streamed
+        return route([(jnp.asarray(ids), keep) for ids, keep in rows])
+
+    def _whole(self, rows):
+        tree = W.make_weights(self.family, self.cfg, self.seed,
+                              round_to="bfloat16")
+
+        @jax.jit
+        def run(tree, ids):
+            ref, low = [logits_fn(self.family, self.cfg, tree, ids, quant)[0]
+                        if quant in self.sides else None
+                        for quant in (False, True)]
+            return gap_rows(ref, jnp.concatenate([ids[0, 1:], ids[0, :1]]),
+                            low)
+        return [{k: np.asarray(v)[keep] for k, v in run(tree, ids).items()}
+                for ids, keep in rows]
+
+    def _leaves(self, entries):
+        drawn = W.make_weights(self.family, self.cfg, self.seed,
+                               only=list(entries.values()),
+                               round_to="bfloat16")
+        return W.pick(drawn, entries)
+
+    def _streamed(self, rows):
+        model, cfg = self.family.model, self.cfg
+        p = self._leaves(model.embed_leaves(cfg))
+        embed = jax.jit(functools.partial(model.embed, cfg))
+        start = [embed(p, ids) for ids, _ in rows]
+        # between layers the rows wait on the device, or on the host where
+        # they would crowd the layer out
+        crowded = (self.hbm_bytes is not None and len(self.sides) * sum(
+            x.nbytes for x in start) > self.PARKED * self.hbm_bytes)
+        park = np.asarray if crowded else (lambda x: x)
+        acts = {quant: [park(x) for x in start] for quant in self.sides}
+        del p, start
+        # one program a kind of layer and route, whatever the layer
+
+        @functools.partial(jax.jit, static_argnums=(2, 3))
+        def run(p, x, kind, quant):
+            return model.layer(cfg, kind, p, x, product(quant))
+        for index, kind in enumerate(model.layer_kinds(cfg)):
+            p = self._leaves(model.layer_leaves(cfg, index))
+            for quant in self.sides:
+                acts[quant] = [park(run(p, x, kind, quant))
+                               for x in acts[quant]]
+            del p
+        p = self._leaves(model.head_leaves(cfg))
+
+        @jax.jit
+        def gaps(p, ids, x, x_low, at):
+            def rows(a):
+                """`self.block` positions from `at` on; the caller drops
+                what lies outside the kept ones."""
+                a = jnp.pad(a, [(0, self.block)] + [(0, 0)] * (a.ndim - 1))
+                return jax.lax.dynamic_slice_in_dim(a, at, self.block)
+            low = (None if x_low is None
+                   else model.head(cfg, p, rows(x_low[0]), product(True)))
+            return gap_rows(model.head(cfg, p, rows(x[0]), product(False)),
+                            rows(ids[0, 1:]), low)
+        out = []
+        for r, (ids, keep) in enumerate(rows):
+            got = []
+            first = 0 if True in acts else keep.start
+            for at in range(first, keep.stop, self.block):
+                g = gaps(p, ids, acts[False][r], acts[True][r]
+                         if True in acts else None, at)
+                got.append({k: np.asarray(v)[max(keep.start - at, 0):
+                                             keep.stop - at]
+                            for k, v in g.items()})
+            out.append({k: np.concatenate([g[k] for g in got])
+                        for k in got[0]})
+        return out

@@ -16,7 +16,7 @@ import time
 import jax
 import numpy as np
 
-from . import flops, program, reference, trace, traffic, weights
+from . import program, reference, trace, traffic
 from .job import Outcome, log
 
 DRAIN_SECONDS = 60.0        # wait this long past the close for answers due
@@ -162,15 +162,16 @@ def pool_occupancy(answers, geometry, seconds, step=0.25):
             "pages_mean": float(np.mean(pages)), "pages_peak": max(pages)}
 
 
-def traced_work(cfg, answers, a, b):
+def traced_work(family, cfg, answers, a, b):
     """Useful work inside [a, b] by the benchmark's own request records. An
     output token after the first is one decode pass at the moment its flush
     arrived. A prompt's forward ran somewhere in [sent, first token]: the
     estimate spreads it evenly over that span; `*_low` counts a prompt only
     where the whole span lies inside [a, b], `*_high` wherever it overlaps,
     so the truth lies between the two whatever the scheduler did when.
-    Counts operations and the K,V rows the attention must read."""
-    layers = cfg["n_layer"]
+    Counts operations and the K,V rows the attention must read, as the
+    family's `counts.py` gives them for a prompt and for an output token."""
+    counts = family.counts
     out = dict(model_flops=0.0, attention_flops=0.0, kv_rows=0.0,
                prompt_tokens=0.0, output_tokens=0)
     low = dict(model_flops=0.0, kv_rows=0.0)
@@ -183,31 +184,26 @@ def traced_work(cfg, answers, a, b):
         span = max(first - c.sent, 1e-9)
         share = max(0.0, min(b, first) - max(a, c.sent)) / span
         if share > 0:
-            work = flops.prompt_flops(cfg, plen)
-            out["model_flops"] += share * work
-            out["attention_flops"] += share * layers * (
-                flops.attention_flops_per_token(cfg, plen * (plen + 1) // 2))
-            out["kv_rows"] += share * plen          # each row at least once
+            work = counts.prompt_work(cfg, plen)
+            for key, value in work.items():
+                out[key] += share * value
             out["prompt_tokens"] += share * plen
             inside = a <= c.sent and first <= b
-            for side, counts in ((low, inside), (high, True)):
-                if counts:
-                    side["model_flops"] += work
-                    side["kv_rows"] += plen
+            for side, counted in ((low, inside), (high, True)):
+                if counted:
+                    for key in side:
+                        side[key] += work[key]
         j = 0
         for t, n in c.flushes:
             for k in range(j, j + n):
                 if k >= 1 and a <= t <= b:          # token 0 is the prompt's
-                    ctx = plen + k
-                    work = flops.serve_token_flops(cfg, ctx, sampled=True)
-                    out["model_flops"] += work
-                    out["attention_flops"] += layers * (
-                        flops.attention_flops_per_token(cfg, ctx))
-                    out["kv_rows"] += ctx
+                    work = counts.token_work(cfg, plen + k)
+                    for key, value in work.items():
+                        out[key] += value
                     out["output_tokens"] += 1
                     for side in (low, high):
-                        side["model_flops"] += work
-                        side["kv_rows"] += ctx
+                        for key in side:
+                            side[key] += work[key]
             j += n
     for key in low:
         out[f"{key}_low"], out[f"{key}_high"] = low[key], high[key]
@@ -232,32 +228,33 @@ def wrong_token_counts(answers):
     return sum(1 for a in answers if a.ok and len(a.tokens) != a.req.max_new)
 
 
-def served_logit_gaps(cfg, seed, answers, *, control=False):
-    """Run the reference once over each answer's prompt and served tokens.
+def served_logit_gaps(family, cfg, seed, answers, width, *, most_new=None,
+                      hbm_bytes=None, control=False):
+    """Run the reference once over each answer's prompt and served tokens,
+    `width` positions a row (the mix's `geometry.max_seq_len`).
     `served_logit_gap`: the widest gap by which a served token's reference
     logit lies below the reference's best at its position; with `control`,
-    `control_logit_gap`: the same for the token the fp8 control puts first."""
-    params = weights.make_weights(cfg, seed, round_to="bfloat16")
-    width = cfg["n_positions"]
-    worst = {"served_gap": 0.0, "control_gap": 0.0}
-    compared = 0
+    `control_logit_gap`: the same for the token the fp8 control puts first.
+    `hbm_bytes` (the chip's memory) decides whether the whole float32 image
+    is made or the reference runs a layer at a time: `reference.ServeCheck`."""
+    rows = []
     for prompt, tokens in answers:
         plen, n = len(prompt), len(tokens)
         ids = np.zeros((1, width), np.int64)
         ids[0, :plen] = prompt
         ids[0, plen:plen + n] = tokens[:width - plen]
-        gaps = reference.serve_gaps(cfg, params, ids, control=control)
-        keep = slice(plen - 1, min(plen - 1 + n, width - 1))
-        compared += keep.stop - keep.start
-        for k, v in gaps.items():
-            worst[k] = max(worst[k], float(np.max(v[keep])))
-    log(f"compared {compared} served tokens of {len(answers)} requests")
+        rows.append((ids, slice(plen - 1, min(plen - 1 + n, width - 1))))
+    rows = [(ids, keep) for ids, keep in rows if keep.stop > keep.start]
+    compared = sum(keep.stop - keep.start for _, keep in rows)
+    log(f"comparing {compared} served tokens of {len(rows)} requests")
     if not compared:
         return {}
-    out = {"served_logit_gap": worst["served_gap"]}
-    if control:
-        out["control_logit_gap"] = worst["control_gap"]
-    return out
+    check = reference.ServeCheck(family, cfg, seed, width, most_new=most_new,
+                                 hbm_bytes=hbm_bytes, control=control)
+    gaps = check.gaps(rows)
+    return {f"{name}_logit_gap": float(max(np.max(g[f"{name}_gap"])
+                                           for g in gaps))
+            for name in (("served", "control") if control else ("served",))}
 
 
 def warm(server, cfg, mix, seed):
@@ -277,7 +274,7 @@ def run(job, make_server=program.Server):
     statistic of `request_metrics`, of which the cell reports those that
     BENCHMARK.json names for it."""
     mix, cfg = job.mix, job.cfg
-    server = make_server(cfg, mix["geometry"], job.seed)
+    server = make_server(job.family, cfg, mix["geometry"], job.seed)
     log("model built, weights made from the seed, server started")
     records, trace_dir = {}, None
     try:
@@ -319,7 +316,7 @@ def run(job, make_server=program.Server):
         metrics["setup_s"] = t_begin - job.t0   # the lead-in is set-up too
         if job.trace:
             a, b = mark["a"] - t_begin, mark["b"] - t_begin
-            records.update(traced_work(cfg, answers, a, b))
+            records.update(traced_work(job.family, cfg, answers, a, b))
             log("useful work in the traced window " + json.dumps(records))
         if server.compiles_after_ready():
             raise RuntimeError("a step program compiled after the warm-up")
@@ -333,7 +330,10 @@ def run(job, make_server=program.Server):
     gc.collect()
     jax.clear_caches()
     t_ref = time.perf_counter()
-    numbers = served_logit_gaps(cfg, job.seed, checked)
+    numbers = served_logit_gaps(
+        job.family, cfg, job.seed, checked, mix["geometry"]["max_seq_len"],
+        most_new=mix["geometry"].get("max_new_tokens"),
+        hbm_bytes=(job.peaks or {}).get("hbm_bytes"))
     numbers["wrong_token_counts"] = wrong_token_counts(answers)
     log(f"reference ran in {time.perf_counter() - t_ref:.1f}s")
     return Outcome(attempted=due, failed=failed, metrics=metrics,

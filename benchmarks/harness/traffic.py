@@ -74,8 +74,7 @@ def requests_due(mix, cfg, seed, due):
     order = rng_for(seed, 0)
     plens = order.permutation(quantile_lengths(mix["prompt"], n))
     outs = order.permutation(quantile_lengths(mix["output"], n))
-    limit = mix.get("max_total", cfg["n_positions"])
-    outs = np.minimum(outs, limit - plens)      # prompt + output <= positions
+    outs = np.minimum(outs, mix["max_total"] - plens)   # prompt + output
     if (outs < 1).any():
         raise ValueError("a prompt leaves no room for an output token")
     ids = rng_for(seed, 1)

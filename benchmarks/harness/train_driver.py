@@ -65,7 +65,8 @@ def first_steps(trainer, feed, job, steps):
         losses.append(trainer.step(x, y))
         if i == 0:
             grad_norms = trainer.first_gradient_norms()
-    delta = trainer.delta_norms(program.start_leaves(job.cfg, job.seed))
+    delta = trainer.delta_norms(
+        program.start_leaves(job.family, job.cfg, job.seed))
     return fed, {"losses": [float(v) for v in losses],
                  "grad_norms": np.asarray(grad_norms),
                  "delta_norms": np.asarray(delta)}
@@ -91,7 +92,8 @@ def _steps_for(trainer, feed, seconds):
 
 
 def reference_numbers(job, fed, *, quant=False, drop_half=False):
-    ref = reference.TrainReference(job.cfg, job.mix["optimizer"], job.seed,
+    ref = reference.TrainReference(job.family, job.cfg,
+                                   job.mix["optimizer"], job.seed,
                                    quant=quant, drop_half=drop_half)
     losses, grad_norms = [], None
     for i, (ids, labels) in enumerate(fed):
@@ -105,7 +107,7 @@ def reference_numbers(job, fed, *, quant=False, drop_half=False):
 
 def run(job, make_trainer=program.Trainer):
     mix = job.mix
-    trainer = make_trainer(job.cfg, mix["optimizer"], job.seed)
+    trainer = make_trainer(job.family, job.cfg, mix["optimizer"], job.seed)
     log("model built, weights made from the seed")
     feed = Feed(trainer, traffic.train_batches(mix, job.cfg, job.seed),
                 mix.get("queue_depth", 4))

@@ -1,6 +1,7 @@
 """Share of the chip's bf16 peak that the step programs used for USEFUL
 tokens in the traced window: prompt and output tokens of the benchmark's own
-request records, no padding and no masked slot."""
+request records, counted by the configuration's family
+(`serve_driver.traced_work`), no padding and no masked slot."""
 
 
 def read(view):

@@ -6,7 +6,8 @@ read at least once, and each query-key pair costs 4 * head_dim * heads
 operations a layer. Over the time the device spent in `_paged_kernel`, the
 step programs' only Mosaic call: the reader fails where it finds another
 number of them (see harness/trace.py)."""
-from benchmarks.harness import flops, trace
+from benchmarks.harness import trace
+from benchmarks.harness.peaks import roofline_seconds
 from benchmarks.harness.job import log
 
 
@@ -20,9 +21,9 @@ def read(view):
     seconds, calls = trace.mosaic_calls(view.events[0], (layers, 2 * layers))
     if not calls:
         return None
-    nbytes = rows * flops.kv_bytes_per_row(view.cfg)
-    least, bound = flops.roofline_seconds(view.records["attention_flops"],
-                                          nbytes, view.peaks)
+    nbytes = rows * view.family.counts.kv_bytes_per_row(view.cfg)
+    least, bound = roofline_seconds(view.records["attention_flops"], nbytes,
+                                    view.peaks)
     log(f"paged kernel: {calls} calls, {seconds:.4f}s on the "
         f"device, least {least:.4f}s ({bound}-bound)")
     return 100.0 * least / seconds

@@ -3,7 +3,8 @@
 
     python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-Loads the cell's configuration and traffic mix by name, refuses anything but
+Loads the cell's configuration, its family (`benchmarks/families/<the
+configuration's model_type>/`) and traffic mix by name, refuses anything but
 the chips the cell asks for (exit 2, no result), sets up (weights from the
 seed, compile or cache load, warm-up, the first steps), measures for
 `--seconds`, compares what the timed path produced with the plain reference,
@@ -82,7 +83,8 @@ def finish(job, outcome, device):
         log(f"trace read and reduced in {time.perf_counter() - t_read:.1f}s")
         device.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
         view = View(cfg=job.cfg, mix=job.mix, peaks=job.peaks,
-                    chips=job.chips, records=outcome.records,
+                    chips=job.chips, family=job.family,
+                    records=outcome.records,
                     window_s=reduced["window_s"], busy_s=reduced["busy_s"],
                     events=reduced["events"])
         metrics = {name: layer_reader(job.root, name)(view) for name in

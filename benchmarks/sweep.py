@@ -35,7 +35,8 @@ def main(argv=None):
     job = run.load_job(args.workload, args.seed, args.seconds, 0)
     run.require_chips(job)
     enable_compile_cache(os.path.join(ROOT, ".jax_cache"))
-    server = program.Server(job.cfg, job.mix["geometry"], job.seed)
+    server = program.Server(job.family, job.cfg, job.mix["geometry"],
+                            job.seed)
     try:
         server.wait_ready(job.mix.get("ready_timeout_s", 1100))
         serve_driver.warm(server, job.cfg, job.mix, job.seed)

@@ -20,11 +20,24 @@ def bench():
     return load_json("BENCHMARK.json")
 
 
+def family(model_type="gpt2", root=ROOT):
+    from benchmarks.harness.job import load_family
+
+    return load_family(root, model_type)
+
+
 def tiny_cfg(name="gpt2-medium", **over):
     cfg = load_json("benchmarks", "configs", f"{name}.json")
     cfg.update(vocab_size=257, n_positions=128, n_embd=64, n_layer=2, n_head=4)
     cfg.update(over)
     return cfg
+
+
+def tiny_large_cfg(**over):
+    """The second configuration at a tiny size of its own: another width,
+    depth and number of heads than `tiny_cfg()`."""
+    return tiny_cfg("gpt2-large", **dict(dict(n_embd=80, n_layer=3, n_head=5),
+                                         **over))
 
 
 def tiny_train_mix(**over):

@@ -2,11 +2,14 @@
 import numpy as np
 import pytest
 
-from _tiny import load_json
-from benchmarks.harness import flops, peaks, serve_driver, traffic
+from _tiny import ROOT, load_json
+from benchmarks.harness import peaks, serve_driver, traffic
+from benchmarks.harness.job import load_family
 
 MEDIUM = load_json("benchmarks", "configs", "gpt2-medium.json")
 LARGE = load_json("benchmarks", "configs", "gpt2-large.json")
+GPT2 = load_family(ROOT, "gpt2")
+flops = GPT2.counts
 
 
 @pytest.mark.parametrize("cfg,seq,want,about", [
@@ -27,7 +30,7 @@ def test_flash_cost_at_the_train_cells_shape():
     work, nbytes = flops.flash_train_cost(MEDIUM, 8, 1024)
     assert work == 7 * 67_108_864 * 128 == 60_129_542_144
     assert nbytes == 12 * 8 * 16 * 1024 * 64 * 2 == 201_326_592
-    least, bound = flops.roofline_seconds(work, nbytes, peaks.PEAKS["TPU v5 lite"])
+    least, bound = peaks.roofline_seconds(work, nbytes, peaks.PEAKS["TPU v5 lite"])
     assert bound == "compute" and least == pytest.approx(work / 197e12)
 
 
@@ -144,7 +147,7 @@ def test_pool_in_use_follows_the_request_records():
 def test_traced_work_counts_rows_and_tokens_inside_the_window():
     c = answer(0.0, [(1.0, 1), (2.0, 2), (9.0, 2)])
     c.sent = 0.0
-    work = serve_driver.traced_work(LARGE, [c], 0.5, 3.0)
+    work = serve_driver.traced_work(GPT2, LARGE, [c], 0.5, 3.0)
     # half of the 4-token prompt's forward, and output tokens 1 and 2 at
     # contexts 5 and 6
     assert work["prompt_tokens"] == pytest.approx(2.0)

@@ -7,7 +7,7 @@ import pytest
 
 from _tiny import BENCH_DIR, ROOT, bench, load_json
 from benchmarks.harness import traffic
-from benchmarks.harness.job import layer_reader
+from benchmarks.harness.job import Family, layer_reader, load_family
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -87,6 +87,72 @@ def test_every_cell_finds_its_files():
     assert len({c["file"] for c in b["configs"]}) == len(b["configs"])
     for m in b["per_layer"]:
         assert callable(layer_reader(ROOT, m["name"])), m["name"]
+
+
+def test_every_configuration_finds_its_family_and_every_piece_of_it():
+    import inspect
+
+    import jax
+
+    b = bench()
+    for c in b["configs"]:
+        cfg = load_json(c["file"])
+        family = load_family(ROOT, cfg["model_type"])
+        assert family is load_family(ROOT, cfg["model_type"])   # one a process
+        assert os.path.samefile(family.folder, os.path.join(
+            BENCH_DIR, "families", cfg["model_type"]))
+        for half, names in ((family.model, Family.MODEL),
+                            (family.counts, Family.COUNTS),
+                            (family.program, Family.PROGRAM)):
+            assert os.path.dirname(half.__file__) == family.folder
+            assert all(callable(getattr(half, n)) for n in names)
+        # only the program's half may import the program, and none the harness
+        for half in (family.model, family.counts):
+            source = inspect.getsource(half)
+            assert "paddle_tpu" not in source and "benchmarks" not in source
+        model = family.model
+        leaves = model.leaves(cfg)
+        kinds = model.layer_kinds(cfg)
+        pieces = [model.embed_leaves(cfg), model.head_leaves(cfg)] + [
+            model.layer_leaves(cfg, i) for i in range(len(kinds))]
+        named = {e if isinstance(e, str) else e[0]
+                 for piece in pieces for e in piece.values()}
+        assert named == set(leaves)         # every leaf is some piece's
+        for shape, mean, std in leaves.values():
+            assert all(isinstance(n, int) and n > 0 for n in shape) and std > 0
+        # every compared leaf is a leaf, and has a place in the program
+        for name, part, layer in model.leaf_names(cfg):
+            assert name in leaves and isinstance(
+                family.program.state_key(name, layer), str)
+        assert model.forward_bytes(cfg, 1024) > 0
+        # the three pieces fit together, by shapes alone
+        from benchmarks.harness import reference
+        tree = {k: jax.ShapeDtypeStruct(s, "float32")
+                for k, (s, _, _) in leaves.items()}
+        ids = jax.numpy.zeros((1, 8), "int32")
+        logits = jax.eval_shape(
+            lambda t: reference.logits_fn(family, cfg, t, ids), tree)
+        assert logits.shape == (1, 8, cfg["vocab_size"])
+        counts = family.counts
+        for work in (counts.prompt_work(cfg, 7), counts.token_work(cfg, 7)):
+            assert set(work) == {"model_flops", "attention_flops", "kv_rows"}
+            assert 0 < work["attention_flops"] < work["model_flops"]
+        assert counts.kv_bytes_per_row(cfg) > 0
+        assert counts.train_flops_per_token(cfg, 1024) > 0
+
+
+def test_a_family_that_lacks_a_piece_or_a_directory_is_refused(tmp_path):
+    import shutil
+
+    with pytest.raises(FileNotFoundError, match="no family 'mamba9'"):
+        Family(ROOT, "mamba9")
+    there = tmp_path / "benchmarks" / "families" / "half-a-family"
+    shutil.copytree(os.path.join(BENCH_DIR, "families", "gpt2"), there)
+    text = (there / "counts.py").read_text().replace("def token_work",
+                                                     "def token_work_")
+    (there / "counts.py").write_text(text)
+    with pytest.raises(AttributeError, match=r"lacks \['token_work'\]"):
+        Family(str(tmp_path), "half-a-family")
 
 
 def test_every_layer_metric_moves_a_metric_its_cells_report():

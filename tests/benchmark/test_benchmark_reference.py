@@ -18,6 +18,7 @@ TRAIN_CELL = {"name": "gpt2-medium-train-s1024", "config": "gpt2-medium",
               "traffic": "train-s1024", "chips": 1}
 SERVE_CELL = {"name": "gpt2-large-serve-chat", "config": "gpt2-large",
               "traffic": "serve-chat", "chips": 1}
+GPT2 = _tiny.family()
 
 
 # Gaps scale with the model: a 2-layer model of width 64 reads a larger loss
@@ -66,35 +67,35 @@ def test_reference_forward_agrees_with_the_program():
     import paddle_tpu as paddle
 
     cfg = _tiny.tiny_cfg(activation_function="gelu")    # the program's erf
-    model = program.build_model(cfg)
+    model = GPT2.program.build_model(cfg)
     model.eval()
-    program.load_weights(model, cfg, seed=3, serve=False)
+    program.load_weights(GPT2, model, cfg, seed=3, serve=False)
     ids = np.random.default_rng(0).integers(0, cfg["vocab_size"], (2, 48))
     got = np.asarray(model(paddle.to_tensor(ids))._value)
-    params = weights.make_weights(cfg, 3)
-    want = np.asarray(reference.logits_fn(cfg, params, ids))
+    params = weights.make_weights(GPT2, cfg, 3)
+    want = np.asarray(reference.logits_fn(GPT2, cfg, params, ids))
     assert np.max(np.abs(got - want)) < 2e-4
     # the source's tanh form differs from the program's erf form by less
     # than a bfloat16 step of the logits
     tanh = np.asarray(reference.logits_fn(
-        dict(cfg, activation_function="gelu_new"), params, ids))
+        GPT2, dict(cfg, activation_function="gelu_new"), params, ids))
     assert 0 < np.max(np.abs(tanh - want)) < 5e-3
 
 
 def test_weights_are_the_seeds_and_the_served_checkpoint_is_their_bf16_image():
     cfg = _tiny.tiny_cfg()
-    a = weights.make_weights(cfg, 2_147_483_659)
-    b = weights.make_weights(cfg, 2_147_483_659)
-    c = weights.make_weights(cfg, 2_147_483_660)
+    a = weights.make_weights(GPT2, cfg, 2_147_483_659)
+    b = weights.make_weights(GPT2, cfg, 2_147_483_659)
+    c = weights.make_weights(GPT2, cfg, 2_147_483_660)
     assert all(np.array_equal(a[k], b[k]) for k in a)
     assert not np.array_equal(a["wte"], c["wte"])
-    served = weights.make_weights(cfg, 5, round_to="bfloat16",
+    served = weights.make_weights(GPT2, cfg, 5, round_to="bfloat16",
                                   out_dtype="bfloat16")
-    image = weights.make_weights(cfg, 5, round_to="bfloat16")
+    image = weights.make_weights(GPT2, cfg, 5, round_to="bfloat16")
     assert str(served["qkv_w"].dtype) == "bfloat16"
     assert all(np.array_equal(np.asarray(served[k], np.float32), image[k])
                for k in image)
-    assert len(weights.leaf_names(cfg)) == 4 + cfg["n_layer"] * 16
+    assert len(GPT2.model.leaf_names(cfg)) == 4 + cfg["n_layer"] * 16
 
 
 # -------------------------------------------------------------------- train
@@ -131,8 +132,8 @@ def test_fp8_control_reads_above_the_program(train_job, sound_train):
 class StateUnchanged(program.Trainer):
     """A step that returns its state unchanged: the update moves nothing."""
 
-    def __init__(self, cfg, opt, seed):
-        super().__init__(cfg, dict(opt, learning_rate=0.0), seed)
+    def __init__(self, family, cfg, opt, seed):
+        super().__init__(family, cfg, dict(opt, learning_rate=0.0), seed)
         self.opt = opt
 
 
@@ -144,10 +145,17 @@ class HalfTheBatch(program.Trainer):
         return super().to_device(ids[:half], labels[:half])
 
 
-@pytest.mark.parametrize("broken,number", [
-    (StateUnchanged, "delta_norm_gap"), (HalfTheBatch, "grad_norm_gap")])
-def test_broken_train_step_is_not_correct(train_job, broken, number):
-    outcome = train_driver.run(train_job, make_trainer=broken)
+@pytest.fixture(scope="module", params=[
+    (StateUnchanged, "delta_norm_gap"), (HalfTheBatch, "grad_norm_gap")],
+    ids=lambda p: f"{p[0].__name__}-{p[1]}")
+def broken_train(request, train_job):
+    """(the fault, the number that has to catch it, the run's outcome)."""
+    broken, number = request.param
+    return broken, number, train_driver.run(train_job, make_trainer=broken)
+
+
+def test_broken_train_step_is_not_correct(train_job, broken_train):
+    broken, number, outcome = broken_train
     line = finish(train_job, outcome)
     assert line["correct"] is False
     got = line["compared"][number]
@@ -164,8 +172,13 @@ def serve_job():
                           seed=13, seconds=1.0)
 
 
-def test_sound_serve_run_is_correct_and_the_control_reads_above_it(serve_job):
-    outcome = serve_driver.run(serve_job)
+@pytest.fixture(scope="module")
+def sound_serve(serve_job):
+    return serve_driver.run(serve_job)
+
+
+def test_sound_serve_run_is_correct(serve_job, sound_serve):
+    outcome = sound_serve
     line = finish(serve_job, outcome)
     assert line["correct"] is True, line["compared"]
     sent = traffic.open_loop_requests(serve_job.mix, serve_job.cfg,
@@ -177,11 +190,15 @@ def test_sound_serve_run_is_correct_and_the_control_reads_above_it(serve_job):
         serve_job.bench, SERVE_CELL["name"], trace=False)) < set(
             outcome.metrics)    # the driver offers more than the cell reports
     assert json.loads(json.dumps(line)) == line
-    # the control in the program's place: at the same positions of the same
-    # prompts and tokens, the token that fp8 puts first lies far below
+
+
+def test_the_serve_control_reads_above_the_program(serve_job, sound_serve):
+    """The control in the program's place: at the same positions of the same
+    prompts and tokens, the token that fp8 puts first lies far below."""
+    outcome = sound_serve
     both = serve_driver.served_logit_gaps(
-        serve_job.cfg, serve_job.seed, outcome.records["answers"],
-        control=True)
+        GPT2, serve_job.cfg, serve_job.seed, outcome.records["answers"],
+        serve_job.mix["geometry"]["max_seq_len"], control=True)
     assert both["served_logit_gap"] == outcome.numbers["served_logit_gap"]
     assert both["control_logit_gap"] >= 3 * both["served_logit_gap"]
     assert not compare.judge(
@@ -210,11 +227,122 @@ class ShortAnswer(program.Server):
         return super().stream(prompt, max_new - 1, timeout)
 
 
-@pytest.mark.parametrize("broken,number", [
-    (AlteredToken, "served_logit_gap"), (ShortAnswer, "wrong_token_counts")])
-def test_broken_server_is_not_correct(serve_job, broken, number):
-    outcome = serve_driver.run(serve_job, make_server=broken)
+@pytest.fixture(scope="module", params=[
+    (AlteredToken, "served_logit_gap"), (ShortAnswer, "wrong_token_counts")],
+    ids=lambda p: f"{p[0].__name__}-{p[1]}")
+def broken_serve(request, serve_job):
+    broken, number = request.param
+    return number, serve_driver.run(serve_job, make_server=broken)
+
+
+def test_broken_server_is_not_correct(serve_job, broken_serve):
+    number, outcome = broken_serve
     line = finish(serve_job, outcome)
     assert line["correct"] is False
     got = line["compared"][number]
     assert got["value"] > got["limit"]
+
+
+# ------------------------------------------------- the serve check's routes
+def greedy_answers(cfg, seed, lengths):
+    """(prompt, tokens) as a sound server gives them: each token the
+    reference's own first choice, by the whole-image forward."""
+    import jax
+
+    width = cfg["n_positions"]
+    tree = weights.make_weights(GPT2, cfg, seed, round_to="bfloat16")
+    forward = jax.jit(lambda ids: reference.logits_fn(GPT2, cfg, tree, ids))
+    rng = np.random.default_rng(seed)
+    out = []
+    for plen, n in lengths:
+        ids = np.zeros((1, width), np.int64)
+        ids[0, :plen] = rng.integers(0, cfg["vocab_size"], plen)
+        for at in range(plen, plen + n):
+            ids[0, at] = int(np.argmax(np.asarray(forward(ids))[0, at - 1]))
+        out.append((ids[0, :plen].copy(), [int(t) for t in ids[0, plen:plen + n]]))
+    return out
+
+
+ROUTE_CFG = dict(n_layer=2, vocab_size=1031)
+ROUTE_SEED, ROUTE_WIDTH = 31, 128
+
+
+def route_gaps(answers, hbm_bytes, control):
+    return serve_driver.served_logit_gaps(
+        GPT2, _tiny.tiny_cfg("gpt2-large", **ROUTE_CFG), ROUTE_SEED, answers,
+        ROUTE_WIDTH, most_new=24, control=control, hbm_bytes=hbm_bytes)
+
+
+@pytest.fixture(scope="module")
+def whole_image():
+    """(answers, what the whole-image route reads on them, control beside)."""
+    cfg = _tiny.tiny_cfg("gpt2-large", **ROUTE_CFG)
+    answers = greedy_answers(cfg, ROUTE_SEED, [(20, 9), (7, 30), (100, 28)])
+    answers[1][1][4] = (answers[1][1][4] + 1) % cfg["vocab_size"]   # a gap
+    return answers, route_gaps(answers, None, True)
+
+
+def room_needed(cfg):
+    """The chip's memory at which the whole-image route, control beside the
+    reference, just fits."""
+    return (weights.image_bytes(GPT2, cfg) + 2 * GPT2.model.forward_bytes(
+        cfg, ROUTE_WIDTH)) / reference.ServeCheck.ROOM
+
+
+def test_the_serve_checks_route_is_reckoned_from_bytes(whole_image):
+    cfg = _tiny.tiny_cfg("gpt2-large", **ROUTE_CFG)
+
+    def fits(hbm_bytes, cfg=cfg, width=ROUTE_WIDTH):
+        return reference.ServeCheck(GPT2, cfg, ROUTE_SEED, width, control=True,
+                                    hbm_bytes=hbm_bytes).whole_image_fits()
+    need = room_needed(cfg)
+    assert fits(None) and fits(need + 1) and not fits(need - 1)
+    _, whole = whole_image
+    assert whole["served_logit_gap"] > 0.001 < whole["control_logit_gap"]
+    # both cells of BENCHMARK.json take the whole-image route on the v5e,
+    # with room to spare; a 10 GB bfloat16 checkpoint (a 20 GB image) cannot
+    from benchmarks.harness.peaks import PEAKS
+    hbm = PEAKS["TPU v5 lite"]["hbm_bytes"]
+    for name in ("gpt2-medium", "gpt2-large"):
+        real = _tiny.load_json("benchmarks", "configs", f"{name}.json")
+        assert fits(hbm, real, 1024) and fits(hbm / 2, real, 1024)
+    assert weights.image_bytes(GPT2, real) == 4 * 774_030_080
+    assert not fits(hbm, dict(real, n_layer=230), 1024)     # 4.9 G leaves
+
+
+# just too little room: the control beside the reference, the rows waiting
+# on the device between layers; far too little: the reference alone (its
+# head over blocks of compared rows only), the rows waiting on the host
+@pytest.mark.parametrize("room,control", [("just short", True),
+                                          ("far short", False)])
+def test_the_layer_at_a_time_check_agrees_with_the_whole_image(
+        monkeypatch, whole_image, room, control):
+    import gc
+    import weakref
+
+    cfg = _tiny.tiny_cfg("gpt2-large", **ROUTE_CFG)
+    answers, whole = whole_image
+    hbm_bytes = room_needed(cfg) - 1 if room == "just short" else 300_000
+    drawn, most_alive, real = [], [0], weights.make_weights
+
+    def counted(family, cfg, seed, *, only=None, **kw):
+        gc.collect()
+        alive = sum(r().nbytes for r in drawn if r() is not None)
+        out = real(family, cfg, seed, only=only, **kw)
+        assert only is not None, "the whole image was made"
+        drawn.extend(weakref.ref(v) for v in out.values())
+        most_alive[0] = max(most_alive[0],
+                            alive + sum(v.nbytes for v in out.values()))
+        return out
+    monkeypatch.setattr(weights, "make_weights", counted)
+    one_layer = 4 * sum(int(np.prod(GPT2.model.leaves(cfg)[k][0][1:]))
+                        for k in GPT2.model.BLOCK_KINDS)
+    ends = 4 * sum(int(np.prod(GPT2.model.leaves(cfg)[k][0]))
+                   for k in GPT2.model.TOP_KINDS)
+    streamed = route_gaps(answers, hbm_bytes, control)
+    assert len(drawn) == 2 + 12 * cfg["n_layer"] + 3
+    # never more than one piece's float32 leaves at a time
+    assert most_alive[0] <= max(one_layer, ends)
+    assert streamed == {k: pytest.approx(whole[k], abs=2e-6)
+                        for k in streamed}
+    assert ("control_logit_gap" in streamed) == control

@@ -5,8 +5,8 @@ import os
 import pytest
 
 from _tiny import BENCH_DIR, ROOT, load_json
-from benchmarks.harness import flops, peaks, trace
-from benchmarks.harness.job import View, layer_reader
+from benchmarks.harness import peaks, trace
+from benchmarks.harness.job import View, layer_reader, load_family
 
 RECORDED = os.path.join(BENCH_DIR, "testdata",
                         "train_two_steps.trace.json.gz")
@@ -75,7 +75,8 @@ def test_layer_metrics_on_the_recorded_trace_are_shares(reduced):
         ROOT, "device_idle.serve")(view)    # one reader for both paths
     assert all(v <= 100 for v in got.values())
     assert got["mfu.train"] == pytest.approx(
-        100 * 2 * 8192 * flops.train_flops_per_token(cfg, 1024)
+        100 * 2 * 8192 * load_family(ROOT, "gpt2").counts.train_flops_per_token(
+            cfg, 1024)
         / (reduced["window_s"] * 197e12))
 
 

@@ -67,7 +67,7 @@ def traced_serve(tmp_path_factory):
     utilization._CLOSED.clear()     # other tests' servers, long closed
     cfg, mix = _tiny.tiny_cfg("gpt2-large"), _tiny.tiny_serve_mix()
     geometry = dict(mix["geometry"], decode_kernel="xla")
-    server = program.Server(cfg, geometry, seed=26)
+    server = program.Server(_tiny.family(), cfg, geometry, seed=26)
     try:
         server.wait_ready(300)
         ask(server, 12, 5)                      # before the session
