@@ -449,7 +449,11 @@ def per_block_bytes(kv_signature, tp=1) -> int:
     plan/pool parity test pins this)."""
     import jax.numpy as jnp
 
-    layers, kv_heads, head_dim, block_size, _nb, dtype = kv_signature
+    from ..inference.kv_cache import CacheSpec
+
+    layers, kv_heads, head_dim, block_size, _nb, dtype = kv_signature[:6]
+    if isinstance(layers, CacheSpec):   # rows that are not K,V of like layers
+        return layers.block_bytes(block_size, jnp.dtype(dtype).itemsize)
     tp = max(1, int(tp))
     heads = int(kv_heads) / tp if int(kv_heads) % tp == 0 else int(kv_heads)
     return int(2 * int(layers) * heads * int(block_size) * int(head_dim)
@@ -509,6 +513,7 @@ class DeploymentPlan:
     programs: tuple = ()                 # ProgramEstimate per manifest entry
     temps_bytes: int = 0                 # declared floor when no programs
     adapter_bank_bytes: int = 0          # ISSUE-15: resident LoRA banks
+    window_pool_bytes: int = 0           # rings of layers that keep a window
     # ISSUE-20: the interconnect component (comms.CommsBudget or None).
     # DISJOINT from components() by construction: these are bytes MOVED
     # per tick, not bytes resident, so they never enter the residency sum
@@ -564,13 +569,16 @@ class DeploymentPlan:
         return int(self.adapter_bank_bytes)
 
     def components(self) -> dict:
-        return {
+        out = {
             "params": self.params_component,
             "kv_pool": self.kv_pool_component,
             "prefix_tier": self.prefix_tier_component,
             "temps": self.temps_component,
             "adapter_bank": self.adapter_bank_component,
         }
+        if self.window_pool_bytes:      # a second pool, where a model has one
+            out["window_pool"] = int(self.window_pool_bytes)
+        return out
 
     @property
     def planned_total_bytes(self) -> int:
@@ -588,6 +596,7 @@ class DeploymentPlan:
             "programs": [p.to_json() for p in self.programs],
             "temps_bytes": int(self.temps_bytes),
             "adapter_bank_bytes": int(self.adapter_bank_bytes),
+            "window_pool_bytes": int(self.window_pool_bytes),
             "comms": self.comms.to_json() if self.comms else None,
             "components": self.components(),
             "planned_total_bytes": self.planned_total_bytes,
@@ -786,7 +795,8 @@ def params_bytes_of(model) -> int:
     return total
 
 
-def plan_kv_pool(budget_bytes, *, num_layers, num_kv_heads, head_dim,
+def plan_kv_pool(budget_bytes, *, num_layers=None, num_kv_heads=None,
+                 head_dim=None, cache_spec=None,
                  block_size, dtype="bfloat16", slots=8, max_seq_len=None,
                  params_bytes=0, tp=1, headroom=DEFAULT_HEADROOM,
                  prefix_blocks=0, temps_bytes=0, adapter_bank_bytes=0,
@@ -802,15 +812,26 @@ def plan_kv_pool(budget_bytes, *, num_layers, num_kv_heads, head_dim,
     what the admissible requests can reach: slots x
     blocks_for(max_seq_len) + parked prefix blocks) — the second clamp is
     what keeps a generous budget from buying unreachable blocks
-    (pool-misfit's waste arm)."""
+    (pool-misfit's waste arm). `cache_spec` (inference.kv_cache.CacheSpec)
+    takes the place of the triple: layers that keep every row are counted
+    a block, and the rings of layers that keep a window (all slots, sized
+    by the largest launch) are a fixed second pool, `window_pool_bytes`."""
+    import jax.numpy as jnp
+
+    from ..inference.kv_cache import CacheSpec
     from .compilesurface import ServingConfig
 
+    if cache_spec is None:
+        cache_spec = CacheSpec.uniform(num_layers, num_kv_heads, head_dim)
+    head = cache_spec.signature_head()
+    window_bytes = cache_spec.window_bytes(
+        block_size, jnp.dtype(dtype).itemsize, slots,
+        max(int(prefill_chunk), int(spec_k) + 1))
     budget_bytes = int(budget_bytes)
     usable = int(budget_bytes * (1.0 - headroom))
     fixed = (int(params_bytes) // max(1, int(tp)) + int(temps_bytes)
-             + int(adapter_bank_bytes))
-    sig = (int(num_layers), int(num_kv_heads), int(head_dim),
-           int(block_size), 0, str(dtype))
+             + int(adapter_bank_bytes) + window_bytes)
+    sig = head + (int(block_size), 0, str(dtype))
     pbb = per_block_bytes(sig, tp=tp)
     fit = (usable - fixed) // pbb
     target = None
@@ -829,14 +850,14 @@ def plan_kv_pool(budget_bytes, *, num_layers, num_kv_heads, head_dim,
         name=name, slots=int(slots), prefill_chunk=int(prefill_chunk),
         decode_steps=int(decode_steps), spec_k=int(spec_k),
         eos_token_id=eos_token_id, max_seq_len=max_seq_len,
-        kv_signature=(int(num_layers), int(num_kv_heads), int(head_dim),
-                      int(block_size), num_blocks, str(dtype)),
+        kv_signature=head + (int(block_size), num_blocks, str(dtype)),
         decode_kernel=decode_kernel)
     plan = DeploymentPlan(
         config=config, budget_bytes=budget_bytes, headroom=headroom,
         params_bytes=int(params_bytes), tp=int(tp),
         prefix_blocks=int(prefix_blocks), temps_bytes=int(temps_bytes),
-        adapter_bank_bytes=int(adapter_bank_bytes))
+        adapter_bank_bytes=int(adapter_bank_bytes),
+        window_pool_bytes=window_bytes)
     return {"num_blocks": num_blocks, "fit_blocks": int(fit),
             "target_blocks": target, "per_block_bytes": pbb, "plan": plan}
 
@@ -937,10 +958,10 @@ def smoke_plan(*, budget_bytes=None, with_measured=True, config_name=None):
         base = match[0]
     config = _dc.replace(base, name="hbm-smoke",
                          max_seq_len=SMOKE_MAX_SEQ_LEN)
-    layers, kv_heads, head_dim, block_size, num_blocks, dtype = \
-        config.kv_signature
-    kv = PagedKVCache(layers, kv_heads, head_dim, block_size=block_size,
-                      num_blocks=num_blocks, dtype=dtype)
+    _, _, _, block_size, num_blocks, dtype = config.kv_signature
+    kv = PagedKVCache.for_model(model, block_size=block_size,
+                                num_blocks=num_blocks, dtype=dtype,
+                                slots=config.slots)
     programs = []
     for path in config.active_paths():
         closed, measured = _trace_step_program(model, kv, config, path)

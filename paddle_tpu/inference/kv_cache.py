@@ -16,6 +16,7 @@ finished-but-retained requests when the pool runs dry.
 """
 from __future__ import annotations
 
+import dataclasses
 import itertools
 import math
 
@@ -23,7 +24,85 @@ import numpy as np
 
 from ..analysis.lockwitness import make_rlock
 
-__all__ = ["CacheOutOfBlocks", "BlockAllocator", "PagedKVCache"]
+__all__ = ["CacheOutOfBlocks", "BlockAllocator", "PagedKVCache",
+           "LayerCache", "CacheSpec"]
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerCache:
+    """What one layer keeps of a token, and for how long.
+
+    kind "kv": a K and a V row of [heads, head_dim] each, in pages a request
+    reaches through its block table (GPT, LLaMA). kind "latent": ONE row of
+    `row` numbers shared by all heads (latent attention: the compressed
+    key/value beside the rotary key) and, where `index_row` > 0, the
+    indexer's key of that many numbers in a second array of the same pages.
+    `window` None keeps every row, in pages; a number keeps a slot's last
+    `window` rows and what one launch writes, in a ring of its own per slot
+    that needs no table: position t lives in ring row t mod the ring."""
+    kind: str = "kv"
+    heads: int = 0
+    head_dim: int = 0
+    row: int = 0
+    index_row: int = 0
+    window: int | None = None
+
+    def row_numbers(self) -> int:
+        """Numbers a token leaves in this layer."""
+        if self.kind == "kv":
+            return 2 * self.heads * self.head_dim
+        return self.row + self.index_row
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheSpec:
+    """A model's decode cache: one `LayerCache` a layer. The ONE object the
+    pool is built from (`PagedKVCache.for_model`) and the residency plan
+    counts (`analysis/hbm.py`). A model whose layers are all of kind "kv"
+    and alike unpacks as the old triple: `layers, kv_heads, head_dim = spec`."""
+    layers: tuple
+
+    @classmethod
+    def uniform(cls, num_layers, num_kv_heads, head_dim):
+        one = LayerCache("kv", int(num_kv_heads), int(head_dim))
+        return cls((one,) * int(num_layers))
+
+    def is_uniform_kv(self) -> bool:
+        first = self.layers[0]
+        return first.kind == "kv" and first.window is None and all(
+            c == first for c in self.layers)
+
+    def kv_triple(self):
+        if not self.is_uniform_kv():
+            raise TypeError("this cache is not (layers, kv_heads, head_dim): "
+                            f"{sorted({c.kind for c in self.layers})} rows")
+        first = self.layers[0]
+        return len(self.layers), first.heads, first.head_dim
+
+    def __iter__(self):
+        return iter(self.kv_triple())
+
+    def signature_head(self):
+        """The model's part of a pool signature: the old (layers, kv_heads,
+        head_dim) where that says it all, else (spec, 0, 0)."""
+        return self.kv_triple() if self.is_uniform_kv() else (self, 0, 0)
+
+    def ring_rows(self, cache, block_size, launch_rows) -> int:
+        """Rows of a window layer's ring: the window, one launch's rows and
+        a page to spare, in whole pages."""
+        pages = -(-(cache.window + int(launch_rows)) // block_size) + 1
+        return pages * int(block_size)
+
+    def block_bytes(self, block_size, itemsize) -> int:
+        """Bytes one page costs over the layers that keep every row."""
+        return int(block_size) * int(itemsize) * sum(
+            c.row_numbers() for c in self.layers if c.window is None)
+
+    def window_bytes(self, block_size, itemsize, slots, launch_rows) -> int:
+        """Bytes of the window layers' rings, all slots."""
+        return int(slots) * int(itemsize) * sum(
+            c.row_numbers() * self.ring_rows(c, block_size, launch_rows)
+            for c in self.layers if c.window is not None)
 
 
 class CacheOutOfBlocks(RuntimeError):
@@ -92,24 +171,28 @@ class PagedKVCache:
     parameters. Everything else (tables, lengths, eviction) is host state.
     """
 
-    def __init__(self, num_layers, num_kv_heads, head_dim, block_size=128,
-                 num_blocks=64, dtype="bfloat16", faults=None, mesh=None):
+    def __init__(self, num_layers=None, num_kv_heads=None, head_dim=None,
+                 block_size=128, num_blocks=64, dtype="bfloat16", faults=None,
+                 mesh=None, spec=None, slots=None, launch_rows=1):
         import jax.numpy as jnp
 
-        self.num_layers = int(num_layers)
-        self.num_kv_heads = int(num_kv_heads)
-        self.head_dim = int(head_dim)
+        if spec is None:
+            spec = CacheSpec.uniform(num_layers, num_kv_heads, head_dim)
+        self.spec = spec
+        self.num_layers = len(spec.layers)
+        # the old triple where the layers are alike K,V rows, else 0: the
+        # debug `gather` and the tp head-sharding read them
+        _, self.num_kv_heads, self.head_dim = spec.signature_head()
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.dtype = jnp.dtype(dtype)
-        # head-leading [Hkv, P, BS, D]: the paged kernel resolves the head
-        # axis in its index_map, so pages stream as contiguous [BS, D] tiles
-        shape = (self.num_kv_heads, self.num_blocks, self.block_size,
-                 self.head_dim)
-        self.k_pages = [jnp.zeros(shape, self.dtype)
-                        for _ in range(self.num_layers)]
-        self.v_pages = [jnp.zeros(shape, self.dtype)
-                        for _ in range(self.num_layers)]
+        self.slots = None if slots is None else int(slots)
+        self.launch_rows = int(launch_rows)
+        self.k_pages, self.v_pages = [], []
+        for cache in spec.layers:
+            k, v = self._layer_arrays(cache)
+            self.k_pages.append(k)
+            self.v_pages.append(v)
         # ("dp","tp") serving mesh: head-shard the pools over tp so each chip
         # resident-holds 1/tp of the KV bytes; step programs keep the layout
         # (commit() stores jit outputs whose shardings propagate from these)
@@ -118,7 +201,8 @@ class PagedKVCache:
             from ..distributed.mesh import get_mesh
             mesh = get_mesh()
         jm = getattr(mesh, "jax_mesh", mesh)  # ProcessMesh | jax Mesh | None
-        if jm is not None and "tp" in getattr(jm, "axis_names", ()):
+        if (jm is not None and "tp" in getattr(jm, "axis_names", ())
+                and spec.is_uniform_kv()):
             from ..distributed.mesh import SpecLayout, mesh_axis_size
             tp = mesh_axis_size("tp", jm)
             if tp > 1 and self.num_kv_heads % tp == 0:
@@ -145,24 +229,63 @@ class PagedKVCache:
         # (gather); RLock because reserve -> _evict_lru -> release re-enters
         self._lock = make_rlock("kv_cache.PagedKVCache._lock")
 
+    @classmethod
+    def for_model(cls, model, **kwargs):
+        """The pool of `model._decode_cache_spec()`: the one place that
+        spec is read for a pool."""
+        return cls(spec=as_cache_spec(model._decode_cache_spec()), **kwargs)
+
+    def _layer_arrays(self, cache):
+        """(first, second) array of one layer: K and V pages head-leading
+        [Hkv, P, BS, D] (the paged kernel resolves the head axis in its
+        index_map, so pages stream as contiguous [BS, D] tiles); latent rows
+        [P, BS, row] beside the indexer's keys [P, BS, index_row] or None;
+        a window layer's ring [slots, ring rows, row], no second array."""
+        import jax.numpy as jnp
+
+        if cache.kind == "kv":
+            shape = (cache.heads, self.num_blocks, self.block_size,
+                     cache.head_dim)
+            return jnp.zeros(shape, self.dtype), jnp.zeros(shape, self.dtype)
+        if cache.window is not None:
+            if self.slots is None:
+                raise ValueError("a window layer's ring is sized by the "
+                                 "slots: pass slots=")
+            ring = self.spec.ring_rows(cache, self.block_size,
+                                       self.launch_rows)
+            return jnp.zeros((self.slots, ring, cache.row), self.dtype), None
+        pages = (self.num_blocks, self.block_size)
+        index = (jnp.zeros(pages + (cache.index_row,), self.dtype)
+                 if cache.index_row else None)
+        return jnp.zeros(pages + (cache.row,), self.dtype), index
+
+    def _arrays(self):
+        return [p for p in self.k_pages + self.v_pages if p is not None]
+
     # ------------------------------------------------------------- identity
     def signature(self):
-        """Hashable shape identity for compiled-runner cache keys."""
-        return (self.num_layers, self.num_kv_heads, self.head_dim,
-                self.block_size, self.num_blocks, str(self.dtype))
+        """Hashable shape identity for compiled-runner cache keys: (layers,
+        kv_heads, head_dim, block_size, num_blocks, dtype) for K,V layers
+        that are alike; any other spec stands in the first place itself,
+        with the slots and launch rows its rings were sized by behind."""
+        head = self.spec.signature_head()
+        sig = head + (self.block_size, self.num_blocks, str(self.dtype))
+        if head[0] is self.spec:
+            sig += (self.slots, self.launch_rows)
+        return sig
 
     def blocks_for(self, seq_len: int) -> int:
         return max(1, math.ceil(seq_len / self.block_size))
 
     def pool_bytes(self) -> int:
         """Logical pool bytes (K + V across all layers), sharding-independent."""
-        return sum(int(p.nbytes) for p in self.k_pages + self.v_pages)
+        return sum(int(p.nbytes) for p in self._arrays())
 
     def per_chip_pool_bytes(self) -> int:
         """Resident KV bytes on one chip: pool_bytes()/tp under tp
         head-sharding, pool_bytes() unsharded (the ISSUE-12 residency gate)."""
         total = 0
-        for p in self.k_pages + self.v_pages:
+        for p in self._arrays():
             shards = getattr(p, "addressable_shards", None)
             total += int(shards[0].data.nbytes) if shards else int(p.nbytes)
         return total
@@ -510,6 +633,9 @@ class PagedKVCache:
         end so a mid-gather commit() cannot mix pool generations."""
         with self._lock:
             req = self._requests[request_id]
+            if not self.spec.is_uniform_kv():
+                raise TypeError("gather reads K,V pages; this pool holds "
+                                "other rows")
             n = self.blocks_for(max(req.length, 1))
             tbl = np.asarray(req.blocks[:n])
 
@@ -520,3 +646,11 @@ class PagedKVCache:
                 return arr.swapaxes(0, 1)[:req.length]
 
             return _dense(self.k_pages[layer]), _dense(self.v_pages[layer])
+
+
+def as_cache_spec(spec) -> CacheSpec:
+    """A model's `_decode_cache_spec()` as a CacheSpec: the object itself,
+    or the old (layers, kv_heads, head_dim) triple."""
+    if isinstance(spec, CacheSpec):
+        return spec
+    return CacheSpec.uniform(*(int(x) for x in spec))

@@ -196,9 +196,10 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
     max_slots            decode width S: concurrent in-flight sequences.
     prefill_chunk        static chunk width C — one slot's prefill quantum.
     prefill_token_budget max prompt tokens spent per tick across all slots
-                         (default 2*C). Lower bounds decode latency under
-                         long-prompt pressure; higher finishes prompts
-                         sooner.
+                         (default 2*C), oldest first; a slot's chunk is
+                         never cut to what is left of it. Lower bounds
+                         decode latency under long-prompt pressure; higher
+                         finishes prompts sooner.
     decode_steps         tokens each decoding slot advances per tick (one
                          compiled scan). Higher amortizes dispatch; lower
                          tightens admit/retire granularity.
@@ -478,10 +479,12 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                 and "num_blocks" not in kwargs):
             from ..analysis.hbm import params_bytes_of, plan_kv_pool
 
-            layers, kv_h, hd = (int(x) for x in model._decode_cache_spec())
+            from .kv_cache import as_cache_spec
+
             sizing = plan_kv_pool(
-                self.hbm_budget, num_layers=layers, num_kv_heads=kv_h,
-                head_dim=hd, block_size=kwargs.get("block_size", 32),
+                self.hbm_budget,
+                cache_spec=as_cache_spec(model._decode_cache_spec()),
+                block_size=kwargs.get("block_size", 32),
                 slots=self.max_slots, max_seq_len=max_seq_len,
                 params_bytes=params_bytes_of(model),
                 name=self._component, prefill_chunk=self.prefill_chunk,
@@ -491,6 +494,8 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                                     else adapters.bank_bytes()))
             kwargs["num_blocks"] = sizing["num_blocks"]
             self._hbm_plan = sizing["plan"]
+        kwargs.setdefault("launch_rows",
+                          max(self.prefill_chunk, self.spec_k + 1))
         super().__init__(model, max_batch_size=max_slots,
                          max_defers=max_defers, **kwargs)
         pool_tokens = self.kv_cache.num_blocks * self.kv_cache.block_size
@@ -499,6 +504,13 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             raise ValueError(f"max_seq_len {self.max_seq_len} exceeds the "
                              f"pool ({pool_tokens} tokens)")
         self.table_width = self.kv_cache.blocks_for(self.max_seq_len)
+        if any(c.window is not None for c in self.kv_cache.spec.layers) and (
+                prefix_cache or qos is not None):
+            # a window layer's ring belongs to a slot and holds only the
+            # last rows: a shared prefix has none there, and a sequence
+            # paused out of its slot loses them
+            raise ValueError("a model whose layers keep a window cannot be "
+                             "served with prefix_cache or qos preemption")
         (self._spec_counter, self._lora_requests_counter,
          self._ttft_hist, self._tpot_hist) = self._bind_scheduler_metrics()
         if prefix_cache:
@@ -1532,8 +1544,38 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                    for a in arrays]
             return out, clock() - t0
 
+    def _model_stats(self):
+        """The counts the model returned beside the launch's tokens, still
+        on the device ({} for a model that counts nothing): they are read
+        back in the tokens' wait, not in one of their own."""
+        return dict((self._last_launch or {}).get("stats") or {})
+
+    def _model_counts(self, program, stats, positions, steps=1, holding=0):
+        """The launch's counts under the ledger's `MODEL_KEYS`: the expert
+        layer's from the device (`stats`, read back with the tokens), the
+        attention's rows by the model's own arithmetic from the positions
+        of the launch's real queries and the `holding` slots that hold a
+        chunk; and `issued_positions`, where the model says how many
+        positions its program carried (a model that walks only the slots
+        with a chunk issues fewer than slots x chunk)."""
+        counts = {}
+        if "moe_expert_tokens" in stats:
+            per = np.asarray(stats["moe_expert_tokens"]).sum(axis=0)
+            counts.update(
+                moe_expert_tokens=[int(n) for n in per],
+                moe_rows_useful=int(per.sum()),
+                moe_rows_issued=int(np.sum(stats["moe_rows_issued"])),
+                moe_assignments_elsewhere=int(np.sum(stats["moe_elsewhere"])))
+        rows = getattr(self.model, "_decode_row_counts", None)
+        if rows is not None:
+            counts.update(rows(program, np.asarray(positions, np.int64),
+                               self.kv_cache, self.table_width, steps,
+                               holding))
+        return counts
+
     def _util_launch(self, program, wait_s, total_units, slot_units,
-                     spec_units=0, live_rows=0, walked_rows=0, table_rows=0):
+                     spec_units=0, live_rows=0, walked_rows=0, table_rows=0,
+                     counts=None):
         """Account for the tick's launch, read back and absorbed: observe
         `paddle_decode_launch_seconds` with the launch THROUGH its
         read-back, and hand the ledger its time split, positions and rows.
@@ -1547,7 +1589,7 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             self._ledger.record_launch(
                 program, info.get("flops"), launch_s, total_units,
                 slot_units, spec_units, wait_s=wait_s, live_rows=live_rows,
-                walked_rows=walked_rows, table_rows=table_rows)
+                walked_rows=walked_rows, table_rows=table_rows, counts=counts)
         except ThreadDeath:
             raise
         except Exception:       # pragma: no cover - telemetry must not bite
@@ -1755,11 +1797,13 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
         budget = self.prefill_token_budget
         picks = []
         for i, s in pre:
-            if budget < 1:
-                break
-            take = min(self.prefill_chunk, s.plen - s.pos, budget)
+            take = min(self.prefill_chunk, s.plen - s.pos)
             if take < 1:
                 continue
+            if take > budget:
+                # a chunk is not cut to what the budget leaves (a prompt's
+                # tail left part of one over): the slot waits a tick
+                break
             picks.append((i, s, take))
             budget -= take
         if not picks:
@@ -1804,12 +1848,18 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             return
         self.breaker.record_success()
         self.metrics.inc("prefill_ticks")
-        (tk,), wait_s = self._read_back("prefill", tk)
+        stats = self._model_stats()
+        (tk, *got), wait_s = self._read_back("prefill", tk, *stats.values())
+        counts = self._model_counts(
+            "prefill_chunk", dict(zip(stats, got)),
+            np.concatenate([np.arange(s.pos, s.pos + take)
+                            for _, s, take in picks]), holding=len(picks))
+        issued = counts.pop("issued_positions", S * C)
         useful = int(sum(t for _, _, t in picks))
         self._span_each(reqs, "prefill_chunk", t0, self.tracer.now_us(),
                         slots=len(picks), tokens=useful)
         with RecordEvent("serve.prefill.absorb", useful=useful,
-                         issued=S * C):
+                         issued=issued):
             for i, s, take in picks:
                 s.pos += take
                 s.length = s.pos
@@ -1822,10 +1872,11 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                     s.phase = _DECODE
                     s.tok = int(tk[i])
                     self._absorb(i, s, [s.tok])
-        # useful positions are exactly each pick's take; the S*C - sum(take)
-        # remainder (idle slots, chunk tail) is pad
-        self._util_launch("prefill_chunk", wait_s, S * C,
-                          [(s.tenant, take) for _, s, take in picks])
+        # useful positions are exactly each pick's take; the rest of what
+        # the program issued (idle slots, chunk tail) is pad
+        self._util_launch("prefill_chunk", wait_s, issued,
+                          [(s.tenant, take) for _, s, take in picks],
+                          counts=counts)
 
     def _register_prefix(self, s, tokens, committed, digests="prompt"):
         """Index this sequence's freshly COMMITTED full blocks (prefill
@@ -1894,7 +1945,13 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             return
         self.breaker.record_success()
         self.metrics.inc("decode_ticks")
-        (toks,), wait_s = self._read_back("decode", toks)
+        stats = self._model_stats()
+        (toks, *got), wait_s = self._read_back("decode", toks,
+                                               *stats.values())
+        counts = self._model_counts(
+            "decode_step", dict(zip(stats, got)),
+            (lengths[active][:, None] + np.arange(T)).reshape(-1), steps=T)
+        counts.pop("issued_positions", None)    # a tick carries every slot
         self._span_each(reqs, "decode_step", t0, self.tracer.now_us(),
                         slots=len(dec), steps=T)
         live_rows, walked_rows, table_rows = self._kv_rows(lengths[active], T)
@@ -1913,7 +1970,7 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                              rows=live_rows)
         self._util_launch("decode_step", wait_s, S * T, units,
                           live_rows=live_rows, walked_rows=walked_rows,
-                          table_rows=table_rows)
+                          table_rows=table_rows, counts=counts)
 
     def _kv_rows(self, lengths, steps, one_call=False):
         """(live_rows, walked_rows, table_rows) of one decode or verify

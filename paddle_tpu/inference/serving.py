@@ -606,15 +606,16 @@ class GenerateBatchingPredictor(BatchingPredictor):
                  max_new_tokens=32, kv_cache=None, decode_kernel="pallas",
                  block_size=32, num_blocks=64, faults=None, admission=None,
                  breaker=None, max_retries=1, max_defers=8, max_restarts=5,
-                 tracer=None, registry=None, component=None):
-        spec = tuple(int(x) for x in model._decode_cache_spec())
-        if kv_cache is None:
-            from .kv_cache import PagedKVCache
+                 tracer=None, registry=None, component=None, launch_rows=1):
+        from .kv_cache import PagedKVCache, as_cache_spec
 
-            num_layers, kv_h, hd = spec
-            kv_cache = PagedKVCache(num_layers, kv_h, hd,
-                                    block_size=block_size,
-                                    num_blocks=num_blocks, faults=faults)
+        spec = as_cache_spec(model._decode_cache_spec())
+        if kv_cache is None:
+            # `launch_rows`: the most rows one launch writes for a slot,
+            # which sizes the ring of a layer that keeps only a window
+            kv_cache = PagedKVCache.for_model(
+                model, block_size=block_size, num_blocks=num_blocks,
+                faults=faults, slots=max_batch_size, launch_rows=launch_rows)
         self.model = model
         self.kv_cache = kv_cache
         self.max_new_tokens = int(max_new_tokens)
@@ -622,7 +623,7 @@ class GenerateBatchingPredictor(BatchingPredictor):
         self.decode_kernel = decode_kernel
         # paged decode launches against a mismatched pool would scatter into
         # wrong shapes; degrade to per-request dense generation instead
-        self.fallback_dense = tuple(kv_cache.signature()[:3]) != spec
+        self.fallback_dense = kv_cache.spec != spec
         # itertools.count: request-id draws are atomic (next() is a single
         # C-level op), so the batcher thread and any future helper threads
         # can draw ids without a lock (thread-lint unguarded-write fix)

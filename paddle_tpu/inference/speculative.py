@@ -260,12 +260,11 @@ def speculative_generate(model, input_ids, max_new_tokens=32, spec_k=4,
     total = plen + max_new
     own_pool = kv_cache is None
     if own_pool:
-        spec_l, spec_h, spec_d = model._decode_cache_spec()
         bs = 32
-        kv_cache = PagedKVCache(
-            spec_l, spec_h, spec_d, block_size=bs,
-            num_blocks=(total + bs - 1) // bs + 1,
-            dtype="float32" if dtype is None else dtype)
+        kv_cache = PagedKVCache.for_model(
+            model, block_size=bs, num_blocks=(total + bs - 1) // bs + 1,
+            dtype="float32" if dtype is None else dtype, slots=1,
+            launch_rows=max(plen, int(spec_k) + 1))
     rid = ("spec", next(_RID))
     kv_cache.reserve(rid, total)
     nb = kv_cache.blocks_for(total)

@@ -10,3 +10,6 @@ from .bert import (  # noqa: F401
     BertConfig, BertForMaskedLM, BertModel, bert_base, bert_mlm_mask,
     bert_tiny, masked_lm_loss,
 )
+from .dots3 import (  # noqa: F401
+    Dots3Config, Dots3ForCausalLM, Dots3Model, dots3_tiny,
+)

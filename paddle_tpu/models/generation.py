@@ -24,7 +24,11 @@ Models plug in via three hooks:
                           (ids, caches=, cache_offset=, decode_kernel=,
                           paged_tables=, cache_valid=) and returns
                           (logits, new_caches)
-  _decode_cache_spec() -> (num_layers, num_kv_heads, head_dim)
+  _decode_cache_spec() -> inference.kv_cache.CacheSpec: what each layer
+                          keeps of a token (K,V rows of [kv_heads, head_dim],
+                          or a latent row) and for how long (all rows, or a
+                          window); `CacheSpec.uniform(layers, kv_heads,
+                          head_dim)` for layers of like K,V rows
   _decode_validate(prompt_len, max_new_tokens) -> None (raise on invalid)
 """
 from __future__ import annotations
@@ -119,22 +123,33 @@ class GenerationMixin:
 
     # ------------------------------------------------------------- internals
     def _decode_call(self, raw_state, tok_ids, caches, offset, decode_kernel,
-                     paged_tables=None, cache_valid=None):
-        """One functional model call over raw jax values -> (logits, caches)."""
+                     paged_tables=None, cache_valid=None, logits_at=None,
+                     stats_out=None):
+        """One functional model call over raw jax values -> (logits, caches).
+        A layer's cache is a pair of arrays, the second None where the layer
+        keeps one (`inference.kv_cache.LayerCache`). `logits_at` [B] is
+        handed on only to a model that says it takes it
+        (`_decode_logits_at`): its logits are then [B, 1, V], of that one
+        position. A model may return counts of its own beside the caches (a
+        dict of small arrays); they are appended to `stats_out`."""
         kwargs = dict(cache_offset=offset, decode_kernel=decode_kernel)
         if paged_tables is not None:
             kwargs.update(paged_tables=paged_tables, cache_valid=cache_valid)
+        if logits_at is not None:
+            kwargs["logits_at"] = logits_at
+
+        def wrap(a):
+            return None if a is None else Tensor(a)
+
+        def raw(a):
+            return a._value if isinstance(a, Tensor) else a
         out = self._decode_layer().functional_call(
             raw_state, Tensor(tok_ids),
-            caches=[(Tensor(k), Tensor(v)) for k, v in caches], **kwargs)
-        logits, new_caches = out
-        lg = logits._value if isinstance(logits, Tensor) else logits
-        nc = [
-            (kc._value if isinstance(kc, Tensor) else kc,
-             vc._value if isinstance(vc, Tensor) else vc)
-            for kc, vc in new_caches
-        ]
-        return lg, nc
+            caches=[(wrap(k), wrap(v)) for k, v in caches], **kwargs)
+        logits, new_caches = out[:2]
+        if stats_out is not None and len(out) > 2:
+            stats_out.append(out[2])
+        return raw(logits), [(raw(kc), raw(vc)) for kc, vc in new_caches]
 
     @staticmethod
     def _make_sampler(greedy, temperature, top_k, eos, ids_dtype):
@@ -218,7 +233,7 @@ class GenerationMixin:
 
     @staticmethod
     def _emit_timing(timing_hook, path, B, P, new_tokens, compiled, t0,
-                     flops=None):
+                     flops=None, stats=None):
         """Decode timing hook (observability layer): called once per launch,
         when the call that enqueued the program has RETURNED. ``dispatch_s``
         is that call alone: the device arrays made and the program handed to
@@ -227,12 +242,17 @@ class GenerationMixin:
         does: ``launch = dispatch + wait``). The same interval is the
         ``generate.<path>`` RecordEvent. ``flops`` (ISSUE-19) is the
         program's issued FLOPs per launch — present only when the hook
-        asked for it (``wants_flops``), None otherwise."""
+        asked for it (``wants_flops``), None otherwise. ``stats`` are the
+        model's own counts of the launch, still on the device: whoever reads
+        the tokens back reads them in the same wait."""
         if timing_hook is None:
             return
-        timing_hook({"path": path, "batch": int(B), "prompt_len": int(P),
-                     "new_tokens": int(new_tokens), "compiled": bool(compiled),
-                     "dispatch_s": time.perf_counter() - t0, "flops": flops})
+        info = {"path": path, "batch": int(B), "prompt_len": int(P),
+                "new_tokens": int(new_tokens), "compiled": bool(compiled),
+                "dispatch_s": time.perf_counter() - t0, "flops": flops}
+        if stats:       # only a model that counts something adds the key
+            info["stats"] = stats
+        timing_hook(info)
 
     def _flops_of(self, cache_key, run, args):
         """Issued FLOPs of one execution of the step program behind
@@ -315,7 +335,11 @@ class GenerationMixin:
                else jnp.asarray(input_ids))
         B, P = ids.shape
         self._decode_validate(P, max_new_tokens)
-        num_layers, kv_h, hd = self._decode_cache_spec()
+        from ..inference.kv_cache import as_cache_spec
+
+        # dense caches are K,V rows of like layers: any other spec says so
+        num_layers, kv_h, hd = as_cache_spec(
+            self._decode_cache_spec()).kv_triple()
         new_tokens = int(max_new_tokens)
         # the COMPILED scan width is the declared bucket, not the raw
         # per-request budget (compile-surface `unbounded-key`): mixed-budget
@@ -607,6 +631,9 @@ class GenerationMixin:
         # the compile key carries the bank SHAPE only — adapter index and
         # bank values stay traced, so churn never lands here
         bank_sig = None if adapters is None else adapters.signature()
+        # a model whose head can run over one position a row is asked for
+        # the chunk's last only: the others' logits are never sampled
+        head_at_last = bool(getattr(self, "_decode_logits_at", False))
 
         def make_run():
             donate = (7, 8) if self._pool_donation() else ()
@@ -618,17 +645,18 @@ class GenerationMixin:
                 caches = list(zip(k_pages, v_pages))
                 valid = (jnp.arange(C, dtype=jnp.int32)[None, :]
                          < lens[:, None])
+                last_at = jnp.maximum(lens - 1, 0)
+                stats = []
                 logits, caches = self._decode_call(
                     raw_state, chunk, caches, offs, decode_kernel,
-                    paged_tables=tables, cache_valid=valid)
-                last = jnp.take_along_axis(
-                    logits,
-                    jnp.maximum(lens - 1, 0)[:, None, None].astype(jnp.int32),
-                    axis=1)[:, 0]
+                    paged_tables=tables, cache_valid=valid, stats_out=stats,
+                    logits_at=last_at if head_at_last else None)
+                last = (logits[:, 0] if head_at_last else jnp.take_along_axis(
+                    logits, last_at[:, None, None], axis=1)[:, 0])
                 tok, _, _ = sample(last, key, jnp.zeros((S,), bool),
                                    stemps, stks)
                 return (tok, [kc for kc, _ in caches],
-                        [vc for _, vc in caches])
+                        [vc for _, vc in caches], (stats or [{}])[0])
 
             if bank_sig is None:
                 return jax.jit(step, donate_argnums=donate)
@@ -663,10 +691,10 @@ class GenerationMixin:
                      if self._wants_flops(timing_hook) else None)
             t0 = time.perf_counter()
             with RecordEvent("generate.prefill_chunk"):
-                tok, new_k, new_v = run(*args)
+                tok, new_k, new_v, stats = run(*args)
                 kv_cache.commit(new_k, new_v)
             self._emit_timing(timing_hook, "prefill_chunk", S, C, 0,
-                              compiled_now, t0, flops=flops)
+                              compiled_now, t0, flops=flops, stats=stats)
             return Tensor(tok)
         finally:
             if was_training:
@@ -734,19 +762,23 @@ class GenerationMixin:
                 def body(carry, _):
                     tok, caches, lens, key, finished = carry
                     valid = (act & (lens < lmax))[:, None]
+                    stats = []
                     lg, caches = self._decode_call(
                         raw_state, tok[:, None], caches, lens, decode_kernel,
-                        paged_tables=tables, cache_valid=valid)
+                        paged_tables=tables, cache_valid=valid,
+                        stats_out=stats)
                     nxt, key, finished = sample(lg[:, -1], key, finished,
                                                 stemps, stks)
                     nxt = jnp.where(act, nxt, tok)   # idle slots hold
-                    return (nxt, caches, lens + adv, key, finished), nxt
+                    return ((nxt, caches, lens + adv, key, finished),
+                            (nxt, (stats or [{}])[0]))
 
-                (_, caches, _, _, _), toks = jax.lax.scan(
+                (_, caches, _, _, _), (toks, stats) = jax.lax.scan(
                     body, (tok, caches, lens, key, jnp.zeros((S,), bool)),
                     jnp.arange(T))
                 return (jnp.swapaxes(toks, 0, 1),
-                        [kc for kc, _ in caches], [vc for _, vc in caches])
+                        [kc for kc, _ in caches], [vc for _, vc in caches],
+                        {k: jnp.sum(v, axis=0) for k, v in stats.items()})
 
             if bank_sig is None:
                 return jax.jit(step, donate_argnums=donate)
@@ -778,10 +810,10 @@ class GenerationMixin:
                      if self._wants_flops(timing_hook) else None)
             t0 = time.perf_counter()
             with RecordEvent("generate.decode_step"):
-                toks, new_k, new_v = run(*args)
+                toks, new_k, new_v, stats = run(*args)
                 kv_cache.commit(new_k, new_v)
             self._emit_timing(timing_hook, "decode_step", S, 1, T,
-                              compiled_now, t0, flops=flops)
+                              compiled_now, t0, flops=flops, stats=stats)
             return Tensor(toks)
         finally:
             if was_training:

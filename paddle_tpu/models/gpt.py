@@ -326,8 +326,11 @@ class GPTForCausalLM(Layer, GenerationMixin):
         return self.gpt
 
     def _decode_cache_spec(self):
+        from ..inference.kv_cache import CacheSpec
+
         c = self.config
-        return c.num_layers, c.num_kv_heads, c.hidden_size // c.num_heads
+        return CacheSpec.uniform(c.num_layers, c.num_kv_heads,
+                                 c.hidden_size // c.num_heads)
 
     def _decode_validate(self, prompt_len, max_new_tokens):
         c = self.config

@@ -267,8 +267,11 @@ class LlamaForCausalLM(Layer, GenerationMixin):
         return self
 
     def _decode_cache_spec(self):
+        from ..inference.kv_cache import CacheSpec
+
         c = self.config
-        return c.num_layers, c.num_kv_heads, c.hidden_size // c.num_heads
+        return CacheSpec.uniform(c.num_layers, c.num_kv_heads,
+                                 c.hidden_size // c.num_heads)
 
     def _decode_validate(self, prompt_len, max_new_tokens):
         pass  # rope positions extrapolate; no learned-position table to overrun

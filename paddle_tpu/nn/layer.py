@@ -16,6 +16,7 @@ import numpy as np
 
 from ..analysis.lockwitness import make_rlock
 from ..framework import dtype as _dt
+from ..framework.lazy_init import LazyGuard
 from ..tensor import Tensor
 from . import initializer as I
 
@@ -152,7 +153,11 @@ class Layer:
                 init = glob[1] if (is_bias and glob[1] is not None) else glob[0]
         if init is None:
             init = I.Constant(0.0) if is_bias else I.XavierUniform()
-        value = init(shape, dtype)
+        if LazyGuard.active():      # a shape and a dtype, no bytes
+            value = jax.ShapeDtypeStruct(tuple(int(n) for n in shape),
+                                         jnp.dtype(dtype))
+        else:
+            value = init(shape, dtype)
         p = Parameter(value, trainable=attr.trainable, name=attr.name)
         if attr.learning_rate != 1.0:
             p.optimize_attr["learning_rate"] = attr.learning_rate

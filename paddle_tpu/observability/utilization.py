@@ -66,6 +66,9 @@ Exported series (absent-iff-off, like every optional subsystem):
   useful | pad | spec_waste; the sum over kinds is issued.
 * ``paddle_tenant_flops_total{component,tenant}`` — chargeback counters.
 * ``paddle_serving_host_gap_seconds{component}`` — per-tick histogram.
+* ``paddle_serving_moe_expert_load_skew{component}`` — the busiest held
+  expert's assignments over the mean's; registered when an expert layer
+  first reports its counts (``MODEL_KEYS``).
 * ``paddle_serving_mfu{component}`` — rolling-window useful FLOP/s over
   ``device_peak_flops`` — registered only when the peak is KNOWN (real
   accelerator or an injected ``peak_flops=``); on CPU the gauge is absent,
@@ -144,6 +147,24 @@ _PROGRAM_KEYS = (_FLOPS + ("launches",) + _POSITIONS
                  + ("live_rows", "walked_rows", "table_rows", "dispatch_s",
                     "wait_s"))
 _TICK_KEYS = _FLOPS + ("launch_s", "dispatch_s", "wait_s")
+# What a model counts of its own launches, on a program's account only where
+# a model reports it (record_launch's `counts`): the expert layer's rows (the
+# tiles it walked, the assignments its held experts got, those of them each
+# expert got, the assignments of real tokens to experts held elsewhere) and
+# the attention's rows (what the queries need at most, what the programs
+# read from the pools, the keys the indexer scored).
+MODEL_KEYS = ("moe_rows_issued", "moe_rows_useful",
+              "moe_assignments_elsewhere", "moe_expert_tokens",
+              "attn_rows_needed", "attn_rows_read", "indexer_rows_scored")
+
+
+def _add_count(acc, key, value):
+    """acc[key] += value, a number or a list added place by place."""
+    if isinstance(value, (list, tuple)):
+        have = acc.get(key) or [0] * len(value)
+        acc[key] = [a + int(b) for a, b in zip(have, value)]
+    else:
+        acc[key] = acc.get(key, 0) + int(value)
 
 
 def _new_account():
@@ -163,6 +184,9 @@ def _fold(acc, tick):
         tot = acc["programs"].setdefault(name, dict.fromkeys(_PROGRAM_KEYS, 0))
         for key in _PROGRAM_KEYS:
             tot[key] += p[key]
+        for key in MODEL_KEYS:
+            if key in p:
+                _add_count(tot, key, p[key])
 
 
 def _account_view(acc):
@@ -227,6 +251,7 @@ class UtilizationLedger:
         self._flops_counter = None
         self._tenant_counter = None
         self._gap_hist = None
+        self._skew_gauge = None     # (registry, component) until it is bound
         _LIVE.add(self)
 
     def close(self):
@@ -258,6 +283,7 @@ class UtilizationLedger:
             "dial",
             labels=("component",), buckets=HOST_GAP_BUCKETS).labels(
                 component)
+        self._skew_gauge = (registry, component)
         if self.peak_flops:
             registry.gauge(
                 "paddle_serving_mfu",
@@ -298,13 +324,14 @@ class UtilizationLedger:
 
     def record_launch(self, program, flops, launch_s, total_units,
                       slot_units, spec_units=0, *, wait_s=0.0, live_rows=0,
-                      walked_rows=0, table_rows=0):
+                      walked_rows=0, table_rows=0, counts=None):
         """Attribute one launch inside the current tick. ``launch_s`` is
         the launch THROUGH its read-back, ``wait_s`` the read-back's part
         of it. ``total_units`` are the positions the program issued,
         ``slot_units`` ``[(tenant_or_None, useful_units), ...]`` per live
         slot — the scheduler's ground truth of which positions carried live
-        tokens — and ``spec_units`` rejected draft positions."""
+        tokens — and ``spec_units`` rejected draft positions. ``counts`` are
+        the model's own of this launch, under ``MODEL_KEYS``."""
         if self._tick is None:      # launch outside a tick (warmup): skip
             return
         self.poll_session()
@@ -335,6 +362,32 @@ class UtilizationLedger:
         p["live_rows"] += int(live_rows)
         p["walked_rows"] += int(walked_rows)
         p["table_rows"] += int(table_rows)
+        for key, value in (counts or {}).items():
+            if key in MODEL_KEYS:
+                _add_count(p, key, value)
+        if self._skew_gauge and "moe_expert_tokens" in (counts or {}):
+            # absent until an expert layer reports: a model without one has
+            # no such series
+            registry, component = self._skew_gauge
+            self._skew_gauge = None
+            registry.gauge(
+                "paddle_serving_moe_expert_load_skew",
+                "Assignments of the busiest held expert over the mean's, "
+                "over the process (1.0: even)",
+                labels=("component",)).labels(component).set_function(
+                    lambda: self.expert_load_skew() or 0.0)
+
+    def expert_load_skew(self):
+        """The busiest held expert's assignments over the mean's, over the
+        process so far (1.0: even; None: no expert layer reported)."""
+        with self._lock:
+            per = [p["moe_expert_tokens"] for p in
+                   self._process["programs"].values()
+                   if p.get("moe_expert_tokens")]
+        if not per:
+            return None
+        load = [sum(col) for col in zip(*per)]
+        return max(load) * len(load) / sum(load) if sum(load) else None
 
     def tick_end(self):
         if self._tick is None:

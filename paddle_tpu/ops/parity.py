@@ -427,16 +427,7 @@ def check_shape(x):  # static-graph debug helper; shape is always concrete here
     return list(_val(x).shape)
 
 
-class LazyGuard:
-    """Reference framework/LazyGuard: delay parameter init until first call.
-    Parameters here are created eagerly but cheaply (jax arrays are lazy until
-    used) — kept as a no-op context for API compatibility."""
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *a):
-        return False
+from ..framework.lazy_init import LazyGuard  # noqa: E402,F401
 
 
 def create_parameter(shape, dtype, name=None, attr=None, is_bias=False,

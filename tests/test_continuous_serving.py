@@ -112,6 +112,46 @@ def test_chunked_prefill_tight_budget_parity(small_gpt):
         gp.close()
 
 
+def test_the_budget_never_cuts_a_chunk(small_gpt):
+    """Three long prompts under the default budget of two chunks a tick: a
+    prompt's tail (shorter than a chunk) leaves part of a chunk of budget
+    over, and the next slot waits a tick rather than take a piece: every
+    pick is a whole chunk or the whole of what its prompt has left, a tick
+    never spends more than its budget, and the answers stay token-exact."""
+    m = small_gpt
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 160, n).astype("int64") for n in (23, 21, 18)]
+    refs = [_dense_ref(m, p, 6) for p in prompts]
+    gp = _make(m, prefill_chunk=4)
+    assert gp.prefill_token_budget == 8
+    picks, real = [], m.prefill_chunk
+
+    def counted(chunk, offs, lens, *a, **k):
+        picks.append([(int(o), int(n)) for o, n in zip(offs, lens) if n])
+        return real(chunk, offs, lens, *a, **k)
+    m.prefill_chunk = counted
+    try:
+        results = {}
+        ts = [threading.Thread(target=lambda i=i: results.update(
+            {i: gp.infer(prompts[i], timeout=300)})) for i in range(3)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=300)
+        for i in range(3):
+            np.testing.assert_array_equal(results[i], refs[i])
+        assert max(len(p) for p in picks) >= 2
+        for tick in picks:
+            assert sum(n for _, n in tick) <= 8
+            # chunks start where the last ended, so a pick that is no whole
+            # chunk is a prompt's tail: offset + take is a prompt's length
+            assert all(n == 4 or o + n in (23, 21, 18) for o, n in tick)
+            assert all(o % 4 == 0 for o, _ in tick)
+    finally:
+        del m.prefill_chunk
+        gp.close()
+
+
 def test_per_request_max_new_retires_early_with_parity(small_gpt):
     """Per-request token budgets: a request asking for fewer tokens gets the
     PREFIX of the full generation (token parity), retires early, and frees
