@@ -251,7 +251,7 @@ def paged_kernel_parity(cfg, geometry, *, expect_mosaic=True):
     table_width = geometry["max_seq_len"] // block_size
     row_counts = (1, geometry["spec_k"] + 1, geometry["prefill_chunk"])
     rng = np.random.default_rng(0)
-    pool = (kv_heads, num_blocks, block_size, head_dim)
+    pool = (num_blocks, block_size, kv_heads * head_dim)
     k_pages = jnp.asarray(rng.standard_normal(pool), jnp.bfloat16)
     v_pages = jnp.asarray(rng.standard_normal(pool), jnp.bfloat16)
     tables = jnp.asarray(

@@ -428,12 +428,11 @@ def step_comms_surfaces(paths=None):
 # declared OUTPUT layout per step path: the KV pool layers stay
 # head-sharded on the way out (same SpecLayout.kv_pool placement the
 # inputs declare); sampled tokens come back replicated to the host.
-_OUTPUT_CONTRACT = {
-    "prefill_chunk": {"out.0": (), "out.1.*": ("tp",), "out.2.*": ("tp",)},
-    "decode_step": {"out.0": (), "out.1.*": ("tp",), "out.2.*": ("tp",)},
-    "verify_step": {"out.0": (), "out.1": (),
-                    "out.2.*": ("tp",), "out.3.*": ("tp",)},
-}
+def _output_contract(pool) -> dict:
+    step = {"out.0": (), "out.1.*": pool, "out.2.*": pool}
+    return {"prefill_chunk": step, "decode_step": step,
+            "verify_step": {"out.0": (), "out.1": (),
+                            "out.2.*": pool, "out.3.*": pool}}
 
 
 def render_comms_table(surfaces) -> str:
@@ -724,8 +723,9 @@ def analyze_step_comms(allowlist=None, *, paths=None,
     surfaces = (_surfaces if _surfaces is not None
                 else step_comms_surfaces(paths=paths))
     layout = SpecLayout()
+    outputs = _output_contract(layout.kv_pool())
     for s in surfaces:
-        s["contract"] = _OUTPUT_CONTRACT.get(s.get("path"), {})
+        s["contract"] = outputs.get(s.get("path"), {})
     return analyze_comms_surfaces(
         surfaces,
         contract=layout.step_contract(),

@@ -297,14 +297,16 @@ def mesh_axis_size(name, jax_mesh=None) -> int:
     return int(dict(zip(jm.axis_names, jm.devices.shape))[name])
 
 
-def per_shard(local_fn, args, dims, out_dims):
+def per_shard(local_fn, args, dims, out_dims, head_dim=1):
     """Run `local_fn(*args)` once per (batch, head) shard of the current
     compute mesh — for a call GSPMD cannot partition (a Mosaic kernel: "wrap
     the call in a shard_map") whose math is independent per batch row and per
     head. `dims` gives one string per argument, one letter per leading
-    dimension: "b" batch, "h" heads, "." anything else ("b.h." for
-    [B, S, H, D], "h" for a head-leading page pool, "" for a table every
-    shard reads whole); `out_dims` the same for the single output.
+    dimension: "b" batch, "h" heads, "H" heads side by side in one
+    dimension, `head_dim` numbers each (it counts as extent / head_dim
+    heads), "." anything else ("b.h." for [B, S, H, D], "..H" for a
+    [pages, rows, heads x head_dim] pool, "" for a table every shard reads
+    whole); `out_dims` the same for the single output.
 
     Batch shards over `dp` and heads over the tensor axis (`mp` | `tp`), each
     only where the axis divides every such dimension. A head dimension of 1
@@ -318,9 +320,12 @@ def per_shard(local_fn, args, dims, out_dims):
         return local_fn(*args)
     sizes = dict(zip(jm.axis_names, jm.devices.shape))
 
+    def heads_of(a, i, c):
+        return a.shape[i] // head_dim if c == "H" else a.shape[i]
+
     def extents(letter):
-        return [a.shape[i] for a, d in zip(args, dims)
-                for i, c in enumerate(d) if c == letter]
+        return [heads_of(a, i, c) for a, d in zip(args, dims)
+                for i, c in enumerate(d) if c.lower() == letter]
 
     def axis_for(names, ns):
         return next((ax for ax in names if sizes.get(ax, 1) > 1
@@ -331,15 +336,15 @@ def per_shard(local_fn, args, dims, out_dims):
     heads = (axis_for(("mp", "tp"), nh)
              if nh and all(n % min(nh) == 0 for n in nh) else None)
 
-    def spec(d, shape=None):
+    def spec(d, a=None):
         return PartitionSpec(*[
             batch if c == "b"
-            else heads if c == "h" and (shape is None or shape[i] > 1)
+            else heads if c in "hH" and (a is None or heads_of(a, i, c) > 1)
             else None for i, c in enumerate(d)])
 
     return jax.shard_map(
         local_fn, mesh=jm,
-        in_specs=tuple(spec(d, a.shape) for a, d in zip(args, dims)),
+        in_specs=tuple(spec(d, a) for a, d in zip(args, dims)),
         out_specs=spec(out_dims), check_vma=False)(*args)
 
 
@@ -347,7 +352,7 @@ def per_shard(local_fn, args, dims, out_dims):
 class SpecLayout:
     """Canonical partition entries for the ("dp","tp") serving mesh (SNIPPETS
     SpecLayout pattern): tp rides the qkv/ffn/embedding tensor axes, the paged
-    KV pool head-shards on its leading axis, and everything slot-shaped stays
+    KV pool head-shards on its last axis, and everything slot-shaped stays
     replicated — dp carries no in-program sharding because data parallelism
     lives at the scheduler-replica level (`ReplicaFleet`)."""
 
@@ -356,9 +361,9 @@ class SpecLayout:
         self.tp_axis = tp_axis
 
     def kv_pool(self):
-        """[Hkv, pages, block, head_dim] — the pool's leading axis IS the KV
-        head axis, so head-sharding is a leading-dim shard."""
-        return (self.tp_axis, None, None, None)
+        """[pages, block, Hkv x head_dim] — a row's heads are lane groups
+        of its last axis, so head-sharding is a last-dim shard."""
+        return (None, None, self.tp_axis)
 
     def heads(self, ndim=4, head_dim=2):
         """Head-major activations, e.g. q [B, S, Hq, D]."""
@@ -399,9 +404,9 @@ class SpecLayout:
             # lm_head, which is what makes the logits vocab-sharded.
             "state.embed_tokens.weight": (tp, None),
             "state.*ln*.weight": (),
-            # the paged pool head-shards on its leading axis (kv_pool())
-            "k_pages*": (tp,),
-            "v_pages*": (tp,),
+            # the paged pool head-shards on its last axis (kv_pool())
+            "k_pages*": (None, None, tp),
+            "v_pages*": (None, None, tp),
             # host-side knobs stay replicated: sampler params, block
             # tables and the PRNG key are scheduler state, never sharded
             "tables": (),

@@ -3,7 +3,7 @@
 Reference role: paddle/phi/kernels/fusion/gpu/block_multi_head_attention_
 kernel.cu + the BlockManager half of vLLM's design (Kwon et al., SOSP 2023).
 TPU-native shape: one shared per-layer page pool on device ([num_blocks,
-block_size, Hkv, D]); each request owns a block TABLE (host ints) handed to
+block_size, Hkv * D]); each request owns a block TABLE (host ints) handed to
 the paged decode-attention kernel (ops/pallas/decode_attention.py), which
 DMAs each request's live pages through its scalar-prefetched table row — no
 gather materialization, and nothing read past a request's length. Mixed-length requests in a batch therefore hold
@@ -236,16 +236,17 @@ class PagedKVCache:
         return cls(spec=as_cache_spec(model._decode_cache_spec()), **kwargs)
 
     def _layer_arrays(self, cache):
-        """(first, second) array of one layer: K and V pages head-leading
-        [Hkv, P, BS, D] (the paged kernel resolves the head axis in its
-        index_map, so pages stream as contiguous [BS, D] tiles); latent rows
+        """(first, second) array of one layer: K and V pages [P, BS, Hkv*D],
+        head h in lanes h*D .. (h+1)*D of a row (the one layout the row
+        writer, the step programs' carry and the paged kernel share: a page
+        is one contiguous block, and nothing copies the pool); latent rows
         [P, BS, row] beside the indexer's keys [P, BS, index_row] or None;
         a window layer's ring [slots, ring rows, row], no second array."""
         import jax.numpy as jnp
 
         if cache.kind == "kv":
-            shape = (cache.heads, self.num_blocks, self.block_size,
-                     cache.head_dim)
+            shape = (self.num_blocks, self.block_size,
+                     cache.heads * cache.head_dim)
             return jnp.zeros(shape, self.dtype), jnp.zeros(shape, self.dtype)
         if cache.window is not None:
             if self.slots is None:
@@ -640,10 +641,10 @@ class PagedKVCache:
             tbl = np.asarray(req.blocks[:n])
 
             def _dense(pages):
-                # [Hkv, n, BS, D] -> [n*BS, Hkv, D]
-                arr = np.asarray(pages)[:, tbl]
-                arr = arr.reshape(self.num_kv_heads, -1, self.head_dim)
-                return arr.swapaxes(0, 1)[:req.length]
+                # [n, BS, Hkv*D] -> [n*BS, Hkv, D]
+                arr = np.asarray(pages)[tbl]
+                return arr.reshape(-1, self.num_kv_heads,
+                                   self.head_dim)[:req.length]
 
             return _dense(self.k_pages[layer]), _dense(self.v_pages[layer])
 

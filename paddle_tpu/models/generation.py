@@ -8,7 +8,7 @@ allocated on the host per call.
 Two cache layouts:
   * dense — per-request [B, max_len, Hkv, D] caches allocated in-program
     (the `generate()` path; one contiguous cache per batch slot).
-  * paged — a shared page pool [num_pages, block_size, Hkv, D] addressed
+  * paged — a shared page pool [num_pages, block_size, Hkv * D] addressed
     through per-request block tables (the `generate_paged()` path; serving
     hands in a paddle_tpu.inference.kv_cache.PagedKVCache so mixed-length
     requests share cache memory instead of each padding to max length).

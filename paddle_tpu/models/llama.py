@@ -112,7 +112,7 @@ class LlamaAttention(Layer):
             if paged:
                 def attend_paged(qv, kv, vv, kp, vp, tbl, ln, vld):
                     ln = jnp.asarray(ln, jnp.int32)
-                    capacity = tbl.shape[1] * kp.shape[2]
+                    capacity = tbl.shape[1] * kp.shape[1]
                     pos = da.write_positions(ln, S, valid=vld,
                                              capacity=capacity)
                     kp, vp = da.paged_cache_update(kp, vp, kv, vv, tbl, pos)

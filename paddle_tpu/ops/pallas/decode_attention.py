@@ -25,26 +25,28 @@ Paged (the serving path; PagedAttention, Kwon et al. 2023): ONE stage whose
 work follows the live context, not the table's width. The page pools stay in
 HBM; grid (B, Hkv / heads_per_step). A step reads its slot's length and trip
 count from scalar-prefetched arrays and runs a loop with that DYNAMIC trip
-count: each trip DMAs `pages_per_step` pages of all the step's heads into a
-double-buffered VMEM scratch (the next trip's copies are in flight while
-this one is multiplied) and folds them into running (m, l, acc) accumulators
-in VMEM — the same block-local `_partials` and the same rescale as the dense
-stage 2, online — then writes the normalised [B, Hkv, S*G, D] output once.
-Pages past a slot's length cost nothing: no grid step, no DMA, no HBM store;
-a slot with no valid new row (an idle slot of the decode tick, a slot that
-takes nothing of a prefill chunk) runs zero trips. `heads_per_step` and
-`pages_per_step` come from the static shapes and a VMEM budget
-(`paged_tiling`); the trip count comes from `paged_walk_blocks`, which the
-scheduler's `walked_rows` counter uses too. For D < 128 the pool enters the
-kernel as a lane-dense view, R = 128/D rows of a page side by side
-(`_row_pack`): Mosaic slices HBM only in whole 128-lane tiles, and neither
-HBM nor VMEM then holds padding.
+count: each trip DMAs `pages_per_step` pages into a double-buffered VMEM
+scratch (the next trip's copies are in flight while this one is multiplied)
+and folds them into running (m, l, acc) accumulators in VMEM — the same
+block-local `_partials` and the same rescale as the dense stage 2, online —
+then writes the normalised output once. Pages past a slot's length cost
+nothing: no grid step, no DMA, no HBM store; a slot with no valid new row (an
+idle slot of the decode tick, a slot that takes nothing of a prefill chunk)
+runs zero trips. `heads_per_step` and `pages_per_step` come from the static
+shapes and a VMEM budget (`paged_tiling`); the trip count comes from
+`paged_walk_blocks`, which the scheduler's `walked_rows` counter uses too.
 
-Layout contract: caches are HEAD-LEADING — [B, Hkv, T, D] dense, [Hkv, P,
-BS, D] paged — so the head axis is a leading index of every block or DMA,
-never sliced in-kernel (in-kernel head slicing would relayout the whole
-block per head under Mosaic). The models pay only a [B, S, Hkv, D] ->
-[B, Hkv, S, D] transpose of the NEW rows per step — S is 1 at decode.
+Layout contract: the dense cache is HEAD-LEADING, [B, Hkv, T, D], so the head
+axis is a leading index of every block. The paged pool is ROW-MAJOR,
+[P, BS, Hkv*D]: a row holds every kv head's D numbers side by side (head h
+in lanes h*D .. (h+1)*D). That ONE layout is what the row writer scatters
+into (`paged_cache_update`, the new [B, S, Hkv, D] rows as they come), what
+the step programs carry from layer to layer and token to token, and what
+the kernel reads (`pl.ANY`: a page is one contiguous [BS, Hkv*D] DMA), so
+no program holds an XLA op of the pool's size: XLA's scatter, the loop's
+carry and Mosaic all agree on row-major. A head's K and V are a static lane
+slice of the VMEM buffer; for D < 128 the 128 // D heads of one 128-lane
+group are multiplied at once under a block-diagonal q (`_partials`).
 
 GQA is native: q rows are grouped per kv head ([B*Hkv, S*G, D], G =
 num_q_heads / num_kv_heads), so K/V are never materialized at the
@@ -110,26 +112,24 @@ def _norm_lengths(lengths, B):
 
 
 # -------------------------------------------------------------- kernel body
-def _partials(length, col0, q, k, v, *, scale, g, pack=1):
+def _partials(length, col0, q, k, v, *, scale, g, heads=1):
     """Block-local (o, m, l) partials for one (batch*head, kv-block) step.
     q: [SG, D] (S query steps × G grouped q heads, row-major (s, g));
     k/v: [BK, D].
 
-    pack = R > 1 is the paged kernel's lane-dense form for D < 128: k/v are
-    [BK/R, R*D], packed row c holding the block's rows c*R .. c*R + R-1 side
-    by side in the lanes, and q is [R*SG, R*D], block-diagonal: row p*SG + i
-    carries query row i in lane group p and zeros elsewhere, so its scores
-    are query i against the rows of parity p (column c = row c*R + p) and
-    lane group p of its o is their share of the output. The R row groups
-    are independent softmax streams (see `_merge_packed`)."""
+    heads = R > 1 is the paged kernel's form for D < 128, where R kv heads
+    share a 128-lane group of the pool's rows: k/v are [BK, R*D], head p in
+    lane group p, and q is [R*SG, R*D], block-diagonal: row p*SG + i carries
+    query row i of head p in lane group p and zeros elsewhere, so its scores
+    are that head's alone and lane group p of its o is that head's output
+    (the other lanes of the row are another head's V under this head's
+    weights: never read). Every row sees every K row."""
     sg, bk = q.shape[0], k.shape[0]
     scale32 = jnp.float32(scale)
-    cols = col0 + pack * jax.lax.broadcasted_iota(jnp.int32, (sg, bk), 1)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, (sg, bk), 1)
     rloc = jax.lax.broadcasted_iota(jnp.int32, (sg, bk), 0)
-    if pack > 1:
-        parity = jax.lax.div(rloc, jnp.int32(sg // pack))
-        cols = cols + parity
-        rloc = rloc - parity * (sg // pack)
+    if heads > 1:
+        rloc = jax.lax.rem(rloc, jnp.int32(sg // heads))
     # row r is query step s = r//G at absolute position length + s — causal
     # over the live prefix + the new rows
     qrow = jax.lax.div(rloc, jnp.int32(g)) if g > 1 else rloc
@@ -281,14 +281,20 @@ _VMEM_BUDGET = 10 << 20   # bytes of VMEM a grid step's buffers may take
 _VMEM_LIMIT = 32 << 20    # the scoped limit asked of Mosaic for the call
 
 
-def _row_pack(BS, D):
-    """Rows of a page the kernel sees side by side in one 128-lane row: the
-    pool goes in as [Hkv, P, BS/R, R*D]. Mosaic slices an HBM ref only in
-    whole 128-lane tiles ("Slice shape ... must be aligned to tiling (128)",
-    libtpu 0.0.34), so at D = 64 a page cannot be DMA'd out of [.., BS, 64];
-    packed, it can, and no lane of HBM or VMEM is padding."""
-    R = 128 // D if D < 128 and 128 % D == 0 else 1
-    return R if BS % R == 0 else 1
+def _heads_per_group(D):
+    """Heads that sit side by side in one lane group of a pool row: 128 // D
+    for D < 128 (two heads of 64 share a 128-lane tile), else one. Mosaic
+    slices refs only in whole 128-lane tiles, so a group is what the kernel
+    multiplies at once."""
+    return 128 // D if D < 128 else 1
+
+
+def paged_kernel_takes(Hq, Hkv, D):
+    """Static check: can the Mosaic paged kernel read a pool of `Hkv` heads
+    of `D` (the LOCAL heads under tp). A row must be whole 128-lane tiles
+    and a head must not straddle one; anything else takes the XLA gather."""
+    return (Hq % Hkv == 0 and D <= 256 and (Hkv * D) % 128 == 0
+            and (128 % D == 0 or D % 128 == 0))
 
 
 def paged_pages_per_step(NB, BS):
@@ -300,20 +306,22 @@ def paged_pages_per_step(NB, BS):
 def paged_tiling(Hkv, NB, BS, D, SG, itemsize):
     """(heads_per_step, pages_per_step) of the paged kernel, from static
     shapes alone. A loop trip folds `paged_pages_per_step` pages; a grid
-    step serves the largest divisor of `Hkv` heads whose buffers fit
-    `_VMEM_BUDGET`: the double-buffered K and V blocks, the pipelined q and
-    output blocks and the f32 (acc, m, l) accumulators, at VMEM's (16, 128)
-    padding."""
+    step serves the largest divisor of the row's lane groups (see
+    `_heads_per_group`) whose buffers fit `_VMEM_BUDGET`: the
+    double-buffered K and V blocks, the pipelined q and output blocks and
+    the f32 (acc, m, l) accumulators, at VMEM's (16, 128) padding."""
     pps = paged_pages_per_step(NB, BS)
-    R = _row_pack(BS, D)
+    R = _heads_per_group(D)
     lanes = -(-R * D // 128) * 128
     rows = -(-R * SG // 16) * 16
-    per_head = (2 * 2 * (pps * BS // R) * lanes * itemsize   # K, V: 2 slots
-                + 2 * rows * lanes * (itemsize + 4)      # q, out (pipelined)
-                + rows * (lanes + 2 * 128) * 4)          # acc, m, l
-    fit = max(1, _VMEM_BUDGET // per_head)
-    hps = max(h for h in range(1, Hkv + 1) if Hkv % h == 0 and h <= fit)
-    return hps, pps
+    per_group = (2 * 2 * pps * BS * lanes * itemsize     # K, V: 2 slots
+                 + 2 * rows * lanes * (itemsize + 4)     # q, out (pipelined)
+                 + rows * (lanes + 2 * 128) * 4)         # acc, m, l
+    fit = max(1, _VMEM_BUDGET // per_group)
+    groups = Hkv // R
+    gps = max(n for n in range(1, groups + 1)
+              if groups % n == 0 and n <= fit)
+    return gps * R, pps
 
 
 def paged_walk_blocks(lengths, new_rows, block_rows):
@@ -327,50 +335,37 @@ def paged_walk_blocks(lengths, new_rows, block_rows):
     return (new_rows > 0) * ((rows + (block_rows - 1)) // block_rows)
 
 
-def _merge_packed(m, l, acc, pack, d):
-    """Fold the R = `pack` row groups of the packed accumulators
-    ([.., R*SG, 1], [.., R*SG, 1], [.., R*SG, R*D]) into the normalised
-    [.., SG, D] output: group p saw the rows of parity p and its share of the
-    output sits in lane group p — the split-KV rescale over R partials. A row
-    that saw no live column (an idle slot) comes out 0."""
-    sg = m.shape[-2] // pack
-    rows = [slice(p * sg, (p + 1) * sg) for p in range(pack)]
-    m_star = functools.reduce(jnp.maximum, [m[..., r, :] for r in rows])
-    l_star, o = 0.0, 0.0
-    for p, r in enumerate(rows):
-        w = jnp.exp(m[..., r, :] - m_star)
-        l_star = l_star + w * l[..., r, :]
-        o = o + w * acc[..., r, p * d:(p + 1) * d]
-    return jnp.where(l_star > 0, o / jnp.where(l_star > 0, l_star, 1.0), 0.0)
-
-
 def _paged_kernel(tbl_ref, len_ref, trips_ref, pages_ref, q_ref, k_hbm, v_hbm,
                   o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref, *, scale,
-                  block_size, g, hps, pps, pack):
-    """One grid step = slot b x `hps` kv heads. Walks the slot's LIVE pages
-    only: `trips_ref[b]` loop trips, each folding `pps` pages of all the
-    step's heads (DMA'd HBM -> VMEM, the next trip's in flight meanwhile)
-    into the running (m, l, acc) — the online form of the split-KV rescale
-    — and writes the normalised output once. A page index past the slot's
-    last live page is clamped to it (its columns are masked), so nothing a
-    slot does not own is ever read. Refs are in the packed form of
-    `_row_pack`; `block_size` is a page's rows as the pool has them."""
+                  block_size, g, gps, pps, heads, d):
+    """One grid step = slot b x `gps` lane groups of `heads` kv heads each.
+    Walks the slot's LIVE pages only: `trips_ref[b]` loop trips, each
+    folding `pps` pages (DMA'd HBM -> VMEM as [BS, lanes] blocks — one
+    contiguous piece a page where the step serves every head — the next
+    trip's in flight meanwhile) into the running (m, l, acc) — the online
+    form of the split-KV rescale — and writes the normalised output once. A
+    page index past the slot's last live page is clamped to it (its columns
+    are masked), so nothing a slot does not own is ever read. A head's K
+    and V are a static lane slice of the buffers."""
     b = pl.program_id(0)
-    hsl = pl.ds(pl.program_id(1) * hps, hps)
     length = len_ref[b]
     trips = trips_ref[b]
     last = pages_ref[b] - 1
-    prows = block_size // pack          # packed rows of one page
+    gw = heads * d                      # lanes of one group
+    lw = gps * gw                       # lanes this step serves: all, or
+    step_lanes = (slice(None) if lw == k_hbm.shape[-1] else pl.ds(  # a slice
+        pl.multiple_of(pl.program_id(1) * lw, 128), lw))
 
     def pages_of(i, slot, act):
         """`act` (start or wait) on the K and V copy of each page of trip i
         into buffer `slot`; a rolled loop, the kernel's code stays small."""
         def page_copies(j, carry):
             page = tbl_ref[b, jnp.minimum(i * pps + j, last)]
-            rows = pl.ds(pl.multiple_of(j * prows, prows), prows)
+            rows = pl.ds(pl.multiple_of(j * block_size, block_size),
+                         block_size)
             for hbm, buf, kv in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
                 act(pltpu.make_async_copy(
-                    hbm.at[hsl, page], buf.at[slot, :, rows, :],
+                    hbm.at[page, :, step_lanes], buf.at[slot, rows, :],
                     sem.at[kv, slot]))
             return carry
 
@@ -400,30 +395,40 @@ def _paged_kernel(tbl_ref, len_ref, trips_ref, pages_ref, q_ref, k_hbm, v_hbm,
         pages_of(i, slot, wait)
         col0 = i * (pps * block_size)
 
-        # a static loop: unrolled, the heads' products overlap (measured on
+        # a static loop: unrolled, the groups' products overlap (measured on
         # the v5e at 20 heads: a decode call 0.30 ms against 0.37 rolled)
-        for h in range(hps):
-            o, m, l = _partials(length, col0, q_ref[0, h], k_buf[slot, h],
-                                v_buf[slot, h], scale=scale, g=g, pack=pack)
-            m_old = m_ref[h]
+        for j in range(gps):
+            lanes = slice(j * gw, (j + 1) * gw)
+            o, m, l = _partials(length, col0, q_ref[0, j],
+                                k_buf[slot, :, lanes], v_buf[slot, :, lanes],
+                                scale=scale, g=g, heads=heads)
+            m_old = m_ref[j]
             m_new = jnp.maximum(m_old, m)
             a, c = jnp.exp(m_old - m_new), jnp.exp(m - m_new)
-            l_ref[h] = a * l_ref[h] + c * l
-            acc_ref[h] = a * acc_ref[h] + c * o
-            m_ref[h] = m_new
+            l_ref[j] = a * l_ref[j] + c * l
+            acc_ref[j] = a * acc_ref[j] + c * o
+            m_ref[j] = m_new
         return carry
 
     jax.lax.fori_loop(0, trips, trip, 0)
-    o_ref[0] = _merge_packed(m_ref[...], l_ref[...], acc_ref[...], pack,
-                             o_ref.shape[-1]).astype(o_ref.dtype)
+    # a row that saw no live column (an idle slot) comes out 0; head p of a
+    # group is its own rows and its own lanes of the accumulator
+    l_all = l_ref[...]
+    out = jnp.where(l_all > 0,
+                    acc_ref[...] / jnp.where(l_all > 0, l_all, 1.0), 0.0)
+    sg = out.shape[1] // heads
+    for p in range(heads):
+        o_ref[0, :, p] = out[:, p * sg:(p + 1) * sg,
+                             p * d:(p + 1) * d].astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
                            scale=None, kernel="pallas", new_rows=None):
     """Decode attention reading KV through per-request block tables.
 
-    q: [B, S, Hq, D]; k_pages/v_pages: [Hkv, P, BS, D] (the shared
-    head-leading page pool); block_tables: [B, NB] int32 page ids (entries
+    q: [B, S, Hq, D]; k_pages/v_pages: [P, BS, Hkv*D] (the shared page pool:
+    a row holds every kv head's D numbers side by side, head h in lanes
+    h*D .. (h+1)*D); block_tables: [B, NB] int32 page ids (entries
     past a request's extent must still be VALID page ids, e.g. 0 — the
     Pallas kernel never fetches them, the XLA gather does and masks them);
     lengths: [B] int32 live prefix per request. new_rows: [B] int32, how
@@ -434,17 +439,18 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
     are zeros. The XLA path takes no notice of it: rows at or past new_rows
     are the caller's to ignore under either kernel.
 
-    Pallas path: the pools stay in HBM; the kernel DMAs the live pages of
+    Pallas path: the pools stay in HBM, in the layout the writer and the
+    step programs' carry hold them in; the kernel DMAs the live pages of
     each slot through its scalar-prefetched table row — the PagedAttention
-    access pattern, no gather materialization.
+    access pattern, no gather materialization and no copy of the pool.
 
     Under a mesh with a tensor axis (the serving mesh's tp, ISSUE-12), the
     whole call runs per head shard (`distributed.mesh.per_shard`): each chip
-    runs the kernel on its LOCAL heads against its LOCAL pool shard
-    (attention is head-local, so no collective is needed here — the only
-    cross-chip exchange per launch is the sampled-logit gather after the
-    vocab-sharded lm_head). The slot dimension stays replicated: the serving
-    mesh's dp is the replica axis, not a batch axis.
+    runs the kernel on its LOCAL heads against its LOCAL pool shard, the
+    lanes of its heads (attention is head-local, so no collective is needed
+    here — the only cross-chip exchange per launch is the sampled-logit
+    gather after the vocab-sharded lm_head). The slot dimension stays
+    replicated: the serving mesh's dp is the replica axis, not a batch axis.
     """
     B, S, D = q.shape[0], q.shape[1], q.shape[3]
     if scale is None:
@@ -458,26 +464,25 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
                           kernel=kernel),
         (q, k_pages, v_pages, jnp.asarray(block_tables, jnp.int32),
          _norm_lengths(lengths, B), _norm_lengths(new_rows, B)),
-        ("..h.", "h", "h", "", "", ""), "..h.")
+        ("..h.", "..H", "..H", "", "", ""), "..h.", head_dim=D)
 
 
 def _paged_decode_attention_impl(q, k_pages, v_pages, block_tables, lengths,
                                  new_rows, scale=None, kernel="pallas"):
     B, S, Hq, D = q.shape
-    Hkv, BS = k_pages.shape[0], k_pages.shape[2]
+    BS, Hkv = k_pages.shape[1], k_pages.shape[2] // D
     NB = block_tables.shape[1]
     G = Hq // Hkv
     if scale is None:
         scale = 1.0 / math.sqrt(D)
-    R = _row_pack(BS, D)
-    if (kernel != "pallas" or D > 256 or Hq % Hkv != 0
-            or (R * D) % 128 != 0):
+    if kernel != "pallas" or not paged_kernel_takes(Hq, Hkv, D):
         # gather-based reference: pages -> contiguous head-leading dense cache
-        k_dense = (k_pages[:, block_tables]        # [Hkv, B, NB, BS, D]
-                   .reshape(Hkv, B, NB * BS, D).swapaxes(0, 1))
-        v_dense = (v_pages[:, block_tables]
-                   .reshape(Hkv, B, NB * BS, D).swapaxes(0, 1))
-        return decode_attention_xla(q, k_dense, v_dense, lengths, scale)
+        def dense(pages):
+            return (pages[block_tables]              # [B, NB, BS, Hkv*D]
+                    .reshape(B, NB * BS, Hkv, D).swapaxes(1, 2))
+
+        return decode_attention_xla(q, dense(k_pages), dense(v_pages),
+                                    lengths, scale)
     hps, pps = paged_tiling(Hkv, NB, BS, D, S * G, k_pages.dtype.itemsize)
     return _paged_pallas(
         q, k_pages, v_pages, block_tables, lengths,
@@ -496,37 +501,39 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, lengths, trips, pages, *,
     shapes, so the kernel is traced and lowered to Mosaic ONCE a program
     (36 layers x 0.4 s each otherwise, which `setup_s` would pay at every
     start, compile cache or not); XLA inlines the calls, one Mosaic
-    instruction a layer."""
+    instruction a layer. The pools go in as they are: no reshape, no copy."""
     B, S, Hq, D = q.shape
-    Hkv, BS = k_pages.shape[0], k_pages.shape[2]
+    BS, Hkv = k_pages.shape[1], k_pages.shape[2] // D
     G = Hq // Hkv
     sg = S * G
-    R = _row_pack(BS, D)
-    qr = _q_rows(q.astype(k_pages.dtype), Hkv, G).reshape(B, Hkv, sg, D)
+    R = _heads_per_group(D)
+    J, gps = Hkv // R, hps // R
+    qr = _q_rows(q.astype(k_pages.dtype), Hkv, G).reshape(B, J, R, sg, D)
     if R > 1:
-        # block-diagonal q: row p*sg + i = query row i in lane group p
-        qr = jnp.einsum("pr,bhid->bhpird", jnp.eye(R, dtype=qr.dtype),
-                        qr).reshape(B, Hkv, R * sg, R * D)
-    packed = (Hkv, k_pages.shape[1], BS // R, R * D)
+        # block-diagonal q: row p*sg + i of group j = query row i of head
+        # j*R + p in that head's lanes, zeros in its neighbours'
+        qr = jnp.einsum("pr,bjpid->bjpird", jnp.eye(R, dtype=qr.dtype), qr)
+    qr = qr.reshape(B, J, R * sg, R * D)
     kernel_fn = functools.partial(_paged_kernel, scale=scale, block_size=BS,
-                                  g=G, hps=hps, pps=pps, pack=R)
+                                  g=G, gps=gps, pps=pps, heads=R, d=D)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,      # block_tables, lengths, trips, pages
-        grid=(B, Hkv // hps),
+        grid=(B, J // gps),
         in_specs=[
-            pl.BlockSpec((1, hps, R * sg, R * D),
+            pl.BlockSpec((1, gps, R * sg, R * D),
                          lambda b, h, *_: (b, h, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, hps, sg, D), lambda b, h, *_: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, gps, R, sg, D),
+                               lambda b, h, *_: (b, h, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, hps, pps * BS // R, R * D), k_pages.dtype),
-            pltpu.VMEM((2, hps, pps * BS // R, R * D), v_pages.dtype),
+            pltpu.VMEM((2, pps * BS, gps * R * D), k_pages.dtype),
+            pltpu.VMEM((2, pps * BS, gps * R * D), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((hps, R * sg, 1), jnp.float32),
-            pltpu.VMEM((hps, R * sg, 1), jnp.float32),
-            pltpu.VMEM((hps, R * sg, R * D), jnp.float32),
+            pltpu.VMEM((gps, R * sg, 1), jnp.float32),
+            pltpu.VMEM((gps, R * sg, 1), jnp.float32),
+            pltpu.VMEM((gps, R * sg, R * D), jnp.float32),
         ],
     )
     with _no_x64():
@@ -535,15 +542,14 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, lengths, trips, pages, *,
             grid_spec=grid_spec,
             # f64 (the global x64 flag) never crosses the kernel boundary
             out_shape=jax.ShapeDtypeStruct(
-                (B, Hkv, sg, D),
+                (B, J, R, sg, D),
                 q.dtype if q.dtype.itemsize <= 4 else jnp.float32),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary"),
                 vmem_limit_bytes=_VMEM_LIMIT),
             interpret=interpret,
             name="_paged_kernel",
-        )(block_tables, lengths, trips, pages, qr, k_pages.reshape(packed),
-          v_pages.reshape(packed))
+        )(block_tables, lengths, trips, pages, qr, k_pages, v_pages)
     out = out.reshape(B, Hkv, S, G, D).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, S, Hq, D).astype(q.dtype)
 
@@ -551,31 +557,32 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, lengths, trips, pages, *,
 def paged_cache_update(k_pages, v_pages, k_new, v_new, block_tables,
                        positions):
     """Scatter S new KV rows per request into the page pool at their (page,
-    slot) targets. k_pages/v_pages: [Hkv, P, BS, D]; k_new/v_new:
-    [B, S, Hkv, D]; `positions` is [B, S] int32 absolute cache positions; rows
+    slot) targets. k_pages/v_pages: [P, BS, Hkv*D]; k_new/v_new:
+    [B, S, Hkv, D], whose rows are the pool's rows as they come (no
+    transpose); `positions` is [B, S] int32 absolute cache positions; rows
     at position >= NB*BS (see `write_positions`) get a poisoned page id so
     XLA's out-of-bounds scatter DROPS them — that is how mixed-length prompts
     padded to a common S skip their padding rows without a mask gather."""
-    BS = k_pages.shape[2]
+    P, BS, W = k_pages.shape
+    B, S, Hkv = k_new.shape[:3]
     NB = block_tables.shape[1]
     pos = jnp.asarray(positions, jnp.int32)
     page = jnp.take_along_axis(block_tables.astype(jnp.int32),
                                jnp.clip(pos // BS, 0, NB - 1), axis=1)
-    page = jnp.where(pos < NB * BS, page, jnp.int32(k_pages.shape[1]))
+    page = jnp.where(pos < NB * BS, page, jnp.int32(P))
     slot = pos % BS
-    # [B, S, Hkv, D] -> [Hkv, B, S, D] so the (page, slot) index arrays land
-    # on the pool's middle axes under one leading full slice
-    k_vals = k_new.astype(k_pages.dtype).transpose(2, 0, 1, 3)
-    v_vals = v_new.astype(v_pages.dtype).transpose(2, 0, 1, 3)
-    k_pages = k_pages.at[:, page, slot].set(k_vals, mode="drop")
-    v_pages = v_pages.at[:, page, slot].set(v_vals, mode="drop")
+    k_pages = k_pages.at[page, slot].set(
+        k_new.astype(k_pages.dtype).reshape(B, S, W), mode="drop")
+    v_pages = v_pages.at[page, slot].set(
+        v_new.astype(v_pages.dtype).reshape(B, S, W), mode="drop")
     # keep the pool head-sharded over tp through the scatter so the step
     # programs' committed outputs preserve the serving-mesh layout (no-op
-    # without a tp mesh — `constrain` drops absent/non-dividing axes)
-    from ...distributed.mesh import constrain
+    # without a tp mesh, or where tp does not divide the heads)
+    from ...distributed.mesh import constrain, mesh_axis_size
 
-    k_pages = constrain(k_pages, ["tp", None, None, None])
-    v_pages = constrain(v_pages, ["tp", None, None, None])
+    if Hkv % mesh_axis_size("tp") == 0:
+        k_pages = constrain(k_pages, [None, None, "tp"])
+        v_pages = constrain(v_pages, [None, None, "tp"])
     return k_pages, v_pages
 
 

@@ -156,7 +156,7 @@ def test_rule_implicit_reshard_flags_undeclared_kinds_only():
 
 
 def test_rule_layout_contract_mismatch_and_rotted_glob():
-    s = _surface(input_specs={"state.w": (), "k_pages.0": ("tp",)},
+    s = _surface(input_specs={"state.w": (), "k_pages.0": (None, None, "tp")},
                  output_specs={"out.0": ()})
     # mismatch on a matched key
     found = list(C._rule_layout_contract(s, {"state.w": (None, "tp")}))
@@ -167,7 +167,7 @@ def test_rule_layout_contract_mismatch_and_rotted_glob():
     assert len(found) == 1 and "matches no input" in found[0].message
     # agreement (including the out.* side) is silent
     assert not list(C._rule_layout_contract(
-        s, {"k_pages.*": ("tp",), "out.0": ()}))
+        s, {"k_pages.*": (None, None, "tp"), "out.0": ()}))
 
 
 def test_rule_replicated_large_buffer_gates_and_strict():
@@ -190,7 +190,7 @@ def test_rule_replicated_large_buffer_gates_and_strict():
 
 
 def test_rule_dead_mesh_axis():
-    s = _surface(input_specs={"k_pages.0": ("tp",)})
+    s = _surface(input_specs={"k_pages.0": (None, None, "tp")})
     found = list(C._rule_dead_mesh_axis({"dp": 2, "tp": 2}, [s]))
     assert [f.rule for f in found] == ["dead-mesh-axis"]
     assert "'dp'" in found[0].message and found[0].severity == WARN

@@ -9,6 +9,9 @@ Covers the ISSUE-1 acceptance surface on CPU (Pallas interpret mode):
   * generate() token-parity between decode_kernel="pallas" and "xla";
   * generate_paged() mixed-length batches == per-request dense generate.
 """
+import math
+import re
+
 import numpy as np
 import pytest
 
@@ -96,19 +99,29 @@ def test_xla_path_has_no_repeated_kv():
             assert tuple(var.aval.shape) != expanded, eqn
 
 
-def test_paged_parity_and_update():
+def _dense_of(pages, tables, Hkv):
+    """[P, BS, Hkv*D] pages through [B, NB] tables -> the head-leading
+    [B, Hkv, NB*BS, D] cache `_naive` reads."""
+    pages, tables = np.asarray(pages), np.asarray(tables)
+    B, NB = tables.shape
+    return pages[tables].reshape(B, NB * pages.shape[1], Hkv, -1).swapaxes(1, 2)
+
+
+@pytest.mark.parametrize("Hq,Hkv,D", [
+    (8, 2, 16),      # 32 lanes a row: the XLA gather under either kernel
+    (8, 8, 16),      # 128 lanes: eight heads share the one lane group
+])
+def test_paged_parity_and_update(Hq, Hkv, D):
     rng = np.random.default_rng(3)
-    B, S, Hq, Hkv, D, BS, P, NB = 2, 1, 8, 2, 16, 16, 12, 4
+    B, S, BS, P, NB = 2, 1, 16, 12, 4
+    assert da.paged_kernel_takes(Hq, Hkv, D) == (Hkv * D % 128 == 0)
     lengths = jnp.asarray([37, 20], jnp.int32)
     tables = jnp.asarray([[3, 7, 1, 9], [5, 2, 0, 0]], jnp.int32)
-    k_pages = _rand((Hkv, P, BS, D), jnp.float32, rng)
-    v_pages = _rand((Hkv, P, BS, D), jnp.float32, rng)
+    k_pages = _rand((P, BS, Hkv * D), jnp.float32, rng)
+    v_pages = _rand((P, BS, Hkv * D), jnp.float32, rng)
     q = _rand((B, S, Hq, D), jnp.float32, rng)
-    kd = np.asarray(k_pages)[:, np.asarray(tables)].reshape(
-        Hkv, B, NB * BS, D).swapaxes(0, 1)
-    vd = np.asarray(v_pages)[:, np.asarray(tables)].reshape(
-        Hkv, B, NB * BS, D).swapaxes(0, 1)
-    ref = _naive(np.asarray(q), kd, vd, np.asarray(lengths))
+    ref = _naive(np.asarray(q), _dense_of(k_pages, tables, Hkv),
+                 _dense_of(v_pages, tables, Hkv), np.asarray(lengths))
     for kern in ("xla", "pallas"):
         got = np.asarray(da.paged_decode_attention(q, k_pages, v_pages,
                                                    tables, lengths,
@@ -123,10 +136,11 @@ def test_paged_parity_and_update():
     pos = da.write_positions(lengths, 2, valid=valid, capacity=NB * BS)
     k2, _ = da.paged_cache_update(k_pages, v_pages, k_new, v_new, tables, pos)
     k2 = np.asarray(k2)
-    np.testing.assert_allclose(k2[:, 1, 5], np.asarray(k_new)[0, 0])  # 37 -> p1s5
-    np.testing.assert_allclose(k2[:, 1, 6], np.asarray(k_new)[0, 1])
-    np.testing.assert_allclose(k2[:, 2, 4], np.asarray(k_new)[1, 0])  # 20 -> p2s4
-    changed = (np.abs(k2 - np.asarray(k_pages)).max(axis=(0, 2, 3)) > 0)
+    rows = np.asarray(k_new).reshape(B, 2, Hkv * D)
+    np.testing.assert_allclose(k2[1, 5], rows[0, 0])            # 37 -> p1s5
+    np.testing.assert_allclose(k2[1, 6], rows[0, 1])
+    np.testing.assert_allclose(k2[2, 4], rows[1, 0])            # 20 -> p2s4
+    changed = (np.abs(k2 - np.asarray(k_pages)).max(axis=(1, 2)) > 0)
     assert changed.sum() == 2                   # pages 1 and 2 only
 
 
@@ -148,8 +162,8 @@ def _paged_batch(S, G, D, dtype, rng, Hkv=2):
         owned.append(tables[b, :n].copy())
     dt = jnp.dtype(dtype)
     q = _rand((6, S, Hkv * G, D), dt, rng)
-    k_pages = _rand((Hkv, P, BS, D), dt, rng)
-    v_pages = _rand((Hkv, P, BS, D), dt, rng)
+    k_pages = _rand((P, BS, Hkv * D), dt, rng)
+    v_pages = _rand((P, BS, Hkv * D), dt, rng)
     return (q, k_pages, v_pages, jnp.asarray(tables),
             jnp.asarray(lengths, jnp.int32),
             jnp.asarray(new_rows, jnp.int32)), owned
@@ -158,28 +172,36 @@ def _paged_batch(S, G, D, dtype, rng, Hkv=2):
 @pytest.mark.parametrize("S,G,D,dtype,Hkv,budget", [
     (1, 1, 64, "bfloat16", 2, None),
     (1, 4, 128, "float32", 2, None),
-    (1, 1, 64, "float32", 3, 2),      # 3 heads, room for 2: one a step
-    (3, 4, 64, "bfloat16", 6, 4),     # 6 heads, room for 4: three a step
+    (1, 1, 64, "float32", 6, 2),      # 3 lane groups, room for 2: one a step
+    (3, 4, 64, "bfloat16", 12, 4),    # 6 lane groups, room for 4: 3 a step
     (3, 1, 128, "float32", 2, None),
     (128, 1, 64, "bfloat16", 2, None),
     (128, 4, 128, "bfloat16", 1, None),
+    (3, 2, 32, "float32", 4, None),   # four heads share the lane group
+    (1, 1, 256, "float32", 2, 1),     # a head of two lane tiles, one a step
 ])
 def test_paged_kernel_follows_lengths(S, G, D, dtype, Hkv, budget,
                                       monkeypatch):
     """The length-bounded paged kernel against `decode_attention_xla` through
     the gather path: decode, verify and prefill-chunk widths, grouped heads,
-    both head sizes (D = 64 runs the lane-packed form), both pool dtypes,
-    lengths on every side of a page and a block edge, idle slots beside
-    live ones, and a head count the VMEM budget does not divide."""
+    head sizes on both sides of a lane tile (D < 128: the heads of one
+    128-lane group are multiplied at once), both pool dtypes, lengths on
+    every side of a page and a block edge, idle slots beside live ones, and
+    a count of lane groups the VMEM budget does not divide (the step then
+    DMAs a lane slice of each page, not the whole page)."""
     rng = np.random.default_rng(11)
     args, _ = _paged_batch(S, G, D, dtype, rng, Hkv)
+    assert da.paged_kernel_takes(Hkv * G, Hkv, D)
     if budget is not None:
-        per_head = da._VMEM_BUDGET // da.paged_tiling(
-            1 << 10, 3, 128, D, S * G, jnp.dtype(dtype).itemsize)[0]
-        monkeypatch.setattr(da, "_VMEM_BUDGET", budget * per_head)
-        hps, pps = da.paged_tiling(Hkv, 3, 128, D, S * G,
-                                   jnp.dtype(dtype).itemsize)
-        assert Hkv % budget and hps < budget and Hkv % hps == 0 and pps == 2
+        R, isz = da._heads_per_group(D), jnp.dtype(dtype).itemsize
+        fit = max(n for n in range(1, 64) if da.paged_tiling(
+            n * R, 3, 128, D, S * G, isz)[0] == n * R)
+        assert fit >= 8
+        monkeypatch.setattr(da, "_VMEM_BUDGET",
+                            budget * (da._VMEM_BUDGET // fit))
+        hps, pps = da.paged_tiling(Hkv, 3, 128, D, S * G, isz)
+        assert hps < Hkv and hps <= budget * R and Hkv % hps == 0
+        assert hps % R == 0 and pps == 2
     *dense, new_rows = args
     got = np.asarray(da.paged_decode_attention(*dense, kernel="pallas",
                                                new_rows=new_rows), np.float32)
@@ -189,6 +211,30 @@ def test_paged_kernel_follows_lengths(S, G, D, dtype, Hkv, budget,
     tol = 2e-5 if dtype == "float32" else 3e-2
     np.testing.assert_allclose(got[live], ref[live], atol=tol, rtol=tol)
     assert np.isfinite(got).all()          # idle slots: finite, ignored
+
+
+def test_paged_kernel_keeps_lane_neighbours_apart():
+    """Two heads of 64 share a 128-lane group and each slot has its own
+    length: a head's output is its own K and V alone. The kernel's output
+    against a loop-and-numpy reference, and again with the OTHER head's
+    lanes replaced by NaN-free but huge numbers in every page: head 0's
+    rows must not move (the block-diagonal q leaves the neighbour's lanes
+    out of the scores, and its share of p.v is never read)."""
+    rng = np.random.default_rng(13)
+    (q, k_pages, v_pages, tables, lengths, new_rows), _ = \
+        _paged_batch(3, 2, 64, "float32", rng)
+    got = np.asarray(da.paged_decode_attention(
+        q, k_pages, v_pages, tables, lengths, new_rows=new_rows))
+    ref = _naive(np.asarray(q), _dense_of(k_pages, tables, 2),
+                 _dense_of(v_pages, tables, 2), np.asarray(lengths))
+    live = np.asarray(new_rows) > 0
+    np.testing.assert_allclose(got[live], ref[live], atol=2e-5, rtol=2e-5)
+    loud_k = k_pages.at[:, :, 64:].multiply(50.0)
+    loud_v = v_pages.at[:, :, 64:].add(1e4)
+    loud = np.asarray(da.paged_decode_attention(
+        q, loud_k, loud_v, tables, lengths, new_rows=new_rows))
+    np.testing.assert_array_equal(loud[:, :, :2], got[:, :, :2])  # head 0
+    assert np.abs(loud[live][:, :, 2:] - got[live][:, :, 2:]).min() > 1e3
 
 
 def test_paged_kernel_reads_nothing_past_a_length():
@@ -207,11 +253,11 @@ def test_paged_kernel_reads_nothing_past_a_length():
         valid[1, 1:] = False
         new_rows = da.valid_new_rows(jnp.asarray(valid), S)
         assert list(np.asarray(new_rows)) == [S, 1, S, S, 0, 0]
-        clean = np.ones(k_pages.shape[1], bool)
+        clean = np.ones(k_pages.shape[0], bool)
         for b, pages in enumerate(owned):
             rows = int(lengths[b]) + int(new_rows[b])
             clean[pages[:-(-rows // 128)]] = False
-        poison = jnp.asarray(clean)[None, :, None, None]
+        poison = jnp.asarray(clean)[:, None, None]
         bad_k = jnp.where(poison, jnp.nan, k_pages)
         bad_v = jnp.where(poison, jnp.nan, v_pages)
         want = np.asarray(da.paged_decode_attention(
@@ -258,7 +304,7 @@ def test_paged_kernel_compiles_for_the_v5e(v5e_chip, monkeypatch, B, S, Hq,
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
 
-    pool = arg((Hkv, P, BS, D), jnp.bfloat16)
+    pool = arg((P, BS, Hkv * D), jnp.bfloat16)
     # the suite's "highest" matmul precision is for comparisons with numpy;
     # the chip runs the default, and Mosaic has no f32 pass over bf16 operands
     with jax.default_matmul_precision("default"):
@@ -269,6 +315,72 @@ def test_paged_kernel_compiles_for_the_v5e(v5e_chip, monkeypatch, B, S, Hq,
             arg((B, NB), jnp.int32), arg((B,), jnp.int32),
             arg((B,), jnp.int32)).compile()
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+def _pool_sized(hlo, pool, dtype="bf16"):
+    """(opcode, line) of every instruction of a compiled program whose
+    result is an array of the pool's dtype and element count, in whatever
+    shape (XLA flattens [P, BS, W] to [P*BS, W] around a scatter)."""
+    out = []
+    for line in hlo.splitlines():
+        m = re.search(r"= (\w+)\[([0-9,]+)\]\S* ([\w-]+)\(", line)
+        if (m and m.group(1) == dtype and math.prod(
+                int(n) for n in m.group(2).split(",")) == math.prod(pool)):
+            out.append((m.group(3), line.strip()))
+    return out
+
+
+@pytest.mark.parametrize("B,S,Hq,Hkv,D,BS,P,NB", [
+    (32, 1, 20, 20, 64, 32, 640, 32),      # gpt2-large decode_step
+    (32, 128, 20, 20, 64, 32, 640, 32),    # gpt2-large prefill_chunk
+    (32, 3, 10, 10, 64, 32, 640, 32),      # verify_step on a tp=2 shard
+    (8, 64, 32, 8, 128, 16, 2048, 64),     # llama GQA, D=128
+])
+def test_step_programs_hold_no_copy_of_the_pool(v5e_chip, monkeypatch, B, S,
+                                                Hq, Hkv, D, BS, P, NB):
+    """ONE layout from the writer through the loop's carry to the kernel:
+    the row writer and the paged kernel of one layer, inside a `lax.scan`
+    that carries donated pools (the shape of `generate_paged` and of the
+    decode tick), compiled for the described v5e. Nothing of the pool's
+    size is left in the program but the pools themselves, views of them
+    (bitcast), the scatter's in-place update and the fusion XLA wraps it in:
+    no copy, no transpose, no prefetch of a pool (a pool small enough for
+    the compiler to park in VMEM would be: the LLaMA case holds 2,048 pages
+    for that), and one Mosaic call."""
+    import jax
+
+    monkeypatch.setattr(da, "_interpret", lambda: False)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def program(kp, vp, q, k_new, v_new, tbl, ln):
+        def step(carry, _):
+            kp, vp, ln = carry
+            kp, vp = da.paged_cache_update(kp, vp, k_new, v_new, tbl,
+                                           da.write_positions(ln, S))
+            out = da._paged_decode_attention_impl(
+                q, kp, vp, tbl, ln, jnp.full((B,), S, jnp.int32))
+            return (kp, vp, ln + S), out[:, -1]
+
+        (kp, vp, _), outs = jax.lax.scan(step, (kp, vp, ln), None, length=2)
+        return kp, vp, outs
+
+    pool = (P, BS, Hkv * D)
+    rows = arg((B, S, Hkv, D), jnp.bfloat16)
+    with jax.default_matmul_precision("default"):
+        hlo = jax.jit(program, donate_argnums=(0, 1)).lower(
+            arg(pool, jnp.bfloat16), arg(pool, jnp.bfloat16),
+            arg((B, S, Hq, D), jnp.bfloat16), rows, rows,
+            arg((B, NB), jnp.int32), arg((B,), jnp.int32)).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    found = _pool_sized(hlo, pool)
+    assert {"parameter", "scatter"} <= {op for op, _ in found}
+    stray = [line for op, line in found if op not in (
+        "parameter", "get-tuple-element", "bitcast", "scatter", "fusion")]
+    assert not stray, stray
+    # the fusions are the scatters' own: in place, nothing else inside
+    assert all("kind=kCustom" in line for op, line in found if op == "fusion")
 
 
 def test_no_x64_leak_into_pallas_calls():
@@ -295,7 +407,7 @@ def test_no_x64_leak_into_pallas_calls():
     k = jnp.zeros((B, Hkv, T, D), jnp.float32)
     ln = jnp.zeros((B,), jnp.int64)
     tables = jnp.zeros((B, 4), jnp.int64)
-    kp = jnp.zeros((Hkv, 8, 16, D), jnp.float32)
+    kp = jnp.zeros((8, 16, Hq * D), jnp.float32)   # 8 kv heads: 128 lanes
     for jx in (
         jax.make_jaxpr(lambda q, k, ln: da.decode_attention(q, k, k, ln))(
             q, k, ln),

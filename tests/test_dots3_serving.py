@@ -215,7 +215,7 @@ def test_one_cache_spec_is_read_in_one_place():
         layers, heads, dim = spec
         kv = PagedKVCache.for_model(model, block_size=8, num_blocks=4)
         assert kv.signature() == (layers, heads, dim, 8, 4, "bfloat16")
-        assert kv.k_pages[0].shape == (heads, 4, 8, dim)
+        assert kv.k_pages[0].shape == (4, 8, heads * dim)
         assert PagedKVCache(*spec, block_size=8,
                             num_blocks=4).signature() == kv.signature()
     mixed = CacheSpec((LayerCache("latent", row=20, index_row=8),

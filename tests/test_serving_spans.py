@@ -448,7 +448,7 @@ def test_walked_rows_are_the_kernels_own_trip_counts(monkeypatch):
                     **static)
 
     monkeypatch.setattr(da, "_paged_pallas", spy)
-    pool = jnp.zeros((Hkv, 2, BS, D), jnp.bfloat16)
+    pool = jnp.zeros((2, BS, Hkv * D), jnp.bfloat16)
     tables = jnp.zeros((4, NB), jnp.int32)
     lengths = np.array([0, 255, 256, 300])
     T = 3
