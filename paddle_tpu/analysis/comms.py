@@ -23,7 +23,7 @@ Two halves, five rules:
 * **Collective inventory** — every ``all-gather`` / ``all-reduce`` /
   ``reduce-scatter`` / ``all-to-all`` / ``collective-permute`` in the
   compiled module, with shape, dtype, replica-group size and estimated
-  bytes-on-wire (per participating chip, ring formulas — see docs/PERF.md).
+  bytes-on-wire (per participating chip, ring formulas: `bytes_on_wire`).
   Ops inside the decode scan (``/while/`` in their op_name metadata) count
   once per scanned step. Rules: ``implicit-reshard`` (HIGH — a collective
   kind no declared SpecLayout transition explains), ``comms-over-budget``
@@ -146,7 +146,7 @@ def _hlo_result_bytes(result: str):
 
 def bytes_on_wire(kind, buffer_bytes, group_size) -> int:
     """Bytes one participating chip puts on the ICI per execution of one
-    collective, ring algorithms (the formulas docs/PERF.md derives):
+    collective, ring algorithms (docs/ANALYSIS.md tables them):
 
     * all-gather (printed result = the full gathered buffer G):  G(n-1)/n
     * all-reduce (printed result = the full buffer B):          2B(n-1)/n

@@ -14,8 +14,7 @@ the simpler ``io_utils.save`` path cannot give:
    them deleted. *serialize* (npz write + fsync) and *commit* (manifest +
    atomic rename + retention) then run on a background writer thread,
    overlapped with the next steps' compute. Only the snapshot cost lands on
-   the training loop; bench.py's ``checkpoint_overhead`` leg gates it < 2%
-   of the GPT-smoke step time.
+   the training loop (its share of a step on the chip: not measured).
 
 2. **Crash-atomicity.** Each checkpoint is a step-numbered directory,
    assembled under a ``.tmp`` name and renamed into place only after every

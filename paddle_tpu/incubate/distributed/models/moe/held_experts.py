@@ -23,12 +23,14 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .....nn import initializer as I
 from .....nn.layer import Layer
 from .....tensor import Tensor
 
-__all__ = ["HeldExperts", "route_sigmoid", "held_experts_ffn", "tile_for"]
+__all__ = ["HeldExperts", "route_sigmoid", "held_experts_ffn", "tile_for",
+           "launch_counts"]
 
 
 def route_sigmoid(y, router, bias, top_k, *, norm_topk=True, scale=1.0):
@@ -112,6 +114,19 @@ def held_experts_ffn(y, chosen, weights, gate_up, down, *, first, valid=None,
                                  "elsewhere": elsewhere.astype(jnp.int32),
                                  "rows_issued": (tile_ends[-1] * tile).astype(
                                      jnp.int32)}
+
+
+def launch_counts(expert_tokens, elsewhere, rows_issued):
+    """One launch's counts of `held_experts_ffn`, read back and stacked a
+    layer (`expert_tokens` [layers, held]), under the tick ledger's names:
+    the rows of the tiles walked, the assignments the held experts got (in
+    all, and each expert's over the layers), and the assignments of real
+    tokens to experts held elsewhere."""
+    per = np.asarray(expert_tokens).sum(axis=0)
+    return {"moe_expert_tokens": [int(n) for n in per],
+            "moe_rows_useful": int(per.sum()),
+            "moe_rows_issued": int(np.sum(rows_issued)),
+            "moe_assignments_elsewhere": int(np.sum(elsewhere))}
 
 
 class HeldExperts(Layer):

@@ -16,93 +16,17 @@ finished-but-retained requests when the pool runs dry.
 """
 from __future__ import annotations
 
-import dataclasses
 import itertools
 import math
 
 import numpy as np
 
 from ..analysis.lockwitness import make_rlock
+# the format lives below the models that declare it; re-exported here
+from ..nn.functional.cached_attention import CacheSpec, LayerCache
 
 __all__ = ["CacheOutOfBlocks", "BlockAllocator", "PagedKVCache",
            "LayerCache", "CacheSpec"]
-
-
-@dataclasses.dataclass(frozen=True)
-class LayerCache:
-    """What one layer keeps of a token, and for how long.
-
-    kind "kv": a K and a V row of [heads, head_dim] each, in pages a request
-    reaches through its block table (GPT, LLaMA). kind "latent": ONE row of
-    `row` numbers shared by all heads (latent attention: the compressed
-    key/value beside the rotary key) and, where `index_row` > 0, the
-    indexer's key of that many numbers in a second array of the same pages.
-    `window` None keeps every row, in pages; a number keeps a slot's last
-    `window` rows and what one launch writes, in a ring of its own per slot
-    that needs no table: position t lives in ring row t mod the ring."""
-    kind: str = "kv"
-    heads: int = 0
-    head_dim: int = 0
-    row: int = 0
-    index_row: int = 0
-    window: int | None = None
-
-    def row_numbers(self) -> int:
-        """Numbers a token leaves in this layer."""
-        if self.kind == "kv":
-            return 2 * self.heads * self.head_dim
-        return self.row + self.index_row
-
-
-@dataclasses.dataclass(frozen=True)
-class CacheSpec:
-    """A model's decode cache: one `LayerCache` a layer. The ONE object the
-    pool is built from (`PagedKVCache.for_model`) and the residency plan
-    counts (`analysis/hbm.py`). A model whose layers are all of kind "kv"
-    and alike unpacks as the old triple: `layers, kv_heads, head_dim = spec`."""
-    layers: tuple
-
-    @classmethod
-    def uniform(cls, num_layers, num_kv_heads, head_dim):
-        one = LayerCache("kv", int(num_kv_heads), int(head_dim))
-        return cls((one,) * int(num_layers))
-
-    def is_uniform_kv(self) -> bool:
-        first = self.layers[0]
-        return first.kind == "kv" and first.window is None and all(
-            c == first for c in self.layers)
-
-    def kv_triple(self):
-        if not self.is_uniform_kv():
-            raise TypeError("this cache is not (layers, kv_heads, head_dim): "
-                            f"{sorted({c.kind for c in self.layers})} rows")
-        first = self.layers[0]
-        return len(self.layers), first.heads, first.head_dim
-
-    def __iter__(self):
-        return iter(self.kv_triple())
-
-    def signature_head(self):
-        """The model's part of a pool signature: the old (layers, kv_heads,
-        head_dim) where that says it all, else (spec, 0, 0)."""
-        return self.kv_triple() if self.is_uniform_kv() else (self, 0, 0)
-
-    def ring_rows(self, cache, block_size, launch_rows) -> int:
-        """Rows of a window layer's ring: the window, one launch's rows and
-        a page to spare, in whole pages."""
-        pages = -(-(cache.window + int(launch_rows)) // block_size) + 1
-        return pages * int(block_size)
-
-    def block_bytes(self, block_size, itemsize) -> int:
-        """Bytes one page costs over the layers that keep every row."""
-        return int(block_size) * int(itemsize) * sum(
-            c.row_numbers() for c in self.layers if c.window is None)
-
-    def window_bytes(self, block_size, itemsize, slots, launch_rows) -> int:
-        """Bytes of the window layers' rings, all slots."""
-        return int(slots) * int(itemsize) * sum(
-            c.row_numbers() * self.ring_rows(c, block_size, launch_rows)
-            for c in self.layers if c.window is not None)
 
 
 class CacheOutOfBlocks(RuntimeError):
@@ -180,8 +104,8 @@ class PagedKVCache:
             spec = CacheSpec.uniform(num_layers, num_kv_heads, head_dim)
         self.spec = spec
         self.num_layers = len(spec.layers)
-        # the old triple where the layers are alike K,V rows, else 0: the
-        # debug `gather` and the tp head-sharding read them
+        # (kv_heads, head_dim) where the layers are alike K,V rows, else 0:
+        # the debug `gather` and the tp head-sharding read them
         _, self.num_kv_heads, self.head_dim = spec.signature_head()
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
@@ -233,7 +157,7 @@ class PagedKVCache:
     def for_model(cls, model, **kwargs):
         """The pool of `model._decode_cache_spec()`: the one place that
         spec is read for a pool."""
-        return cls(spec=as_cache_spec(model._decode_cache_spec()), **kwargs)
+        return cls(spec=model._decode_cache_spec(), **kwargs)
 
     def _layer_arrays(self, cache):
         """(first, second) array of one layer: K and V pages [P, BS, Hkv*D],
@@ -648,10 +572,3 @@ class PagedKVCache:
 
             return _dense(self.k_pages[layer]), _dense(self.v_pages[layer])
 
-
-def as_cache_spec(spec) -> CacheSpec:
-    """A model's `_decode_cache_spec()` as a CacheSpec: the object itself,
-    or the old (layers, kv_heads, head_dim) triple."""
-    if isinstance(spec, CacheSpec):
-        return spec
-    return CacheSpec.uniform(*(int(x) for x in spec))

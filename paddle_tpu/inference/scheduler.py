@@ -21,7 +21,7 @@ of S slots:
   most `prefill_token_budget` prompt tokens (across slots) in ONE
   `prefill_chunk` launch, so a 10k-token prompt never stalls in-flight
   decoders for more than a chunk's worth of compute (this is what bounds
-  decode p99 — docs/PERF.md).
+  decode p99: docs/DEPLOYMENT.md, `prefill_token_budget`).
 * decode — all decoding slots advance `decode_steps` tokens in ONE
   `decode_step` launch (a compiled scan: the host syncs per tick, not per
   token).
@@ -479,11 +479,8 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                 and "num_blocks" not in kwargs):
             from ..analysis.hbm import params_bytes_of, plan_kv_pool
 
-            from .kv_cache import as_cache_spec
-
             sizing = plan_kv_pool(
-                self.hbm_budget,
-                cache_spec=as_cache_spec(model._decode_cache_spec()),
+                self.hbm_budget, cache_spec=model._decode_cache_spec(),
                 block_size=kwargs.get("block_size", 32),
                 slots=self.max_slots, max_seq_len=max_seq_len,
                 params_bytes=params_bytes_of(model),
@@ -1544,33 +1541,24 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                    for a in arrays]
             return out, clock() - t0
 
-    def _model_stats(self):
-        """The counts the model returned beside the launch's tokens, still
-        on the device ({} for a model that counts nothing): they are read
-        back in the tokens' wait, not in one of their own."""
-        return dict((self._last_launch or {}).get("stats") or {})
-
-    def _model_counts(self, program, stats, positions, steps=1, holding=0):
-        """The launch's counts under the ledger's `MODEL_KEYS`: the expert
-        layer's from the device (`stats`, read back with the tokens), the
-        attention's rows by the model's own arithmetic from the positions
-        of the launch's real queries and the `holding` slots that hold a
-        chunk; and `issued_positions`, where the model says how many
-        positions its program carried (a model that walks only the slots
-        with a chunk issues fewer than slots x chunk)."""
-        counts = {}
-        if "moe_expert_tokens" in stats:
-            per = np.asarray(stats["moe_expert_tokens"]).sum(axis=0)
-            counts.update(
-                moe_expert_tokens=[int(n) for n in per],
-                moe_rows_useful=int(per.sum()),
-                moe_rows_issued=int(np.sum(stats["moe_rows_issued"])),
-                moe_assignments_elsewhere=int(np.sum(stats["moe_elsewhere"])))
-        rows = getattr(self.model, "_decode_row_counts", None)
-        if rows is not None:
-            counts.update(rows(program, np.asarray(positions, np.int64),
-                               self.kv_cache, self.table_width, steps,
-                               holding))
+    def _counts_of(self, program, picks, t0, stats, positions, **kw):
+        """What the model says the launch adds to the ledger (the contract:
+        models/generation.py). A key under one of the ledger's own names is
+        the model's fault against that contract: it is raised HERE, where
+        it fails the launch's requests with the ValueError that names the
+        key (None says so), and not inside `_util_launch`'s guard, where it
+        would only be telemetry that is missing."""
+        try:
+            counts = self.model._launch_counts(
+                program, stats, positions, self.kv_cache, self.table_width,
+                **kw)
+            self._ledger.check_counts(
+                k for k in counts if k != "issued_positions")
+        except ThreadDeath:
+            raise
+        except Exception as e:
+            self._fail_picks(picks, e, program, t0)
+            return None
         return counts
 
     def _util_launch(self, program, wait_s, total_units, slot_units,
@@ -1848,12 +1836,19 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             return
         self.breaker.record_success()
         self.metrics.inc("prefill_ticks")
-        stats = self._model_stats()
+        # the model's counts of the launch come back in the tokens' wait,
+        # and the model says what they add to the ledger (the contract:
+        # models/generation.py); a model that walks only the slots with a
+        # chunk issues fewer positions than slots x chunk
+        stats = self._last_launch["stats"]
         (tk, *got), wait_s = self._read_back("prefill", tk, *stats.values())
-        counts = self._model_counts(
-            "prefill_chunk", dict(zip(stats, got)),
+        counts = self._counts_of(
+            "prefill_chunk", [(i, s) for i, s, _ in picks], t0,
+            dict(zip(stats, got)),
             np.concatenate([np.arange(s.pos, s.pos + take)
                             for _, s, take in picks]), holding=len(picks))
+        if counts is None:
+            return
         issued = counts.pop("issued_positions", S * C)
         useful = int(sum(t for _, _, t in picks))
         self._span_each(reqs, "prefill_chunk", t0, self.tracer.now_us(),
@@ -1945,12 +1940,14 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             return
         self.breaker.record_success()
         self.metrics.inc("decode_ticks")
-        stats = self._model_stats()
+        stats = self._last_launch["stats"]
         (toks, *got), wait_s = self._read_back("decode", toks,
                                                *stats.values())
-        counts = self._model_counts(
-            "decode_step", dict(zip(stats, got)),
+        counts = self._counts_of(
+            "decode_step", dec, t0, dict(zip(stats, got)),
             (lengths[active][:, None] + np.arange(T)).reshape(-1), steps=T)
+        if counts is None:
+            return
         counts.pop("issued_positions", None)    # a tick carries every slot
         self._span_each(reqs, "decode_step", t0, self.tracer.now_us(),
                         slots=len(dec), steps=T)

@@ -3,8 +3,8 @@
 Reference role: the AnalysisPredictor deployment stack (paddle/fluid/
 inference/, ~90K C++) + Paddle Serving's request batching. TPU-native shape:
 one resident compiled program per batch bucket; a collector thread coalesces
-concurrent requests into a single device launch (decode/serving throughput on
-TPU is batch-bound — see docs/PERF.md serving numbers), then splits results.
+concurrent requests into a single device launch (decode throughput on TPU is
+bound by the batch), then splits results.
 The HTTP front end is a stdlib ThreadingHTTPServer speaking npz, so a client
 needs nothing but numpy.
 
@@ -607,9 +607,9 @@ class GenerateBatchingPredictor(BatchingPredictor):
                  block_size=32, num_blocks=64, faults=None, admission=None,
                  breaker=None, max_retries=1, max_defers=8, max_restarts=5,
                  tracer=None, registry=None, component=None, launch_rows=1):
-        from .kv_cache import PagedKVCache, as_cache_spec
+        from .kv_cache import PagedKVCache
 
-        spec = as_cache_spec(model._decode_cache_spec())
+        spec = model._decode_cache_spec()
         if kv_cache is None:
             # `launch_rows`: the most rows one launch writes for a slot,
             # which sizes the ring of a layer that keeps only a window

@@ -1,8 +1,8 @@
 """Speculative decoding: pluggable drafters + the single-stream driver.
 
 Single-stream decode is the serving shape that wastes the chip: each step
-launches one token of work, so b1 runs at dispatch speed, not math speed
-(~625 vs ~3.5k tok/s — docs/PERF.md). Speculative decoding (Leviathan et
+launches one token of work, so b1 runs at dispatch speed, not math
+speed. Speculative decoding (Leviathan et
 al., "Fast Inference from Transformers via Speculative Decoding", ICML
 2023; Stern et al., NeurIPS 2018) converts the idle width into useful
 tokens: a cheap DRAFTER proposes K tokens, and the target model scores all
@@ -40,7 +40,7 @@ Shipped drafters:
   DraftModelDrafter with draft_model == target. The draft only attends the
   last `window` tokens, so a draft launch costs O(window) attention
   instead of O(full prefix) — profitable once the accepted-token value
-  beats the extra small launches (cost model in docs/PERF.md).
+  beats the extra small launches (cost model: docs/DEPLOYMENT.md).
 
 The continuous scheduler (scheduler.py, ``spec_k=`` knob) drives the same
 verify program at S slots; this module's `speculative_generate` is the
@@ -176,7 +176,7 @@ class SpecStats:
     """Per-run speculation accounting. wasted = drafted - accepted is the
     draft compute (and verify width) spent on rejected tokens; the
     acceptance rate is THE number that decides whether speculation pays
-    (docs/PERF.md cost model)."""
+    (docs/DEPLOYMENT.md, "Cost model")."""
 
     __slots__ = ("drafted", "accepted", "launches", "emitted")
 

@@ -19,7 +19,7 @@ o_i * sigmoid(u W_g)_i, before the output projection.
                   row of the same pages.
   window layers   their own ranks and head sizes, no indexer; token t
                   attends t - window < s <= t, so a slot keeps a ring of the
-                  last rows and no pages (`inference.kv_cache.LayerCache`).
+                  last rows and no pages (`LayerCache`, under `nn/functional`).
 
 Two forms of one attention. Many queries (a prefill chunk, a whole
 sequence): the selection is a MASK inside blocked attention over the slot's
@@ -48,9 +48,12 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..incubate.distributed.models.moe.held_experts import HeldExperts
-from ..inference.kv_cache import CacheSpec, LayerCache
+from ..incubate.distributed.models.moe.held_experts import (
+    HeldExperts,
+    launch_counts,
+)
 from ..nn import initializer as I
+from ..nn.functional.cached_attention import AttnCache, CacheSpec, LayerCache
 from ..nn.layer import Layer
 from ..nn.layer_common import LayerList
 from ..tensor import Tensor
@@ -270,8 +273,8 @@ class Dots3Attention(Layer):
     """Latent attention of one kind (`full`: with the indexer; else the
     window). `forward(u, pos, valid, cache)` -> (out, cache): u [B, C, h]
     normed, pos [B, C] int32, valid [B, C] bool (rows that are no token
-    write nothing), cache None (the keys are this call's own rows) or the
-    layer's pair of pool arrays with the block tables."""
+    write nothing), cache None (the keys are this call's own rows) or an
+    `AttnCache`: the layer's pair of pool arrays with the block tables."""
 
     def __init__(self, config: Dots3Config, full: bool):
         super().__init__()
@@ -423,7 +426,8 @@ class Dots3Attention(Layer):
     def _paged(self, q_nope, q_rope, row, index, pos, valid, scale, cache):
         """A full layer over its pages: the new rows are written in place,
         then read back with the context through the block tables."""
-        rows_pool, index_pool, tables = cache
+        rows_pool, index_pool = cache.first, cache.second
+        tables = cache.tables
         pages, block, width = rows_pool.shape
         batch, chunk = pos.shape
         span = tables.shape[1] * block
@@ -470,7 +474,7 @@ class Dots3Attention(Layer):
         None: row b is slot b); the ring holds the window and one launch's
         rows, so what a query of this launch needs is never overwritten by
         it."""
-        ring, _, _ = cache
+        ring = cache.first
         count, length, width = ring.shape
         batch, chunk = pos.shape
         if chunk + self.config.sliding_window_size - 1 > length:
@@ -621,7 +625,8 @@ class Dots3Model(Layer):
         x = self.embed._value[ids].astype(jnp.float32)
         new_pools, counts = [], []
         for i, blk in enumerate(self.layers):
-            cache = None if pools is None else pools[i] + (tables,)
+            cache = (None if pools is None
+                     else AttnCache(*pools[i], tables=tables))
             x, cache, got = blk(x, pos, valid, cache, slots)
             new_pools.append(cache)
             if got is not None:
@@ -701,10 +706,11 @@ class Dots3ForCausalLM(Layer, GenerationMixin):
     def _decode_validate(self, prompt_len, max_new_tokens):
         pass    # rotary positions; the pool bounds the length
 
-    def _decode_row_counts(self, program, positions, kv_cache, table_width,
-                           steps=1, holding=0):
-        """Attention's rows in one launch, by arithmetic (the tick ledger's
-        `attn_rows_needed`, `attn_rows_read`, `indexer_rows_scored`), and
+    def _launch_counts(self, program, stats, positions, kv_cache,
+                       table_width, steps=1, holding=0):
+        """The expert layers' counts of the launch (`stats`, off the device)
+        under the tick ledger's names, and attention's rows by arithmetic
+        (`attn_rows_needed`, `attn_rows_read`, `indexer_rows_scored`) beside
         the positions the launch issued. `positions`: the position of every
         real query of the launch; `holding`: the slots that hold a chunk.
         All three count (query, cache row) pairs. Needed: over the layers,
@@ -719,6 +725,11 @@ class Dots3ForCausalLM(Layer, GenerationMixin):
         import numpy as np
 
         c = self.config
+        counts = {}
+        if "moe_expert_tokens" in stats:
+            counts = launch_counts(stats["moe_expert_tokens"],
+                                   stats["moe_elsewhere"],
+                                   stats["moe_rows_issued"])
         context = np.asarray(positions, np.int64) + 1
         span = table_width * kv_cache.block_size
         if program == "decode_step":
@@ -738,9 +749,10 @@ class Dots3ForCausalLM(Layer, GenerationMixin):
                 needed += int(np.minimum(context,
                                          c.sliding_window_size).sum())
                 read += launches * rows * width * pool.shape[1]
-        return {"attn_rows_needed": needed, "attn_rows_read": read,
-                "indexer_rows_scored": scored,
-                "issued_positions": launches * rows * width}
+        counts.update(attn_rows_needed=needed, attn_rows_read=read,
+                      indexer_rows_scored=scored,
+                      issued_positions=launches * rows * width)
+        return counts
 
 
 def dots3_tiny(**over):

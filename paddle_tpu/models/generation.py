@@ -1,4 +1,5 @@
-"""Shared autoregressive decoding for the causal-LM models (GPT, LLaMA).
+"""Shared autoregressive decoding for the causal-LM models (GPT, LLaMA,
+dots3), and the contract a model keeps to be served.
 
 TPU-native shape: prefill is one compiled program; the ENTIRE decode loop is
 a second compiled program (`lax.scan` over steps) — no per-token host
@@ -19,17 +20,50 @@ or "pallas" (split-KV flash-decode kernel). Dense defaults to "xla" (the
 measured serving baseline); paged defaults to "pallas" (the XLA paged path
 re-gathers the pool into a dense cache every step).
 
-Models plug in via three hooks:
-  _decode_layer()      -> Layer whose functional_call accepts
+THE CONTRACT between a model and the serving stack. A causal model is
+served (`inference/`: the paged pool, the continuous scheduler, speculation,
+the prefix cache) by mixing in `GenerationMixin` and giving:
+
+  _decode_layer()      -> the Layer whose `functional_call` accepts
                           (ids, caches=, cache_offset=, decode_kernel=,
-                          paged_tables=, cache_valid=) and returns
-                          (logits, new_caches)
-  _decode_cache_spec() -> inference.kv_cache.CacheSpec: what each layer
-                          keeps of a token (K,V rows of [kv_heads, head_dim],
-                          or a latent row) and for how long (all rows, or a
-                          window); `CacheSpec.uniform(layers, kv_heads,
-                          head_dim)` for layers of like K,V rows
+                          paged_tables=, cache_valid=) and ALWAYS returns
+                          `(logits, new_caches, counts)`: `caches` a pair of
+                          arrays a layer (the second None where the layer
+                          keeps one), `counts` a dict of small device
+                          arrays, `{}` for a model that counts nothing. A
+                          step program returns them beside its tokens, a
+                          scan summed over its steps; the launch record the
+                          timing hook gets always carries them as `stats`.
+  _decode_cache_spec() -> `nn.functional.cached_attention.CacheSpec`: what
+                          each layer keeps of a token (K,V rows of
+                          [kv_heads, head_dim], or a latent row) and for how
+                          long (all rows, or a window). The pool is built
+                          from it (`PagedKVCache.for_model`) and a layer is
+                          handed its arrays as an `AttnCache`; layers of K,V
+                          rows share one attention-with-cache
+                          (`cached_attention`).
   _decode_validate(prompt_len, max_new_tokens) -> None (raise on invalid)
+
+and, optionally, over the defaults `GenerationMixin` declares:
+
+  _decode_logits_at    class attribute, False: the head runs over every
+                          position. True: the decode layer also accepts
+                          `logits_at=` [B] and returns [B, 1, V], the logits
+                          of that one position a row.
+  _launch_counts(program, stats, positions, kv_cache, table_width,
+                 steps=1, holding=0) -> dict, `{}`: what the model adds to
+                          the tick ledger for one launch, under the ledger's
+                          key names; `stats` are the launch's `counts` read
+                          back with its tokens. The ledger sums whatever
+                          keys arrive (a list place by place); none may be
+                          one of its own: the scheduler fails the launch's
+                          requests with the ValueError that names such a
+                          key. `issued_positions`, where given, says how
+                          many positions the program carried.
+
+The arrows point one way: `ops/pallas` <- `nn/functional` (the cache format
+and the attend call) <- `models/*` <- this module (the step programs) <-
+`inference/*` <- the benchmark.
 """
 from __future__ import annotations
 
@@ -94,6 +128,13 @@ def bucket_new_tokens(max_new_tokens):
 
 
 class GenerationMixin:
+    # the optional parts of the contract (module docstring), with defaults
+    _decode_logits_at = False
+
+    def _launch_counts(self, program, stats, positions, kv_cache,
+                       table_width, steps=1, holding=0) -> dict:
+        return {}
+
     # ------------------------------------------------------------- state cast
     def _decode_state(self, dtype):
         """Model state cast (once) to the decode dtype, cached by parameter
@@ -123,15 +164,13 @@ class GenerationMixin:
 
     # ------------------------------------------------------------- internals
     def _decode_call(self, raw_state, tok_ids, caches, offset, decode_kernel,
-                     paged_tables=None, cache_valid=None, logits_at=None,
-                     stats_out=None):
-        """One functional model call over raw jax values -> (logits, caches).
-        A layer's cache is a pair of arrays, the second None where the layer
-        keeps one (`inference.kv_cache.LayerCache`). `logits_at` [B] is
-        handed on only to a model that says it takes it
-        (`_decode_logits_at`): its logits are then [B, 1, V], of that one
-        position. A model may return counts of its own beside the caches (a
-        dict of small arrays); they are appended to `stats_out`."""
+                     paged_tables=None, cache_valid=None, logits_at=None):
+        """One functional model call over raw jax values -> (logits, caches,
+        counts). A layer's cache is a pair of arrays, the second None where
+        the layer keeps one (`LayerCache`). `logits_at` [B] is for a model
+        that says it takes it (`_decode_logits_at`): its logits are then
+        [B, 1, V], of that one position. `counts` are the model's own of the
+        call, a dict of small arrays ({}: it counts nothing)."""
         kwargs = dict(cache_offset=offset, decode_kernel=decode_kernel)
         if paged_tables is not None:
             kwargs.update(paged_tables=paged_tables, cache_valid=cache_valid)
@@ -143,13 +182,11 @@ class GenerationMixin:
 
         def raw(a):
             return a._value if isinstance(a, Tensor) else a
-        out = self._decode_layer().functional_call(
+        logits, new_caches, counts = self._decode_layer().functional_call(
             raw_state, Tensor(tok_ids),
             caches=[(wrap(k), wrap(v)) for k, v in caches], **kwargs)
-        logits, new_caches = out[:2]
-        if stats_out is not None and len(out) > 2:
-            stats_out.append(out[2])
-        return raw(logits), [(raw(kc), raw(vc)) for kc, vc in new_caches]
+        return (raw(logits), [(raw(kc), raw(vc)) for kc, vc in new_caches],
+                counts)
 
     @staticmethod
     def _make_sampler(greedy, temperature, top_k, eos, ids_dtype):
@@ -243,15 +280,15 @@ class GenerationMixin:
         ``generate.<path>`` RecordEvent. ``flops`` (ISSUE-19) is the
         program's issued FLOPs per launch — present only when the hook
         asked for it (``wants_flops``), None otherwise. ``stats`` are the
-        model's own counts of the launch, still on the device: whoever reads
-        the tokens back reads them in the same wait."""
+        model's own counts of the launch, still on the device ({}: it counts
+        nothing): whoever reads the tokens back reads them in the same
+        wait."""
         if timing_hook is None:
             return
         info = {"path": path, "batch": int(B), "prompt_len": int(P),
                 "new_tokens": int(new_tokens), "compiled": bool(compiled),
-                "dispatch_s": time.perf_counter() - t0, "flops": flops}
-        if stats:       # only a model that counts something adds the key
-            info["stats"] = stats
+                "dispatch_s": time.perf_counter() - t0, "flops": flops,
+                "stats": stats or {}}
         timing_hook(info)
 
     def _flops_of(self, cache_key, run, args):
@@ -335,11 +372,8 @@ class GenerationMixin:
                else jnp.asarray(input_ids))
         B, P = ids.shape
         self._decode_validate(P, max_new_tokens)
-        from ..inference.kv_cache import as_cache_spec
-
         # dense caches are K,V rows of like layers: any other spec says so
-        num_layers, kv_h, hd = as_cache_spec(
-            self._decode_cache_spec()).kv_triple()
+        num_layers, kv_h, hd = self._decode_cache_spec().kv_triple()
         new_tokens = int(max_new_tokens)
         # the COMPILED scan width is the declared bucket, not the raw
         # per-request budget (compile-surface `unbounded-key`): mixed-budget
@@ -371,7 +405,7 @@ class GenerationMixin:
                      jnp.zeros((B, kv_h, max_len, hd), cache_dtype))
                     for _ in range(num_layers)
                 ]
-                logits, caches = self._decode_call(
+                logits, caches, _ = self._decode_call(
                     raw_state, prompt, caches, jnp.int32(0), decode_kernel)
                 finished = jnp.zeros((B,), bool)
                 tok0, key, finished = sample(logits[:, -1], key, finished,
@@ -379,7 +413,7 @@ class GenerationMixin:
 
                 def body(carry, t):
                     tok, caches, key, finished = carry
-                    lg, caches = self._decode_call(
+                    lg, caches, _ = self._decode_call(
                         raw_state, tok[:, None], caches,
                         (P + t).astype(jnp.int32), decode_kernel)
                     nxt, key, finished = sample(lg[:, -1], key, finished,
@@ -497,7 +531,7 @@ class GenerationMixin:
                 valid = (jnp.arange(P, dtype=jnp.int32)[None, :]
                          < plens[:, None])
                 # prefill at per-request offset 0; padding rows write nothing
-                logits, caches = self._decode_call(
+                logits, caches, _ = self._decode_call(
                     raw_state, prompt, caches, jnp.zeros((B,), jnp.int32),
                     decode_kernel, paged_tables=tables, cache_valid=valid)
                 last = jnp.take_along_axis(
@@ -509,7 +543,7 @@ class GenerationMixin:
 
                 def body(carry, _):
                     tok, caches, lengths, key, finished = carry
-                    lg, caches = self._decode_call(
+                    lg, caches, _ = self._decode_call(
                         raw_state, tok[:, None], caches, lengths,
                         decode_kernel, paged_tables=tables, cache_valid=None)
                     nxt, key, finished = sample(lg[:, -1], key, finished)
@@ -633,7 +667,7 @@ class GenerationMixin:
         bank_sig = None if adapters is None else adapters.signature()
         # a model whose head can run over one position a row is asked for
         # the chunk's last only: the others' logits are never sampled
-        head_at_last = bool(getattr(self, "_decode_logits_at", False))
+        head_at_last = self._decode_logits_at
 
         def make_run():
             donate = (7, 8) if self._pool_donation() else ()
@@ -646,17 +680,16 @@ class GenerationMixin:
                 valid = (jnp.arange(C, dtype=jnp.int32)[None, :]
                          < lens[:, None])
                 last_at = jnp.maximum(lens - 1, 0)
-                stats = []
-                logits, caches = self._decode_call(
+                logits, caches, counts = self._decode_call(
                     raw_state, chunk, caches, offs, decode_kernel,
-                    paged_tables=tables, cache_valid=valid, stats_out=stats,
+                    paged_tables=tables, cache_valid=valid,
                     logits_at=last_at if head_at_last else None)
                 last = (logits[:, 0] if head_at_last else jnp.take_along_axis(
                     logits, last_at[:, None, None], axis=1)[:, 0])
                 tok, _, _ = sample(last, key, jnp.zeros((S,), bool),
                                    stemps, stks)
                 return (tok, [kc for kc, _ in caches],
-                        [vc for _, vc in caches], (stats or [{}])[0])
+                        [vc for _, vc in caches], counts)
 
             if bank_sig is None:
                 return jax.jit(step, donate_argnums=donate)
@@ -762,16 +795,14 @@ class GenerationMixin:
                 def body(carry, _):
                     tok, caches, lens, key, finished = carry
                     valid = (act & (lens < lmax))[:, None]
-                    stats = []
-                    lg, caches = self._decode_call(
+                    lg, caches, counts = self._decode_call(
                         raw_state, tok[:, None], caches, lens, decode_kernel,
-                        paged_tables=tables, cache_valid=valid,
-                        stats_out=stats)
+                        paged_tables=tables, cache_valid=valid)
                     nxt, key, finished = sample(lg[:, -1], key, finished,
                                                 stemps, stks)
                     nxt = jnp.where(act, nxt, tok)   # idle slots hold
                     return ((nxt, caches, lens + adv, key, finished),
-                            (nxt, (stats or [{}])[0]))
+                            (nxt, counts))
 
                 (_, caches, _, _, _), (toks, stats) = jax.lax.scan(
                     body, (tok, caches, lens, key, jnp.zeros((S,), bool)),
@@ -900,7 +931,7 @@ class GenerationMixin:
                 # prior over-speculation sit inside the next launch's write
                 # window and are overwritten before they become attendable
                 valid = act[:, None] & ((offs[:, None] + pos) < lmax[:, None])
-                logits, caches = self._decode_call(
+                logits, caches, _ = self._decode_call(
                     raw_state, chunk, caches, offs, decode_kernel,
                     paged_tables=tables, cache_valid=valid)
                 lg32 = logits.astype(jnp.float32)            # [S, W, V]

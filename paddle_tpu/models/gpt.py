@@ -23,6 +23,12 @@ from ..distributed.fleet.meta_parallel import (
 )
 from ..distributed.mesh import get_mesh
 from ..nn import functional as F
+from ..nn.functional.cached_attention import (
+    AttnCache,
+    CacheSpec,
+    cache_positions,
+    cached_attention,
+)
 from ..nn.layer import Layer
 from ..nn.layer_common import Dropout, Embedding, LayerList, Linear
 from ..nn.layer_conv_norm import LayerNorm, RMSNorm
@@ -105,80 +111,19 @@ class GPTAttention(Layer):
             return q, k, vv
 
         q, k, v = apply_op(split_qkv, "split_qkv", qkv)
-        if cache is not None:
-            # autoregressive decode: rope at absolute positions, K/V appended
-            # into the cache (dense slice or paged scatter), attention over
-            # the valid prefix via ops/pallas/decode_attention (xla reference
-            # or the split-KV Pallas kernel per `decode_kernel`)
-            paged = len(cache) == 5
-            if paged:
-                k_cache, v_cache, length, tables, valid = cache
-            else:
-                k_cache, v_cache, length = cache
-            if self.use_rope and position_ids is None:
-                if paged:
-                    ln = length._value if isinstance(length, Tensor) else length
-                    position_ids = (jnp.asarray(ln, jnp.int32)[:, None]
-                                    + jnp.arange(S, dtype=jnp.int32)[None, :])
-                else:
-                    from ..ops.creation import arange
-
-                    position_ids = arange(S) + length
-            if self.use_rope:
-                from ..incubate.nn.functional import (
-                    fused_rotary_position_embedding,
-                )
-
-                q, k, _ = fused_rotary_position_embedding(
-                    q, k, position_ids=position_ids)
-
-            from ..ops.pallas import decode_attention as da
-
-            kernel = decode_kernel or ("pallas" if paged else "xla")
-            scale = 1.0 / math.sqrt(self.head_dim)
-
-            if paged:
-                def attend_paged(qv, kv, vv, kp, vp, tbl, ln, vld):
-                    ln = jnp.asarray(ln, jnp.int32)
-                    capacity = tbl.shape[1] * kp.shape[1]
-                    pos = da.write_positions(ln, S, valid=vld,
-                                             capacity=capacity)
-                    kp, vp = da.paged_cache_update(kp, vp, kv, vv, tbl, pos)
-                    out = da.paged_decode_attention(
-                        qv, kp, vp, tbl, ln, scale=scale, kernel=kernel,
-                        new_rows=da.valid_new_rows(vld, S))
-                    return out, kp, vp
-
-                out, k_cache, v_cache = apply_op(
-                    attend_paged, "paged_decode_attention",
-                    q, k, v, k_cache, v_cache, tables, length, valid, nout=3)
-            else:
-                def attend(qv, kv, vv, kc, vc, ln):
-                    ln = (ln.astype(jnp.int32) if hasattr(ln, "astype")
-                          else jnp.int32(ln))
-                    zero = jnp.int32(0)
-                    # caches are head-leading [B, Hkv, T, D] (the decode
-                    # kernel's DMA-contiguous layout); only the NEW rows
-                    # transpose, S=1 at decode
-                    kc = jax.lax.dynamic_update_slice(
-                        kc, jnp.swapaxes(kv, 1, 2).astype(kc.dtype),
-                        (zero, zero, ln, zero))
-                    vc = jax.lax.dynamic_update_slice(
-                        vc, jnp.swapaxes(vv, 1, 2).astype(vc.dtype),
-                        (zero, zero, ln, zero))
-                    out = da.decode_attention(qv, kc, vc, ln, scale=scale,
-                                              kernel=kernel)
-                    return out, kc, vc
-
-                out, k_cache, v_cache = apply_op(attend, "decode_attention",
-                                                 q, k, v, k_cache, v_cache,
-                                                 length, nout=3)
-            out = out.reshape([B, S, q_size])
-            return self.out_proj(out), (k_cache, v_cache)
         if self.use_rope:
             from ..incubate.nn.functional import fused_rotary_position_embedding
 
+            if cache is not None and position_ids is None:
+                position_ids = cache_positions(cache, S)    # absolute
             q, k, _ = fused_rotary_position_embedding(q, k, position_ids=position_ids)
+        if cache is not None:
+            # autoregressive decode: the new rows into the cache, attention
+            # over the live prefix (nn/functional/cached_attention)
+            out, new_kv = cached_attention(
+                q, k, v, cache, scale=1.0 / math.sqrt(self.head_dim),
+                decode_kernel=decode_kernel)
+            return self.out_proj(out.reshape([B, S, q_size])), new_kv
         out, _ = F.flash_attention(q, k, v, dropout=self.dropout, causal=True,
                                    training=self.training)
         out = out.reshape([B, S, q_size])
@@ -272,9 +217,8 @@ class GPTModel(Layer):
         if caches is not None:
             new_caches = []
             for blk, (kc, vc) in zip(self.blocks, caches):
-                cache = ((kc, vc, cache_offset, paged_tables, cache_valid)
-                         if paged_tables is not None
-                         else (kc, vc, cache_offset))
+                cache = AttnCache(kc, vc, cache_offset, paged_tables,
+                                  cache_valid)
                 x, new_kv = blk(x, position_ids, cache=cache,
                                 decode_kernel=decode_kernel)
                 new_caches.append(new_kv)
@@ -298,7 +242,7 @@ class GPTModel(Layer):
         else:
             logits = self.lm_head(x)
         if caches is not None:
-            return logits, new_caches
+            return logits, new_caches, {}   # this model counts nothing
         return logits
 
 
@@ -326,8 +270,6 @@ class GPTForCausalLM(Layer, GenerationMixin):
         return self.gpt
 
     def _decode_cache_spec(self):
-        from ..inference.kv_cache import CacheSpec
-
         c = self.config
         return CacheSpec.uniform(c.num_layers, c.num_kv_heads,
                                  c.hidden_size // c.num_heads)
@@ -349,8 +291,8 @@ def gpt3_1p3b():
 
 
 def gpt_350m(max_position=1024):
-    """GPT-350M (GPT-medium class, rope / RMSNorm / SwiGLU): the ONE width
-    bench.py times and chip_smoke.py brings up, so they stay comparable."""
+    """GPT-350M (GPT-medium class, rope / RMSNorm / SwiGLU): the width
+    chip_smoke.py brings up."""
     return GPTConfig(vocab_size=50304, hidden_size=1024, num_layers=24,
                      num_heads=16, max_position=max_position, use_rope=True,
                      use_rms_norm=True, use_swiglu=True)

@@ -21,7 +21,6 @@ from __future__ import annotations
 import math
 
 import jax
-import jax.numpy as jnp
 
 from ..distributed.fleet.meta_parallel import (
     ColumnParallelLinear,
@@ -29,11 +28,15 @@ from ..distributed.fleet.meta_parallel import (
     VocabParallelEmbedding,
 )
 from ..nn import functional as F
+from ..nn.functional.cached_attention import (
+    AttnCache,
+    CacheSpec,
+    cache_positions,
+    cached_attention,
+)
 from ..nn.layer import Layer
 from ..nn.layer_common import LayerList
 from ..nn.layer_conv_norm import RMSNorm
-from ..ops import apply_op
-from ..tensor import Tensor
 from .generation import GenerationMixin
 from .gpt import _shard_seq
 
@@ -82,74 +85,19 @@ class LlamaAttention(Layer):
         v = self.v_proj(x).reshape([B, S, self.num_kv_heads, self.head_dim])
         from ..incubate.nn.functional import fused_rotary_position_embedding
 
-        if cache is not None:
-            # decode: rope at absolute positions, K/V into the cache (dense
-            # or paged), GQA attention over the live prefix WITHOUT expanding
-            # K/V to q heads (ops/pallas/decode_attention)
-            paged = len(cache) == 5
-            if paged:
-                k_cache, v_cache, length, tables, valid = cache
-            else:
-                k_cache, v_cache, length = cache
-            if position_ids is None:
-                if paged:
-                    ln = length._value if isinstance(length, Tensor) else length
-                    position_ids = (jnp.asarray(ln, jnp.int32)[:, None]
-                                    + jnp.arange(S, dtype=jnp.int32)[None, :])
-                else:
-                    from ..ops.creation import arange
-
-                    position_ids = arange(S) + length
-            q, k, _ = fused_rotary_position_embedding(
-                q, k, position_ids=position_ids,
-                rotary_emb_base=self.rope_theta)
-
-            from ..ops.pallas import decode_attention as da
-
-            kernel = decode_kernel or ("pallas" if paged else "xla")
-            scale = 1.0 / math.sqrt(self.head_dim)
-
-            if paged:
-                def attend_paged(qv, kv, vv, kp, vp, tbl, ln, vld):
-                    ln = jnp.asarray(ln, jnp.int32)
-                    capacity = tbl.shape[1] * kp.shape[1]
-                    pos = da.write_positions(ln, S, valid=vld,
-                                             capacity=capacity)
-                    kp, vp = da.paged_cache_update(kp, vp, kv, vv, tbl, pos)
-                    out = da.paged_decode_attention(
-                        qv, kp, vp, tbl, ln, scale=scale, kernel=kernel,
-                        new_rows=da.valid_new_rows(vld, S))
-                    return out, kp, vp
-
-                out, k_cache, v_cache = apply_op(
-                    attend_paged, "paged_decode_attention",
-                    q, k, v, k_cache, v_cache, tables, length, valid, nout=3)
-            else:
-                def attend(qv, kv, vv, kc, vc, ln):
-                    ln = (ln.astype(jnp.int32) if hasattr(ln, "astype")
-                          else jnp.int32(ln))
-                    zero = jnp.int32(0)
-                    # caches are head-leading [B, Hkv, T, D] (the decode
-                    # kernel's DMA-contiguous layout); only the NEW rows
-                    # transpose, S=1 at decode
-                    kc = jax.lax.dynamic_update_slice(
-                        kc, jnp.swapaxes(kv, 1, 2).astype(kc.dtype),
-                        (zero, zero, ln, zero))
-                    vc = jax.lax.dynamic_update_slice(
-                        vc, jnp.swapaxes(vv, 1, 2).astype(vc.dtype),
-                        (zero, zero, ln, zero))
-                    out = da.decode_attention(qv, kc, vc, ln, scale=scale,
-                                              kernel=kernel)
-                    return out, kc, vc
-
-                out, k_cache, v_cache = apply_op(attend, "decode_attention",
-                                                 q, k, v, k_cache, v_cache,
-                                                 length, nout=3)
-            out = self.o_proj(
-                out.reshape([B, S, self.num_heads * self.head_dim]))
-            return out, (k_cache, v_cache)
+        if cache is not None and position_ids is None:
+            position_ids = cache_positions(cache, S)    # absolute
         q, k, _ = fused_rotary_position_embedding(
             q, k, position_ids=position_ids, rotary_emb_base=self.rope_theta)
+        if cache is not None:
+            # decode: the new rows into the cache (dense or paged), GQA
+            # attention over the live prefix WITHOUT expanding K/V to q
+            # heads (nn/functional/cached_attention)
+            out, new_kv = cached_attention(
+                q, k, v, cache, scale=1.0 / math.sqrt(self.head_dim),
+                decode_kernel=decode_kernel)
+            return self.o_proj(
+                out.reshape([B, S, self.num_heads * self.head_dim])), new_kv
         out, _ = F.flash_attention(q, k, v, causal=True, training=self.training)
         return self.o_proj(out.reshape([B, S, self.num_heads * self.head_dim]))
 
@@ -208,9 +156,8 @@ class LlamaModel(Layer):
         if caches is not None:
             new_caches = []
             for blk, (kc, vc) in zip(self.layers, caches):
-                cache = ((kc, vc, cache_offset, paged_tables, cache_valid)
-                         if paged_tables is not None
-                         else (kc, vc, cache_offset))
+                cache = AttnCache(kc, vc, cache_offset, paged_tables,
+                                  cache_valid)
                 x, new_kv = blk(x, position_ids, cache=cache,
                                 decode_kernel=decode_kernel)
                 new_caches.append(new_kv)
@@ -251,7 +198,7 @@ class LlamaForCausalLM(Layer, GenerationMixin):
                                        decode_kernel=decode_kernel,
                                        paged_tables=paged_tables,
                                        cache_valid=cache_valid)
-            return self.lm_head(h), new_caches
+            return self.lm_head(h), new_caches, {}  # this model counts nothing
         h = self.llama(input_ids, position_ids)
         logits = self.lm_head(h)
         if labels is not None:
@@ -267,8 +214,6 @@ class LlamaForCausalLM(Layer, GenerationMixin):
         return self
 
     def _decode_cache_spec(self):
-        from ..inference.kv_cache import CacheSpec
-
         c = self.config
         return CacheSpec.uniform(c.num_layers, c.num_kv_heads,
                                  c.hidden_size // c.num_heads)

@@ -2,12 +2,11 @@
 
 The serving path answers "where did this request spend its deadline"
 (trace.py + serving.py); this module makes the TRAINING path answer the
-equivalent three questions live, per step, instead of offline in bench.py:
+equivalent three questions live, per step:
 
 1. **How fast am I actually going?** — per-step wall time, samples/sec,
    tokens/sec, and live MFU whose numerator is the compiled program's OWN
-   ``cost_analysis()`` FLOPs (``observability.xla``) — the same number
-   bench.py audits, so the two cannot drift apart silently.
+   ``cost_analysis()`` FLOPs (``observability.xla``).
 2. **Did I just recompile?** — a recompilation sentinel fingerprints the
    argument avals each ``TrainStep.__call__`` sees. A fingerprint never seen
    before (after the first compile) means XLA built a new program: counted in

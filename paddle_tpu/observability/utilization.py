@@ -67,8 +67,8 @@ Exported series (absent-iff-off, like every optional subsystem):
 * ``paddle_tenant_flops_total{component,tenant}`` — chargeback counters.
 * ``paddle_serving_host_gap_seconds{component}`` — per-tick histogram.
 * ``paddle_serving_moe_expert_load_skew{component}`` — the busiest held
-  expert's assignments over the mean's; registered when an expert layer
-  first reports its counts (``MODEL_KEYS``).
+  expert's assignments over the mean's; registered when a model first
+  reports ``moe_expert_tokens`` among its counts.
 * ``paddle_serving_mfu{component}`` — rolling-window useful FLOP/s over
   ``device_peak_flops`` — registered only when the peak is KNOWN (real
   accelerator or an injected ``peak_flops=``); on CPU the gauge is absent,
@@ -147,15 +147,10 @@ _PROGRAM_KEYS = (_FLOPS + ("launches",) + _POSITIONS
                  + ("live_rows", "walked_rows", "table_rows", "dispatch_s",
                     "wait_s"))
 _TICK_KEYS = _FLOPS + ("launch_s", "dispatch_s", "wait_s")
-# What a model counts of its own launches, on a program's account only where
-# a model reports it (record_launch's `counts`): the expert layer's rows (the
-# tiles it walked, the assignments its held experts got, those of them each
-# expert got, the assignments of real tokens to experts held elsewhere) and
-# the attention's rows (what the queries need at most, what the programs
-# read from the pools, the keys the indexer scored).
-MODEL_KEYS = ("moe_rows_issued", "moe_rows_useful",
-              "moe_assignments_elsewhere", "moe_expert_tokens",
-              "attn_rows_needed", "attn_rows_read", "indexer_rows_scored")
+# Beside these a program's account holds whatever a model counts of its own
+# launches (record_launch's `counts`, from the model's `_launch_counts`),
+# under the model's key names: summed like the ledger's own, a list place by
+# place. A model's key may not be one of the ledger's.
 
 
 def _add_count(acc, key, value):
@@ -182,11 +177,11 @@ def _fold(acc, tick):
     for name, p in tick["programs"].items():
         acc["launches"] += p["launches"]
         tot = acc["programs"].setdefault(name, dict.fromkeys(_PROGRAM_KEYS, 0))
-        for key in _PROGRAM_KEYS:
-            tot[key] += p[key]
-        for key in MODEL_KEYS:
-            if key in p:
-                _add_count(tot, key, p[key])
+        for key, value in p.items():
+            if key in _PROGRAM_KEYS:
+                tot[key] += value
+            else:                   # a model's own count
+                _add_count(tot, key, value)
 
 
 def _account_view(acc):
@@ -322,6 +317,18 @@ class UtilizationLedger:
         self._tick = dict.fromkeys(_TICK_KEYS, 0)
         self._tick.update(tenants={}, programs={}, profiled=profiled)
 
+    @staticmethod
+    def check_counts(counts):
+        """Raise ValueError if a model's count is under one of the ledger's
+        own key names. The scheduler asks this of every `_launch_counts`
+        answer in the tick, where the error fails the launch's requests:
+        inside its guard around the ledger it would only be missing
+        telemetry."""
+        taken = sorted(k for k in counts if k in _PROGRAM_KEYS)
+        if taken:
+            raise ValueError(f"a model's count may not be the ledger's own "
+                             f"key: {taken}")
+
     def record_launch(self, program, flops, launch_s, total_units,
                       slot_units, spec_units=0, *, wait_s=0.0, live_rows=0,
                       walked_rows=0, table_rows=0, counts=None):
@@ -331,9 +338,13 @@ class UtilizationLedger:
         ``slot_units`` ``[(tenant_or_None, useful_units), ...]`` per live
         slot — the scheduler's ground truth of which positions carried live
         tokens — and ``spec_units`` rejected draft positions. ``counts`` are
-        the model's own of this launch, under ``MODEL_KEYS``."""
+        the model's own of this launch, under its own key names: every one
+        is summed on the program's account; one that is the ledger's own
+        raises ValueError."""
         if self._tick is None:      # launch outside a tick (warmup): skip
             return
+        counts = counts or {}
+        self.check_counts(counts)   # before anything of the launch is added
         self.poll_session()
         slot_units = list(slot_units)
         issued, useful, pad, spec, bills = attribute_launch(
@@ -362,10 +373,9 @@ class UtilizationLedger:
         p["live_rows"] += int(live_rows)
         p["walked_rows"] += int(walked_rows)
         p["table_rows"] += int(table_rows)
-        for key, value in (counts or {}).items():
-            if key in MODEL_KEYS:
-                _add_count(p, key, value)
-        if self._skew_gauge and "moe_expert_tokens" in (counts or {}):
+        for key, value in counts.items():
+            _add_count(p, key, value)
+        if self._skew_gauge and "moe_expert_tokens" in counts:
             # absent until an expert layer reports: a model without one has
             # no such series
             registry, component = self._skew_gauge

@@ -7,9 +7,8 @@ surfaces across jax versions and backends:
 
 * ``cost_analysis`` / ``cost_flops`` — the compiled program's own FLOP count
   (jax returns a dict on some versions, a 1-list of dicts on others; some
-  backends return nothing).  This is the number bench.py's MFU audit and the
-  live ``StepMonitor`` MFU must AGREE on, which is why both now import it
-  from here instead of keeping private copies.
+  backends return nothing).  This is the number the live ``StepMonitor``
+  MFU and the serving ledger's FLOPs probe read.
 * ``memory_stats`` — ``compiled.memory_analysis()`` (XLA's
   ``CompiledMemoryStats``) flattened to plain ints: argument / output / temp /
   generated-code / alias bytes plus a derived ``peak_bytes`` watermark
@@ -32,7 +31,8 @@ __all__ = ["cost_analysis", "cost_flops", "memory_stats",
            "device_ici_bandwidth", "ICI_BANDWIDTH_BYTES"]
 
 # Per-chip peak bf16 TFLOP/s (dense), from public TPU specs. The single
-# source of truth — bench.py's _chip_peak reads this table.
+# source of truth for the program's own gauges (the benchmark keeps its
+# table, benchmarks/harness/peaks.py: ROADMAP Queue C).
 PEAK_BF16_FLOPS = {
     "TPU v3": 123e12,
     "TPU v4": 275e12,

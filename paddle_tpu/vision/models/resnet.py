@@ -80,8 +80,7 @@ class BottleneckBlock(Layer):
 class ResNet(Layer):
     """`data_format` (TPU extension beyond the reference constructor): "NHWC"
     builds the whole network channels-last — convs, BN reductions, residual
-    adds and pooling all share the TPU-native minor-most channel layout, worth
-    ~2 MFU points end-to-end at B=128 (docs/PERF.md round-5 layout table).
+    adds and pooling all share the TPU-native minor-most channel layout.
     Input must then be NHWC too."""
 
     def __init__(self, block, depth=50, width=64, num_classes=1000, with_pool=True,

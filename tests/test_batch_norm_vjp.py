@@ -1,7 +1,7 @@
 """Custom-vjp training batch norm (_bn_train): gradient parity against the
 composed relu(bn(x)+residual) reference + variance numerical stability for
 large-mean inputs (guards the exact two-pass form; the one-pass and
-shifted variants were rejected — see docs/PERF.md)."""
+shifted variants lose the variance of a large-mean input to cancellation)."""
 import numpy as np
 import pytest
 

@@ -1,8 +1,7 @@
 """Preemption-tolerant training (ISSUE-7): CheckpointManager async sharded
 save/restore, bit-exact auto-resume through TrainStep and Model.fit,
 fault-injected kill drills at the ckpt.* sites, torn/corrupt fallback,
-retention, goodput accounting, crash-safe io_utils, and the bench
-checkpoint_overhead field wiring."""
+retention, goodput accounting and crash-safe io_utils."""
 import json
 import os
 import pickle
@@ -671,51 +670,6 @@ def test_all_ndarray_dict_roundtrips_and_reference_converts(tmp_path):
         assert isinstance(ref_loaded[k], Tensor), k
         np.testing.assert_array_equal(np.asarray(ref_loaded[k]._value),
                                       ours[k])
-
-
-# ================================================================= bench wiring
-def test_checkpoint_overhead_fields_pure():
-    from bench import checkpoint_overhead_fields
-
-    out = {"bare_wall_sec": 10.0, "checkpointed_wall_sec": 10.1,
-           "steps": 20, "snapshot_sec": 0.01, "goodput": 0.97}
-    checkpoint_overhead_fields(out)
-    assert out["overhead_pct"] == 1.0
-    assert out["audit"] == "ok"
-    assert out["step_time_sec"] == 0.5
-    assert out["snapshot_pct_of_step"] == 2.0
-
-    bad = {"bare_wall_sec": 10.0, "checkpointed_wall_sec": 10.3, "steps": 20}
-    checkpoint_overhead_fields(bad)
-    assert bad["overhead_pct"] == 3.0
-    assert bad["audit"] == "checkpoint-overhead"
-
-    noise = {"bare_wall_sec": 10.0, "checkpointed_wall_sec": 9.9, "steps": 5}
-    checkpoint_overhead_fields(noise)
-    assert noise["overhead_pct"] == 0.0   # clamped: noise, not time travel
-    assert noise["audit"] == "ok"
-
-    empty = {}
-    checkpoint_overhead_fields(empty)
-    assert "audit" not in empty
-
-
-def test_checkpoint_overhead_bench_source_pins():
-    """The bench leg exists, gates at <2%, and reports goodput + per-phase
-    seconds (source-level pin, the graph_lint test idiom)."""
-    import inspect
-
-    import bench
-
-    src = inspect.getsource(bench.bench_checkpoint_overhead)
-    assert "CheckpointManager" in src
-    assert "checkpoint_overhead_fields" in src
-    main_src = inspect.getsource(bench.main)
-    assert "bench_checkpoint_overhead" in main_src
-    assert '"checkpoint_overhead"' in main_src
-    fields_src = inspect.getsource(bench.checkpoint_overhead_fields)
-    assert "2.0" in fields_src and "goodput" not in fields_src.split(
-        "overhead_pct")[0]
 
 
 # ================================================================ slow soak

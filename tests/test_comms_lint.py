@@ -5,7 +5,7 @@ fixtures, the CLI legs, and the metrics exposition.
 
 The parser pins are HAND-COMPUTED on inline HLO lines — every wire-bytes
 number below is derivable on paper from the printed buffer size, the group
-size and the ring formulas (docs/PERF.md), which is the point: when one
+size and the ring formulas (docs/ANALYSIS.md), which is the point: when one
 breaks, the cost model's semantics changed, not a tolerance. The one REAL
 compiled program in the non-slow tier is the sampled-logits gather probe —
 the split-KV decode path's single documented collective — pinned to exactly

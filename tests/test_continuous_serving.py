@@ -152,58 +152,6 @@ def test_the_budget_never_cuts_a_chunk(small_gpt):
         gp.close()
 
 
-def test_per_request_max_new_retires_early_with_parity(small_gpt):
-    """Per-request token budgets: a request asking for fewer tokens gets the
-    PREFIX of the full generation (token parity), retires early, and frees
-    its blocks for the next stream — the core throughput win over
-    whole-request batching."""
-    m = small_gpt
-    rng = np.random.default_rng(7)
-    prompt = rng.integers(0, 160, 5).astype("int64")
-    ref = _dense_ref(m, prompt, 6)
-    gp = _make(m)
-    try:
-        out2 = gp.infer(prompt, timeout=300, max_new_tokens=2)
-        np.testing.assert_array_equal(out2, ref[:len(prompt) + 2])
-        out_all = gp.infer(prompt, timeout=300)
-        np.testing.assert_array_equal(out_all, ref)
-        # over-cap asks clamp to the server cap instead of erroring
-        out_cap = gp.infer(prompt, timeout=300, max_new_tokens=999)
-        np.testing.assert_array_equal(out_cap, ref)
-        assert gp.kv_cache.blocks_in_use == 0
-    finally:
-        gp.close()
-
-
-def test_eos_freezes_remainder_like_dense_sampler(small_gpt):
-    """EOS early-exit parity: pick the sequence's own first generated token
-    as EOS — dense freezes every later position to EOS, the scheduler must
-    produce the identical frozen tail (and retire the slot early)."""
-    m = small_gpt
-    rng = np.random.default_rng(9)
-    prompt = rng.integers(0, 160, 5).astype("int64")
-    tok0 = int(_dense_ref(m, prompt, 1)[-1])
-    ref = _dense_ref(m, prompt, 6, eos=tok0)
-    gp = _make(m, eos_token_id=tok0)
-    try:
-        out = gp.infer(prompt, timeout=300)
-        np.testing.assert_array_equal(out, ref)
-        assert list(out[len(prompt):]) == [tok0] * 6
-    finally:
-        gp.close()
-
-
-def test_oversized_for_max_seq_len_rejected_invalid(small_gpt):
-    gp = _make(small_gpt, max_seq_len=16)   # 16 - 6 new = 10 prompt tokens
-    try:
-        with pytest.raises(ValueError):
-            gp.infer(np.arange(11).astype("int64"), timeout=30)
-        assert gp.metrics.get("rejected_invalid") == 1
-        assert gp.metrics.get("accepted") == 0
-    finally:
-        gp.close()
-
-
 def test_scheduler_gauges_and_counters_exposed(small_gpt):
     """Scheduler observability: slot/budget gauges and admit/retire counters
     land in the Prometheus registry, and the slot gauge partitions
@@ -312,98 +260,6 @@ def test_close_fails_inflight_with_service_unavailable(small_gpt):
     assert "r" in outcome or "e" in outcome
     assert gp.kv_cache.blocks_in_use == 0
     gp.kv_cache.check_conservation()
-
-
-# ------------------------------------------- per-request sampling (ISSUE-8)
-def test_mixed_sampler_traffic_compiles_exactly_two_step_programs(small_gpt):
-    """ROADMAP item 1: temperature/top-k are TRACED per-slot inputs of the
-    step programs, so greedy and sampled requests share one compiled
-    prefill_chunk and one compiled decode_step — pinned off the runner
-    cache (the serving twin of the recompile sentinel). Greedy requests
-    stay token-identical to dense generate() while decoding in the same
-    ticks as sampled neighbors."""
-    m = small_gpt
-    rng = np.random.default_rng(17)
-    gp = _make(m)
-    try:
-        prompts = [rng.integers(0, 160, n).astype("int64")
-                   for n in (3, 5, 7, 4, 6, 9)]
-        refs = [_dense_ref(m, p, 6) for p in prompts]
-        samplers = [dict(),                                  # greedy
-                    dict(temperature=0.8, top_k=5),
-                    dict(temperature=1.2),
-                    dict(),                                  # greedy
-                    dict(temperature=0.5, top_k=3),
-                    dict(temperature=0.9, top_k=1)]
-        outs = [None] * len(prompts)
-
-        def client(i):
-            outs[i] = np.asarray(gp.infer(prompts[i], timeout=300,
-                                          **samplers[i]))
-
-        ts = [threading.Thread(target=client, args=(i,))
-              for i in range(len(prompts))]
-        for t in ts:
-            t.start()
-        for t in ts:
-            t.join(timeout=300)
-        assert not any(t.is_alive() for t in ts)
-
-        for i, out in enumerate(outs):
-            assert out is not None
-            assert out.shape == (len(prompts[i]) + 6,)
-            np.testing.assert_array_equal(out[:len(prompts[i])], prompts[i])
-            assert (out >= 0).all() and (out < 160).all()
-            if not samplers[i]:     # greedy: token-identical to dense
-                np.testing.assert_array_equal(out, refs[i])
-
-        # THE pin: mixed-sampler traffic forked zero step programs
-        step_keys = [k for k in m._generate_cache
-                     if k[0] in ("prefill_chunk", "decode_step")
-                     and k[5] == -1]     # this suite's eos-less programs
-        assert len(step_keys) == 2, step_keys
-    finally:
-        gp.close()
-
-
-def test_per_slot_sampler_isolation_model_level(small_gpt):
-    """A sampled neighbor slot must not perturb a greedy slot: decode the
-    same two-slot batch twice — once all-greedy, once with slot 1 at
-    temperature 1.5/top-k 4 — and slot 0's tokens must be bit-identical
-    (per-slot sampler isolation inside the ONE compiled program)."""
-    from paddle_tpu.inference.kv_cache import PagedKVCache
-
-    m = small_gpt
-    spec = tuple(int(x) for x in m._decode_cache_spec())
-    rng = np.random.default_rng(5)
-    prompt = rng.integers(0, 160, 4).astype("int64")
-
-    def run(slot1_temp, slot1_topk):
-        kv = PagedKVCache(*spec, block_size=8, num_blocks=16)
-        for s in ("s0", "s1"):
-            kv.reserve(s, 12)
-        tbl = np.stack([kv.block_table(s, pad_to=2) for s in ("s0", "s1")])
-        chunk = np.stack([prompt, prompt])
-        tk = m.prefill_chunk(chunk, np.zeros(2, np.int64),
-                             np.full(2, 4, np.int64), kv, tbl,
-                             temperature=np.asarray([0.0, slot1_temp],
-                                                    np.float32),
-                             top_k=np.asarray([0, slot1_topk], np.int32),
-                             decode_kernel="xla")
-        tk = np.asarray(tk._value if hasattr(tk, "_value") else tk)
-        toks = m.decode_step(
-            tk, np.full(2, 4, np.int64), np.asarray([True, True]), kv, tbl,
-            steps=4, max_lens=np.full(2, 12, np.int64),
-            temperature=np.asarray([0.0, slot1_temp], np.float32),
-            top_k=np.asarray([0, slot1_topk], np.int32),
-            decode_kernel="xla")
-        return tk, np.asarray(toks._value if hasattr(toks, "_value")
-                              else toks)
-
-    tk_a, toks_a = run(0.0, 0)
-    tk_b, toks_b = run(1.5, 4)
-    assert tk_a[0] == tk_b[0]                       # greedy prefill sample
-    np.testing.assert_array_equal(toks_a[0], toks_b[0])   # greedy decode
 
 
 # ------------------------------------------- speculative decoding (ISSUE-10)

@@ -10,7 +10,7 @@ Two halves, both required by the acceptance bar:
    allowlist, visibly, with its justification.
 
 Plus the integration surfaces: analyze_lowered (StableHLO-text subset),
-the CLI --self-check entry point, and the bench graph_lint field wiring.
+and the CLI --self-check entry point.
 """
 import json
 
@@ -216,7 +216,7 @@ def test_analyze_lowered_donation_and_callback():
 # old fixture built the whole 16-program zoo eagerly (the single largest
 # tier-1 line, 60-80s: every tp/lora/verify variant traced and linted) while
 # the tests below read exactly these six — so build per-entry, on first
-# access, and let bench_graph_lint keep exercising the full zoo.
+# access; `python -m paddle_tpu.analysis --self-check` lints the full zoo.
 _ZOO_KEY = {
     "train_step:GPT": "gpt_train",
     "train_step:ResNet18": "resnet_train",
@@ -315,7 +315,7 @@ def test_train_step_donation_rule_would_catch_dropped_donation():
                for f in r2.findings)
 
 
-# ----------------------------------------------------------------- CLI + bench
+# ------------------------------------------------------------------------ CLI
 def test_cli_self_check_in_process(capsys):
     # a two-program subset keeps this leg inside the tier-1 per-test budget
     # (the full zoo is already linted by the module fixture above); paged
@@ -346,22 +346,6 @@ def test_cli_list_rules_names_all_six(capsys):
     for rule in ("donation-miss", "dtype-upcast", "host-sync",
                  "constant-bloat", "recompile-hazard", "collective-axis"):
         assert rule in out
-
-
-def test_bench_graph_lint_fields_wiring():
-    from bench import graph_lint_fields
-
-    synth = {"findings": [
-        {"rule": "donation-miss", "severity": "high"},
-        {"rule": "donation-miss", "severity": "high"},
-        {"rule": "host-sync", "severity": "warn"},
-    ]}
-    graph_lint_fields(synth)
-    assert synth["findings_by_rule"] == {"donation-miss": 2, "host-sync": 1}
-    assert synth["high_total"] == 2 and synth["audit"] == "lint-high"
-    clean = {"findings": []}
-    graph_lint_fields(clean)
-    assert clean["high_total"] == 0 and clean["audit"] == "ok"
 
 
 def test_report_render_and_dict_roundtrip():

@@ -637,37 +637,3 @@ def test_train_series_exposition_lint_with_merged_registries():
     # the serving side of the merge is intact too
     assert series[("paddle_serving_events_total",
                    'component="generator",event="accepted"')] == 1
-
-
-# --------------------------------------------------------------- bench wiring
-def test_observability_overhead_fields():
-    import importlib
-    import os
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    bench = importlib.import_module("bench")
-    out = {"traced_wall_sec": 10.2, "untraced_wall_sec": 10.0}
-    bench.observability_overhead_fields(out)
-    assert out["overhead_pct"] == pytest.approx(2.0)
-    assert out["audit"] == "ok"
-    out = {"traced_wall_sec": 12.0, "untraced_wall_sec": 10.0}
-    bench.observability_overhead_fields(out)
-    assert out["overhead_pct"] == pytest.approx(20.0)
-    assert out["audit"] == "tracing-overhead"
-    out = {"traced_wall_sec": 9.5, "untraced_wall_sec": 10.0}
-    bench.observability_overhead_fields(out)
-    assert out["overhead_pct"] == 0.0 and out["audit"] == "ok"  # noise clamp
-    out = {"traced_wall_sec": 9.5}
-    bench.observability_overhead_fields(out)
-    assert "overhead_pct" not in out and "audit" not in out
-
-    # source-level pin: the bench leg must actually run on-vs-off and route
-    # through the pure fields function (running it live takes minutes)
-    import inspect
-
-    src = inspect.getsource(bench.bench_observability_overhead)
-    assert "Tracer(enabled=False)" in src
-    assert "observability_overhead_fields(" in src
-    assert "\"observability_overhead\"" in inspect.getsource(bench.main)

@@ -140,7 +140,8 @@ def test_record_event_still_lands_in_a_recording_profiler():
 
 def test_timing_hook_names_its_interval_dispatch(small_gpt):
     """No field called launch_s leaves the wait out: the hook's interval is
-    dispatch_s, and per_token_s (which nobody read) is gone."""
+    dispatch_s, and per_token_s (which nobody read) is gone. The record
+    always carries the model's counts of the launch (`stats`, {} here)."""
     sched = _make(small_gpt)
     seen = []
     inner = sched._timing_hook
@@ -151,7 +152,8 @@ def test_timing_hook_names_its_interval_dispatch(small_gpt):
         sched.close()
     assert seen and all(
         set(i) == {"path", "batch", "prompt_len", "new_tokens", "compiled",
-                   "dispatch_s", "flops"} for i in seen)
+                   "dispatch_s", "flops", "stats"} for i in seen)
+    assert all(i["stats"] == {} for i in seen)
 
 
 # ------------------------------------------------- pure ledger, fake clock

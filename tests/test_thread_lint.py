@@ -1,6 +1,6 @@
 """Thread lint (ISSUE-8 tentpole): every rule proven live on a seeded
-violation, the real tree proven clean (or visibly allowlisted), the CLI
-gate, and the bench thread_lint field wiring.
+violation, the real tree proven clean (or visibly allowlisted) and the CLI
+gate.
 
 The fixtures in tests/thread_lint_fixtures/ are analyzed as SOURCE (pure
 AST — never imported), so the deadlocks and races they seed can never
@@ -200,30 +200,7 @@ def test_cli_json_shape_with_threads(tmp_path):
     assert "thread-lint" in names
 
 
-# ------------------------------------------------- bench fields + metrics
-def test_bench_thread_lint_fields_pure_wiring():
-    import sys
-
-    sys.path.insert(0, "/root/repo")
-    try:
-        from bench import thread_lint_fields
-    finally:
-        sys.path.pop(0)
-
-    out = {"findings": [
-        {"rule": "unguarded-write", "severity": "high"},
-        {"rule": "unguarded-write", "severity": "warn"},
-        {"rule": "raw-clock", "severity": "warn"},
-    ]}
-    thread_lint_fields(out)
-    assert out["findings_by_rule"] == {"unguarded-write": 2, "raw-clock": 1}
-    assert out["high_total"] == 1 and out["audit"] == "lint-high"
-
-    clean = {"findings": []}
-    thread_lint_fields(clean)
-    assert clean["high_total"] == 0 and clean["audit"] == "ok"
-
-
+# --------------------------------------------------------------- metrics
 def test_record_findings_exposes_prometheus_series():
     from paddle_tpu.observability.metrics import (
         MetricsRegistry,

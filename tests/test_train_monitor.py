@@ -2,7 +2,7 @@
 metrics + spans, live MFU from the compiled program's own cost_analysis, HBM
 watermark gauges from memory_analysis, the recompilation sentinel (including
 the AOT-fallback path), numerics anomaly detection, the hapi MonitorCallback /
-ProgBarLogger surfacing, and the bench train_observability_overhead wiring."""
+ProgBarLogger surfacing."""
 import importlib
 import json
 import os
@@ -319,58 +319,6 @@ def test_progbar_surfaces_monitor_fields_only_when_active(capsys):
     live = capsys.readouterr().out
     assert "ips: 123.4" in live and "mfu: 41.5%" in live
     assert "tok/s: 2048" in live
-
-
-# ------------------------------------------------------------ bench wiring
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-bench = importlib.import_module("bench")
-
-
-def test_train_overhead_fields_gate_and_mfu_cross_check():
-    out = {"monitored_wall_sec": 10.2, "unmonitored_wall_sec": 10.0,
-           "live_mfu": 0.48, "bench_mfu": 0.50}
-    bench.train_observability_overhead_fields(out)
-    assert out["overhead_pct"] == pytest.approx(2.0)
-    assert out["audit"] == "ok"
-    assert out["mfu_delta_pct"] == pytest.approx(4.0)
-
-    out = {"monitored_wall_sec": 10.5, "unmonitored_wall_sec": 10.0}
-    bench.train_observability_overhead_fields(out)
-    assert out["overhead_pct"] == pytest.approx(5.0)
-    assert out["audit"] == "monitor-overhead"           # > 3% gate
-    assert "mfu_delta_pct" not in out                   # CPU leg: no MFU
-
-    out = {"monitored_wall_sec": 9.5, "unmonitored_wall_sec": 10.0}
-    bench.train_observability_overhead_fields(out)
-    assert out["overhead_pct"] == 0.0 and out["audit"] == "ok"  # noise clamp
-
-    out = {"monitored_wall_sec": 9.5}
-    bench.train_observability_overhead_fields(out)
-    assert "overhead_pct" not in out and "audit" not in out
-
-
-def test_train_overhead_bench_wires_monitor_and_fields():
-    """Source-level pin (running the leg live takes minutes): the bench must
-    run monitored-vs-bare legs, report the sentinel/HBM/MFU numbers, and
-    route through the pure fields function."""
-    import inspect
-
-    src = inspect.getsource(bench.bench_train_observability_overhead)
-    assert "StepMonitor(" in src
-    assert "train_observability_overhead_fields(" in src
-    for field in ("recompiles", "hbm_peak_bytes", "live_mfu", "bench_mfu"):
-        assert field in src, f"bench leg dropped {field}"
-    assert '"train_observability_overhead"' in inspect.getsource(bench.main)
-
-
-def test_bench_flops_helpers_are_the_shared_xla_ones():
-    """bench MFU and live MFU must share one numerator: the bench helpers
-    delegate to observability.xla instead of keeping private copies."""
-    import inspect
-
-    assert "cost_flops" in inspect.getsource(bench._cost_flops)
-    assert "device_peak_flops" in inspect.getsource(bench._chip_peak)
-    assert "hbm_peak_bytes" in inspect.getsource(bench._gpt_train_phase)
 
 
 # ----------------------------------------------- merged exposition with serving

@@ -464,6 +464,20 @@ def test_server_utilization_endpoint_and_metrics_block(small_gpt):
     try:
         assert _post_ids(base, "/generate",
                          np.arange(5, dtype="int64"))[0] == 200
+        # the answer leaves when its last token is absorbed; the tick
+        # thread accounts that tick's launch AFTER it. Read only once every
+        # slot is free and two reads of the ledger's `launches` agree, so
+        # both reads below see one state
+        free = sched.metrics.registry.gauge(
+            "paddle_sched_slots", labels=("component", "phase")).labels(
+                "continuous", "free")
+        deadline, seen = time.monotonic() + 30, None
+        while time.monotonic() < deadline:
+            now = (free.value, sched.util.snapshot()["launches"])
+            if now == seen and now[0] == sched.max_slots and now[1] > 0:
+                break
+            seen = now
+            time.sleep(0.1)
         status, body, hdrs = _get(base, "/utilization")
         assert status == 200
         assert hdrs["Content-Type"] == "application/json"
