@@ -812,6 +812,13 @@ BUILTIN_THREAD_ALLOWLIST = Allowlist([
                "would risk lock re-entry from launch paths"),
     AllowlistEntry(
         "unguarded-write", subject="thread-lint",
+        contains="ContinuousGenerateBatchingPredictor._ahead",
+        reason="tick-thread-only: the decode launch dispatched ahead of its "
+               "read-back is set, landed and dropped by the scheduler loop "
+               "thread alone (the tick, its shutdown and ThreadDeath paths "
+               "run on it); no other thread reads it"),
+    AllowlistEntry(
+        "unguarded-write", subject="thread-lint",
         contains="InferenceServer.profile_dir",
         reason="lazy tmpdir resolution runs only while self._profile_lock "
                "is held: the /debug/profile handler acquires it "

@@ -24,7 +24,15 @@ of S slots:
   decode p99: docs/DEPLOYMENT.md, `prefill_token_budget`).
 * decode — all decoding slots advance `decode_steps` tokens in ONE
   `decode_step` launch (a compiled scan: the host syncs per tick, not per
-  token).
+  token). Each launch goes out AHEAD of the read-back of the launch before
+  it, taking the input tokens that launch makes from the device: in a
+  prefill tick right behind the chunk (a slot the chunk completes starts
+  from the chunk's token), in a stretch of pure decode behind the decode
+  launch in flight (the last column of its tokens), so the host's
+  dispatch, read-back bookkeeping and stream flushes run while the device
+  works. A tick that is not a pure decode continuation (a prefill pick, a
+  QoS pause, shutdown) lands a launch still in flight from the tick
+  before first; `spec_k > 0` never runs ahead.
 * retire — finished / EOS / deadline-expired / client-cancelled sequences
   free their blocks and slot at the next tick boundary; the freed slot is
   admissible on the same tick.
@@ -55,6 +63,8 @@ import itertools
 import queue
 import threading
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..analysis.lockwitness import make_lock
@@ -72,6 +82,23 @@ __all__ = ["ContinuousGenerateBatchingPredictor", "phase_walls",
            "attribution_shares"]
 
 _PREFILL, _DECODE = "prefill", "decode"
+
+
+# The input tokens of a decode launch that goes out before the launch
+# before it is read back, made on the device: one small program each, so
+# the warm-up compiles them once and no first launch run ahead does.
+@jax.jit
+def _carry_tokens(toks, keep):
+    """Each kept slot's last token of a decode launch's [S, T] tokens; 0
+    for every other slot, as the host hands an idle one."""
+    return jnp.where(keep, toks[:, -1], 0)
+
+
+@jax.jit
+def _chunk_tokens(first, chunk_tok, host_tok):
+    """The chunk's token for a slot whose prompt it completes, the host's
+    for every other."""
+    return jnp.where(first, chunk_tok, host_tok)
 
 
 def phase_walls(t0, t_admit, t_first, t_end, paused_s, paused_pre_s):
@@ -187,6 +214,26 @@ class _SlotSeq:
         self.paused_s = 0.0
         self.paused_pre_s = 0.0
         self.n_tok = 0      # tokens actually sampled (EOS freeze excluded)
+
+
+class _DecodeLaunch:
+    """One dispatched `decode_step` launch until it is read back: the
+    slots it carries, the host arrays it was given (a launch dispatched
+    ahead of it is built from them), its tokens still on the device, and
+    the hook's record of it. `ahead`: it was dispatched before the launch
+    before it had been read back."""
+
+    __slots__ = ("picks", "lengths", "active", "maxlens", "temps", "tks",
+                 "tables", "toks", "info", "t0", "ahead")
+
+    def __init__(self, picks, lengths, active, maxlens, temps, tks, tables,
+                 ahead):
+        self.picks = picks
+        self.lengths, self.active, self.maxlens = lengths, active, maxlens
+        self.temps, self.tks, self.tables = temps, tks, tables
+        self.ahead = ahead
+        self.toks = self.info = None
+        self.t0 = 0.0
 
 
 class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
@@ -458,6 +505,9 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
         self._ledger = (utilization if self._util_shown
                         and utilization is not True else UtilizationLedger())
         self._last_launch = None        # tick-thread-only hook stash
+        # the decode launch dispatched ahead of the tick that reads it back
+        # (a _DecodeLaunch; tick thread only)
+        self._ahead = None
         hook = self._gen_timing
         if self._util_shown:
             def hook(info, _h=self._gen_timing):
@@ -550,6 +600,7 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
         try:
             stats = AOTWarmup(self, cache_dir=self.compile_cache_dir,
                               tracer=self.tracer).run()
+            self._warm_carry()
             self._warm_stats.append(stats)
             if not stats["missing"] and not self._stop.is_set():
                 self._warm_armed.set()
@@ -557,6 +608,17 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             self._warm_errors.append(e)
         finally:
             self._warm_done.set()
+
+    def _warm_carry(self):
+        """Compile the two programs that hand a decode launch its tokens on
+        the device (`_carry_tokens`, `_chunk_tokens`) at this scheduler's
+        shapes: the first launch run ahead then compiles nothing."""
+        if self.spec_k > 0:
+            return
+        S = self.max_slots
+        idle = np.zeros(S, bool)
+        _carry_tokens(jnp.zeros((S, self.decode_steps), jnp.int64), idle)
+        _chunk_tokens(idle, jnp.zeros(S, jnp.int64), np.zeros(S, np.int64))
 
     def warm_stats(self):
         """Latest AOT warmup stats dict (programs/compiled/missing/
@@ -1297,6 +1359,11 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                         victim, vi = s, i
             if victim is None or victim.priority <= wprio:
                 return
+            if self._ahead is not None:
+                # the victim's state on the host has to be whole before it
+                # is parked: land the launch in flight, then look again
+                self._drain()
+                continue
             self._pause_slot(vi, victim)
             idx = self._free_slot()
             pick = self._qos_pick() if idx is not None else None
@@ -1562,24 +1629,32 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             return None
         return counts
 
-    def _util_launch(self, program, wait_s, total_units, slot_units,
+    def _take_launch(self):
+        """The hook's record of the launch just dispatched, taken off the
+        stash: a launch whose hook did not fire is never given another's."""
+        info, self._last_launch = self._last_launch, None
+        return info
+
+    def _util_launch(self, program, info, wait_s, total_units, slot_units,
                      spec_units=0, live_rows=0, walked_rows=0, table_rows=0,
-                     sampler=(False, False), counts=None):
-        """Account for the tick's launch, read back and absorbed: observe
+                     sampler=(False, False), counts=None, ahead=False,
+                     ahead_dropped=0):
+        """Account for a launch, read back and absorbed: observe
         `paddle_decode_launch_seconds` with the launch THROUGH its
         read-back, and hand the ledger its time split, positions and rows.
-        The timing hook stashed the launch's record on this thread; a path
-        mismatch means the hook never fired for this program (warmup
-        interleave) — skip rather than misattribute."""
-        if (self._last_launch or {}).get("path") != program:
+        `info` is the hook's record of the launch (`_take_launch`); a
+        missing one or a path mismatch means the hook never fired for this
+        program — skip rather than misattribute."""
+        if (info or {}).get("path") != program:
             return
-        info, launch_s = self._launch_done(wait_s)
+        info, launch_s = self._launch_done(wait_s, info)
         try:
             self._ledger.record_launch(
                 program, info.get("flops"), launch_s, total_units,
                 slot_units, spec_units, wait_s=wait_s, live_rows=live_rows,
                 walked_rows=walked_rows, table_rows=table_rows,
-                sampler=sampler, counts=counts)
+                sampler=sampler, counts=counts, ahead=ahead,
+                ahead_dropped=ahead_dropped)
         except ThreadDeath:
             raise
         except Exception:       # pragma: no cover - telemetry must not bite
@@ -1798,6 +1873,7 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             budget -= take
         if not picks:
             return
+        self._drain()       # a chunk is no decode continuation
         S, C = self.max_slots, self.prefill_chunk
         with RecordEvent("serve.prefill.assemble"):
             chunk = np.zeros((S, C), np.int64)
@@ -1830,6 +1906,7 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                     timing_hook=self._timing_hook, **akw)
                 if RecordEvent.capturing():
                     ev.set_stats(compiled=self._compiled_now("prefill_chunk"))
+            info = self._take_launch()
         except ThreadDeath:
             raise
         except Exception as e:
@@ -1838,11 +1915,20 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             return
         self.breaker.record_success()
         self.metrics.inc("prefill_ticks")
+        self._ahead, failed = self._decode_behind_chunk(picks, tk)
+        self._land_prefill(picks, tk, info, t0, reqs, temps, tks)
+        if failed is not None:
+            self._fail_dispatch(failed)
+
+    def _land_prefill(self, picks, tk, info, t0, reqs, temps, tks):
+        """Read a chunk launch back and absorb it: prompt positions taken,
+        and a first token for each slot whose prompt it completes."""
+        S, C = self.max_slots, self.prefill_chunk
         # the model's counts of the launch come back in the tokens' wait,
         # and the model says what they add to the ledger (the contract:
         # models/generation.py); a model that walks only the slots with a
         # chunk issues fewer positions than slots x chunk
-        stats = self._last_launch["stats"]
+        stats = info["stats"]
         (tk, *got), wait_s = self._read_back("prefill", tk, *stats.values())
         counts = self._counts_of(
             "prefill_chunk", [(i, s) for i, s, _ in picks], t0,
@@ -1871,7 +1957,7 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                     self._absorb(i, s, [s.tok])
         # useful positions are exactly each pick's take; the rest of what
         # the program issued (idle slots, chunk tail) is pad
-        self._util_launch("prefill_chunk", wait_s, issued,
+        self._util_launch("prefill_chunk", info, wait_s, issued,
                           [(s.tenant, take) for _, s, take in picks],
                           sampler=sampler_engages(temps, tks), counts=counts)
 
@@ -1894,69 +1980,193 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             pass
 
     # --------------------------------------------------------------- decode
+    def _decoding(self):
+        with self._slot_lock:
+            return [(i, s) for i, s in enumerate(self._slots)
+                    if s is not None and s.phase == _DECODE]
+
     def _decode_tick(self):
+        """Every decoding slot's next `decode_steps` tokens: one launch
+        read back and absorbed. The launch may have been dispatched ahead
+        (`_ahead`): behind this tick's chunk, or in the tick before; before
+        reading it back the tick dispatches the next one wherever
+        `_decode_ahead` can build it from the launch in flight alone. A
+        decoding slot that the launch in flight does not carry (a resumed
+        sequence) lands it first, and the tick assembles its launch from the
+        host as before."""
         if self.spec_k > 0:
             return self._verify_tick()
-        with self._slot_lock:
-            dec = [(i, s) for i, s in enumerate(self._slots)
-                   if s is not None and s.phase == _DECODE]
-        if not dec:
-            return
-        S, T = self.max_slots, self.decode_steps
+        launch, self._ahead = self._ahead, None
+        dec = self._decoding()
+        if launch is not None:
+            held = dict(launch.picks)
+            if any(held.get(i) is not s for i, s in dec):
+                self._land_decode(launch)
+                launch, dec = None, self._decoding()
+        if launch is None:
+            if not dec:
+                return
+            launch, failed = self._assemble_decode(dec)
+            if failed is not None:
+                self._fail_dispatch(failed)
+                return
+        ahead, failed = self._decode_ahead(launch)
+        self._land_decode(launch)
+        if failed is not None:
+            self._fail_dispatch(failed)
+        elif ahead is not None:
+            if any(self._slots[i] is s for i, s in ahead.picks):
+                self._ahead = ahead
+            else:           # all it carries finished: no later tick lands it
+                self._land_decode(ahead)
+
+    def _assemble_decode(self, dec, done=(), tk=None):
+        """A launch for the decoding slots `dec`, from their state on the
+        host, and for the slots `done` whose prompt the chunk launch `tk`
+        completes: their first input is that chunk's token, on the device,
+        and the launch goes out before the chunk is read back."""
+        S = self.max_slots
+        picks = list(dec) + list(done)
         with RecordEvent("serve.decode.assemble"):
             tok = np.zeros(S, np.int64)
             lengths = np.zeros(S, np.int64)
             maxlens = np.zeros(S, np.int64)
             active = np.zeros(S, bool)
+            first = np.zeros(S, bool)
             temps = np.zeros(S, np.float32)
             tks = np.zeros(S, np.int32)
             tables = np.zeros((S, self.table_width), np.int32)
-            for i, s in dec:
+            for i, _ in done:
+                first[i] = True
+            for i, s in picks:
                 tok[i] = s.tok
-                lengths[i] = s.length
+                lengths[i] = s.plen if first[i] else s.length
                 maxlens[i] = s.plen + s.max_new  # write ceiling: reserved rows
                 active[i] = True
                 temps[i] = s.temperature
                 tks[i] = s.top_k
                 tables[i] = s.table
-            reqs = [s.req for _, s in dec]
-            akw = self._adapter_tick_kwargs(dec, reqs)
+            if done:
+                tok = _chunk_tokens(first, tk._value, tok)
+            akw = self._adapter_tick_kwargs(picks, [s.req for _, s in picks])
+        launch = _DecodeLaunch(picks, lengths, active, maxlens, temps, tks,
+                               tables, ahead=tk is not None)
+        return self._dispatch_decode(launch, tok, akw)
+
+    def _decode_behind_chunk(self, picks, tk):
+        """Dispatch the tick's decode launch right behind its chunk launch
+        `tk`, before the chunk is read back: the decoding slots' inputs are
+        on the host (the tick landed any launch in flight first), and a slot
+        whose prompt the chunk completes starts from the chunk's token, on
+        the device, unless its first token is all it asked for. The tick's
+        `_decode_tick` lands it as a launch run ahead. Returns what
+        `_dispatch_decode` does."""
+        if self.spec_k > 0:
+            return None, None
+        dec = self._decoding()
+        done = [(i, s) for i, s, take in picks
+                if s.pos + take >= s.plen and s.max_new > 1]
+        if not dec and not done:
+            return None, None
+        return self._assemble_decode(dec, done, tk)
+
+    def _decode_ahead(self, launch):
+        """Dispatch the launch after `launch` before `launch` is read back,
+        where its inputs are known without `launch`'s tokens on the host:
+        its input tokens are the last column of `launch`'s, still on the
+        device; the lengths are `launch`'s plus `decode_steps`; the slots
+        and tables are `launch`'s less the slots that leave before it runs
+        (those the host knows finish in `launch` by count, and those
+        cancelled or past their deadline). A slot that finishes in `launch`
+        by EOS rides along and has its tokens dropped at the read-back.
+
+        Runs no launch ahead while a slot prefills (the next tick's chunk
+        would land this one before it, and its decoders would get two
+        launches a chunk), nor under a tenant ledger while a sequence waits
+        paused or a request waits: the next admission may resume one into
+        a slot this launch does not carry, or pause one it does. Returns
+        (launch, None), (None, failure) or (None, None)."""
+        T = self.decode_steps
+        with self._slot_lock:
+            if any(s is not None and s.phase == _PREFILL
+                   for s in self._slots):
+                return None, None
+        if self.qos is not None and (self._paused or self._backlog
+                                     or not self._queue.empty()):
+            return None, None
+        keep = [(i, s) for i, s in launch.picks
+                if self._slots[i] is s and s.req.state == _PENDING
+                and (s.req.deadline is None or not s.req.deadline.expired())
+                and len(s.generated) + T < s.max_new]
+        if not keep:
+            return None, None
+        with RecordEvent("serve.decode.assemble"):
+            active = np.zeros(self.max_slots, bool)
+            active[[i for i, _ in keep]] = True
+            nxt = _DecodeLaunch(
+                keep, np.where(active, launch.lengths + T, 0), active,
+                np.where(active, launch.maxlens, 0),
+                np.where(active, launch.temps, 0).astype(np.float32),
+                np.where(active, launch.tks, 0).astype(np.int32),
+                np.where(active[:, None], launch.tables, 0).astype(np.int32),
+                ahead=True)
+            tok = _carry_tokens(launch.toks._value, active)
+            akw = self._adapter_tick_kwargs(keep, [s.req for _, s in keep])
+        return self._dispatch_decode(nxt, tok, akw)
+
+    def _dispatch_decode(self, launch, tok, akw):
+        """Hand `launch` to the device with input tokens `tok` (host or
+        device): (launch, None), or (None, failure) where failure is what
+        `_fail_picks` takes."""
         traced = self.tracer.enabled
-        t0 = self.tracer.now_us() if traced else 0.0
+        launch.t0 = t0 = self.tracer.now_us() if traced else 0.0
         try:
             if self._faults is not None:
                 self._faults.check("predictor.generate")
             with RecordEvent("serve.decode.dispatch") as ev:
-                toks = self.model.decode_step(
-                    tok, lengths, active, self.kv_cache, tables, steps=T,
-                    max_lens=maxlens, temperature=temps, top_k=tks,
-                    eos_token_id=self.eos_token_id,
+                launch.toks = self.model.decode_step(
+                    tok, launch.lengths, launch.active, self.kv_cache,
+                    launch.tables, steps=self.decode_steps,
+                    max_lens=launch.maxlens, temperature=launch.temps,
+                    top_k=launch.tks, eos_token_id=self.eos_token_id,
                     decode_kernel=self.decode_kernel, seed=next(self._seed),
                     timing_hook=self._timing_hook, **akw)
                 if RecordEvent.capturing():
                     ev.set_stats(compiled=self._compiled_now("decode_step"))
+            launch.info = self._take_launch()
         except ThreadDeath:
             raise
         except Exception as e:
-            self._fail_picks(dec, e, "decode_step", t0)
-            return
+            return None, (launch.picks, e, "decode_step", t0)
         self.breaker.record_success()
         self.metrics.inc("decode_ticks")
-        stats = self._last_launch["stats"]
-        (toks, *got), wait_s = self._read_back("decode", toks,
+        return launch, None
+
+    def _land_decode(self, launch):
+        """Read `launch` back and absorb it into the slots that still hold
+        the sequences it carried. A sequence that finished since it was
+        dispatched (EOS, cancel, deadline: only a launch run ahead spans a
+        tick boundary) has its tokens dropped; the ledger counts them as
+        `ahead_dropped` slot-steps."""
+        S, T = self.max_slots, self.decode_steps
+        stats = launch.info["stats"]
+        (toks, *got), wait_s = self._read_back("decode", launch.toks,
                                                *stats.values())
+        live = [(i, s) for i, s in launch.picks if self._slots[i] is s]
+        lengths, active = launch.lengths, launch.active
         counts = self._counts_of(
-            "decode_step", dec, t0, dict(zip(stats, got)),
+            "decode_step", live, launch.t0, dict(zip(stats, got)),
             (lengths[active][:, None] + np.arange(T)).reshape(-1), steps=T)
         if counts is None:
             return
         counts.pop("issued_positions", None)    # a tick carries every slot
-        self._span_each(reqs, "decode_step", t0, self.tracer.now_us(),
-                        slots=len(dec), steps=T)
+        self._span_each([s.req for _, s in live], "decode_step", launch.t0,
+                        self.tracer.now_us(), slots=len(launch.picks),
+                        steps=T)
         live_rows, walked_rows, table_rows = self._kv_rows(lengths[active], T)
         units = []
         with RecordEvent("serve.decode.absorb") as ev:
-            for i, s in dec:
+            for i, s in live:
                 s.length += T
                 s.tok = int(toks[i, -1])
                 n0 = s.n_tok
@@ -1967,10 +2177,27 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             if RecordEvent.capturing():
                 ev.set_stats(useful=sum(u for _, u in units), issued=S * T,
                              rows=live_rows)
-        self._util_launch("decode_step", wait_s, S * T, units,
+        self._util_launch("decode_step", launch.info, wait_s, S * T, units,
                           live_rows=live_rows, walked_rows=walked_rows,
                           table_rows=table_rows,
-                          sampler=sampler_engages(temps, tks), counts=counts)
+                          sampler=sampler_engages(launch.temps, launch.tks),
+                          counts=counts, ahead=launch.ahead,
+                          ahead_dropped=T * (len(launch.picks) - len(live)))
+
+    def _fail_dispatch(self, failed):
+        """Fail a decode launch that could not be dispatched, once the
+        launch before it has landed: as if the dispatch had come after that
+        read-back, the slots that finished meanwhile keep their answers."""
+        picks, error, span, t0 = failed
+        self._fail_picks([(i, s) for i, s in picks if self._slots[i] is s],
+                         error, span, t0)
+
+    def _drain(self):
+        """Land the decode launch run ahead, if one is in flight: a tick
+        that is not a pure decode continuation does so first."""
+        launch, self._ahead = self._ahead, None
+        if launch is not None:
+            self._land_decode(launch)
 
     def _kv_rows(self, lengths, steps, one_call=False):
         """(live_rows, walked_rows, table_rows) of one decode or verify
@@ -2072,6 +2299,7 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                     timing_hook=self._timing_hook, **akw)
                 if RecordEvent.capturing():
                     ev.set_stats(compiled=self._compiled_now("verify_step"))
+            info = self._take_launch()
         except ThreadDeath:
             raise
         except Exception as e:
@@ -2109,7 +2337,7 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
             if RecordEvent.capturing():
                 ev.set_stats(useful=sum(u for _, u in units),
                              issued=S * (K + 1), rows=live_rows)
-        self._util_launch("verify_step", wait_s, S * (K + 1), units,
+        self._util_launch("verify_step", info, wait_s, S * (K + 1), units,
                           spec_units=drafted - accepted,
                           live_rows=live_rows, walked_rows=walked_rows,
                           table_rows=table_rows,
@@ -2120,7 +2348,9 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
         """ThreadDeath path: free every slot's blocks; still-pending
         requests re-enter the queue and re-run from scratch after the
         supervisor heals the thread (their chunked-prefill progress is
-        lost with the thread — correctness over cleverness)."""
+        lost with the thread — correctness over cleverness). A launch run
+        ahead is left to the device unread: nothing it carries survives."""
+        self._ahead = None
         for i, s in enumerate(list(self._slots)):
             if s is None:
                 continue
@@ -2140,7 +2370,14 @@ class ContinuousGenerateBatchingPredictor(GenerateBatchingPredictor):
                 self._enqueue(s.req)
 
     def _shutdown_slots(self):
-        """stop() path: nobody hangs on a closed scheduler."""
+        """stop() path: nobody hangs on a closed scheduler. A launch run
+        ahead lands first: what it finished is answered."""
+        try:
+            self._drain()
+        except ThreadDeath:
+            raise
+        except Exception:       # pragma: no cover - the device failed it
+            pass
         for i, s in enumerate(list(self._slots)):
             if s is None:
                 continue

@@ -655,11 +655,13 @@ class GenerateBatchingPredictor(BatchingPredictor):
         self._tokens_total.labels(self._component).inc(
             info["batch"] * info["new_tokens"])
 
-    def _launch_done(self, wait_s):
-        """Observe the launch the hook last stashed, now that its result is
-        on the host: dispatch plus the `wait_s` of the read-back. Returns
-        (hook record, launch seconds), or (None, 0.0) if no hook fired."""
-        info, self._last_launch = self._last_launch, None
+    def _launch_done(self, wait_s, info=None):
+        """Observe a launch now that its result is on the host: dispatch
+        plus the `wait_s` of the read-back. `info` is the launch's hook
+        record, by default the one the hook last stashed. Returns (hook
+        record, launch seconds), or (None, 0.0) if no hook fired."""
+        if info is None:
+            info, self._last_launch = self._last_launch, None
         if info is None:
             return None, 0.0
         launch_s = info["dispatch_s"] + wait_s

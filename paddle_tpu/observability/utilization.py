@@ -49,7 +49,13 @@ saved). Beside ``launches``, two counts say how often the step programs'
 sampler engaged: ``sampler_drawn``, the launches in which some slot sampled
 at a temperature (the random draw ran), and ``sampler_sorted``, those in
 which such a slot also asked for top-k (the sort over the vocabulary ran);
-a launch of greedy slots counts in neither. These are plain integers and
+a launch of greedy slots counts in neither. Two more say how often the
+scheduler kept a decode launch ahead of its read-back: ``ahead``, the
+launches dispatched before the launch before them had been read back, and
+``ahead_dropped``, the slot-steps such launches computed for a sequence
+that had finished meanwhile (EOS, cancel, deadline), dropped on the host;
+``ahead / launches`` is the share of launches that overlapped the host's
+tick. These are plain integers and
 need no FLOPs probe, so every continuous scheduler keeps them;
 ``utilization=True`` adds the probe and the exported series.
 
@@ -147,7 +153,8 @@ def attribute_launch(flops, total_units, slot_units, spec_units=0):
 _FLOPS = ("issued", "useful", "pad", "spec_waste")
 _POSITIONS = ("issued_positions", "useful_positions", "pad_positions",
               "spec_positions")
-_PROGRAM_KEYS = (_FLOPS + ("launches", "sampler_drawn", "sampler_sorted")
+_PROGRAM_KEYS = (_FLOPS + ("launches", "sampler_drawn", "sampler_sorted",
+                            "ahead", "ahead_dropped")
                  + _POSITIONS
                  + ("live_rows", "walked_rows", "table_rows", "dispatch_s",
                     "wait_s"))
@@ -337,7 +344,7 @@ class UtilizationLedger:
     def record_launch(self, program, flops, launch_s, total_units,
                       slot_units, spec_units=0, *, wait_s=0.0, live_rows=0,
                       walked_rows=0, table_rows=0, sampler=(False, False),
-                      counts=None):
+                      counts=None, ahead=False, ahead_dropped=0):
         """Attribute one launch inside the current tick. ``launch_s`` is
         the launch THROUGH its read-back, ``wait_s`` the read-back's part
         of it. ``total_units`` are the positions the program issued,
@@ -345,7 +352,10 @@ class UtilizationLedger:
         slot — the scheduler's ground truth of which positions carried live
         tokens — and ``spec_units`` rejected draft positions. ``sampler``
         is ``(drawn, sorted)``, which of the sampler's branches the launch
-        ran (``models.generation.sampler_engages``). ``counts`` are
+        ran (``models.generation.sampler_engages``). ``ahead``: the launch
+        was dispatched before the one before it had been read back;
+        ``ahead_dropped``: slot-steps it computed for sequences that had
+        finished by then, whose tokens were dropped. ``counts`` are
         the model's own of this launch, under its own key names: every one
         is summed on the program's account; one that is the ledger's own
         raises ValueError."""
@@ -376,6 +386,8 @@ class UtilizationLedger:
         p["launches"] += 1
         p["sampler_drawn"] += int(bool(sampler[0]))
         p["sampler_sorted"] += int(bool(sampler[1]))
+        p["ahead"] += int(bool(ahead))
+        p["ahead_dropped"] += int(ahead_dropped)
         p["issued_positions"] += int(total_units)
         p["useful_positions"] += useful_pos
         p["spec_positions"] += int(spec_units)

@@ -332,13 +332,7 @@ class _Late:
         return np.asarray(self.value)
 
 
-class _Result:
-    def __init__(self, late):
-        self._value = late
-
-
-def test_fake_clock_tick_wall_has_admission_in_and_parking_out(
-        small_gpt, monkeypatch):
+def test_fake_clock_tick_wall_has_admission_in_and_parking_out(small_gpt):
     clk = FakeClock()
     ADMIT, WAIT = 0.1, 0.5
     sched = _make(small_gpt, utilization=UtilizationLedger(
@@ -353,12 +347,14 @@ def test_fake_clock_tick_wall_has_admission_in_and_parking_out(
         return orig_admit()
 
     sched._admit = slow_admit
-    for name in ("prefill_chunk", "decode_step"):
-        real = getattr(small_gpt, name)
-        monkeypatch.setattr(
-            small_gpt, name,
-            lambda *a, _real=real, **k: _Result(
-                _Late(_real(*a, **k)._value, clk, WAIT)))
+    read_back = sched._read_back
+
+    def late_read_back(phase, tokens, *rest):
+        # a launch's tokens take WAIT of the fake clock to come back (what
+        # stays on the device, a launch run ahead's input, takes nothing)
+        return read_back(phase, _Late(tokens._value, clk, WAIT), *rest)
+
+    sched._read_back = late_read_back
     t_start = clk()
     try:
         out = sched.infer(np.arange(7, dtype="int64"), timeout=60)
